@@ -37,7 +37,7 @@ chaos-campaign:
 	$(PY) -m spicedb_kubeapi_proxy_tpu.chaos.campaign \
 	  --seeds $(CHAOS_SEEDS) --episodes $(CHAOS_EPISODES)
 
-# the headline benchmark (real TPU if reachable, CPU-degraded otherwise)
+# the headline benchmark: needs a TPU (no CPU fall-back; see bench-quick)
 bench:
 	$(PY) bench.py
 
@@ -145,6 +145,7 @@ verify: lint analyze
 
 clean:
 	rm -f spicedb_kubeapi_proxy_tpu/native/libgraphcore.so
+	rm -rf .jax_compile_cache .chip_smoke
 	find . -name __pycache__ -type d -exec rm -rf {} +
 
 # flake hunting: loop the suite until it fails (reference

@@ -175,7 +175,7 @@ class LookupBatcher:
             # row composition: cache their device copies — concurrent
             # lists of the same resource types repeat the composition, and
             # re-uploading B x objects of slot ids per dispatch is
-            # measurable tunnel traffic. A single-row batch shares the
+            # measurable host->device traffic. A single-row batch shares the
             # direct lookup path's key (identical array bytes).
             if len(composition) == 1:
                 key = ("lookup",) + composition[0]

@@ -598,7 +598,7 @@ class Engine:
         background compactor's fold (deliberately OFF the lock: the old
         base keeps serving while the fold runs)."""
         t0 = time.perf_counter()
-        cg = compile_graph(self.schema, self.store.snapshot(),
+        cg = compile_graph(self.schema, self.store.snapshot(self.schema),
                            delta_capacity=self._delta_capacity)
         if self._tier_budget:
             # each compiled base gets a fresh TierStore: residency and
@@ -1251,8 +1251,7 @@ class Engine:
         t0 = time.perf_counter()
         # the query arrays are a pure function of (type, permission) slot
         # layout: cache their device copies across queries (the ~0.5MB
-        # upload per 100k-object lookup otherwise dominates wall latency
-        # on remotely-attached chips)
+        # upload per 100k-object lookup is otherwise paid on every call)
         self._apply_crossover(cg)
         fut = self._backend(cg).query_async(
             seeds, q_slots, q_batch, now=now,

@@ -2377,6 +2377,9 @@ def main(argv=None) -> int:
                          "production")
     args = ap.parse_args(argv)
     logging.basicConfig(level=logging.INFO)
+    from ..utils.compile_cache import place_compile_cache
+
+    place_compile_cache()
     if not 0.0 <= args.trace_sample <= 1.0:
         ap.error("--trace-sample must be in [0, 1]")
     tracer.configure(sample=args.trace_sample,

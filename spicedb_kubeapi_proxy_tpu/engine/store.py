@@ -1078,13 +1078,25 @@ class Store:
                              journal_payload)
             self._watch_cond.notify_all()
 
-    def snapshot(self) -> Snapshot:
+    def snapshot(self, schema=None) -> Snapshot:
         """Immutable columnar view of all live tuples for the compiler.
 
         Expired tuples are retained (with their timestamps) — the device
         kernel masks them against the query-time clock, mirroring SpiceDB's
-        read-time expiration filtering."""
+        read-time expiration filtering.
+
+        With ``schema``, its type, relation and permission names get their
+        ids first: the compiler's id -> slot tables then cover every write
+        the schema admits, so the first tuple of a type or relation no
+        loaded tuple used (the dual-write's lock / workflow / creator)
+        rides the overlay instead of forcing a recompile."""
         with self._lock:
+            if schema is not None:
+                for tname in sorted(schema.definitions):
+                    d = schema.definitions[tname]
+                    self.types.intern(tname)
+                    for name in sorted({*d.relations, *d.permissions}):
+                        self.relations.intern(name)
             blocks = [
                 cols.take(np.flatnonzero(alive))
                 for cols, alive in zip(self._chunks, self._alive)

@@ -366,8 +366,9 @@ class SchemaMigrator:
         from ..ops.reachability import compile_graph
 
         t0 = time.perf_counter()
-        self._new_cg = compile_graph(self._new_schema, e.store.snapshot(),
-                                     delta_capacity=e._delta_capacity)
+        self._new_cg = compile_graph(
+            self._new_schema, e.store.snapshot(self._new_schema),
+            delta_capacity=e._delta_capacity)
         metrics.histogram("engine_migration_compile_seconds").observe(
             time.perf_counter() - t0)
 

@@ -239,14 +239,16 @@ def _dense_kernel(f_ref, a_ref, out_ref):
     the first step) — the standard Pallas accumulation pattern; the
     frontier-tile emptiness predicate skips the matmul for all-zero
     frontier chunks, the push-flavored work skip that makes the pull
-    kernel cheap on sparse iterations too."""
+    kernel cheap on sparse iterations too. The predicate reduces the
+    tile widened to int32: Mosaic has no relayout for the i1 mask of an
+    int8 compare ((32,128) tiling) into the reduction's (8,128) one."""
     from jax.experimental import pallas as pl
 
     @pl.when(pl.program_id(1) == 0)
     def _init():
         out_ref[:] = jnp.zeros_like(out_ref)
 
-    @pl.when(jnp.any(f_ref[:] != 0))
+    @pl.when(jnp.max(f_ref[:].astype(jnp.int32)) > 0)
     def _accum():
         part = jax.lax.dot_general(
             f_ref[:], a_ref[:],

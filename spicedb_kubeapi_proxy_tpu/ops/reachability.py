@@ -320,7 +320,7 @@ def _closure_pairs(dst_local: np.ndarray, src_local: np.ndarray,
 
 def _stratify(offs: np.ndarray, src_rid: np.ndarray, dst_rid: np.ndarray,
               programs: list, ignore_self: frozenset = frozenset(),
-              ) -> tuple[dict, int]:
+              potential: frozenset = frozenset()) -> tuple[dict, int]:
     """Range-level stratification of the dependency graph.
 
     Build the range-granularity dependency graph (edges: src range feeds
@@ -340,6 +340,14 @@ def _stratify(offs: np.ndarray, src_rid: np.ndarray, dst_rid: np.ndarray,
     ``ignore_self``: range ids whose self-dependency (r -> r edges) is
     satisfied by a closured dense block (one application = all hops), so
     the self-edge must not force the range into the core.
+
+    ``potential``: (src range, dst range) pairs the SCHEMA admits whether
+    or not a tuple uses them yet. They order the peeled levels too, so the
+    first write along one (a relation or type no loaded tuple had) fits
+    the frozen levels and rides the overlay. The core stays what the data
+    makes it: a pair that would close a cycle the data does not have, or
+    pull a peeled range into the core, is left out and stays a
+    ``stratification-inversion`` when it is first written.
     """
     n_ranges = len(offs)
     consumers: list[set] = [set() for _ in range(n_ranges)]
@@ -356,14 +364,37 @@ def _stratify(offs: np.ndarray, src_rid: np.ndarray, dst_rid: np.ndarray,
         p_rid = _range_id(offs, p.dst_off)
         for off in set(p.leaf_off.values()):
             consumers[_range_id(offs, off)].add(p_rid)
-    remaining = set(range(n_ranges))
-    peel: list[list[int]] = []
-    while True:
-        removable = [r for r in remaining if not (consumers[r] & remaining)]
-        if not removable:
-            break
-        peel.append(removable)
-        remaining -= set(removable)
+
+    def peel_all() -> tuple[list, set]:
+        remaining = set(range(n_ranges))
+        peel: list[list[int]] = []
+        while True:
+            removable = [r for r in remaining
+                         if not (consumers[r] & remaining)]
+            if not removable:
+                return peel, remaining
+            peel.append(removable)
+            remaining -= set(removable)
+
+    def feeds(a: int, b: int) -> bool:
+        """Whether range a's values reach range b along ``consumers``."""
+        seen, todo = {a}, [a]
+        while todo:
+            for c in consumers[todo.pop()]:
+                if c == b:
+                    return True
+                if c not in seen:
+                    seen.add(c)
+                    todo.append(c)
+        return False
+
+    if potential:
+        _, core = peel_all()
+        for s, d in sorted(potential):
+            if s != d and s not in core and d not in core \
+                    and not feeds(d, s):
+                consumers[s].add(d)
+    peel, remaining = peel_all()
     n_levels = len(peel)
     level = {r: 0 for r in remaining}  # cyclic core + its ancestors
     for i, grp in enumerate(peel):  # peeled first -> evaluated last
@@ -970,8 +1001,7 @@ class CompiledGraph:
         function of the slot layout (list-filter masks read a type's whole
         permission range every time) pass a key so the padded device
         arrays are built and uploaded ONCE per compiled-graph generation —
-        at the 100k-object scale that upload is ~0.5MB per query, a large
-        share of wall latency on remotely-attached chips.
+        at the 100k-object scale that upload is ~0.5MB per query.
         """
         d = self._dev()
         B = seed_slots.shape[0]
@@ -1083,7 +1113,7 @@ class CompiledGraph:
         with jax.profiler.TraceAnnotation("sdbkp:fixpoint"):
             # seeds ride the jit call as a host array: jax folds the
             # transfer into the dispatch instead of a separate device_put
-            # round trip (visible through remotely-attached chips)
+            # round trip
             out, converged, iters, n_push, cav_missing = run(
                 blocks_arg, bits_arg, d["src"], d["dst"], d["exp"],
                 d["cav"], d["dsrc"], d["ddst"], d["dexp"], d["dcav"],
@@ -1097,8 +1127,7 @@ class CompiledGraph:
             converged.copy_to_host_async()
             # iters feeds the fixpoint-iterations metric in the engine's
             # result finalizer; without the prefetch that int() is a
-            # synchronous device roundtrip per query (a full tunnel RTT on
-            # remotely-attached chips)
+            # synchronous device roundtrip per query
             iters.copy_to_host_async()
             n_push.copy_to_host_async()
             cav_missing.copy_to_host_async()
@@ -1847,8 +1876,28 @@ def compile_graph(schema: Schema, snapshot: Snapshot,
                     closure_rids.add(d_rid)
                     closure_coo[d_rid] = coo
 
-    level_map, n_levels = _stratify(offs, src_rid, dst_rid, programs,
-                                    ignore_self=frozenset(closure_rids))
+    # range pairs the schema admits (mirrors the three edge extractions
+    # above: direct, userset, arrow), data or no data
+    admitted: set = set()
+    for tname, d in schema.definitions.items():
+        for rname, r in d.relations.items():
+            for a in r.allowed:
+                feeder = (a.type, a.relation or SELF_REL)
+                if feeder in slot_offset:
+                    admitted.add((feeder, (tname, rname)))
+        for pname in d.permissions:
+            for k, arrow in enumerate(arrow_terms[(tname, pname)]):
+                for a in d.relations[arrow.tupleset].allowed:
+                    if not a.relation and (a.type, arrow.target) \
+                            in slot_offset:
+                        admitted.add(((a.type, arrow.target),
+                                      (tname, f"__arrow_{pname}_{k}")))
+    level_map, n_levels = _stratify(
+        offs, src_rid, dst_rid, programs,
+        ignore_self=frozenset(closure_rids),
+        potential=frozenset(
+            (_range_id(offs, slot_offset[s]), _range_id(offs, slot_offset[t]))
+            for s, t in admitted))
 
     # Retain the range-granularity adjacency for tiered demand closure:
     # every (src range, dst range) pair the FULL edge set crosses (the

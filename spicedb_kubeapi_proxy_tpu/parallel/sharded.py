@@ -80,12 +80,8 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:  # jax>=0.4.35 exposes shard_map at top level
-    from jax import shard_map  # type: ignore[attr-defined]
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map
 
 from ..ops import semiring
 from ..ops.reachability import (
@@ -161,8 +157,16 @@ def _run_sharded(meta, block_meta, ng: int, level_edges, blocks,
             block_meta, blocks, no_bits, src, dst, acts[k],
             dsrc, ddst, dact, Vflat, occ, crossover,
             level=k, mode=meta.spmm_mode, shard=(g_idx, ng))
-        # join partials over ICI — outside the primitive's mode cond
-        return jax.lax.pmax(prop, "graph"), is_push
+        # join partials over ICI — outside the primitive's mode cond.
+        # Widened to int32 for the wire: on v5e chips a uint8 pmax
+        # compares the four bytes packed in a 32-bit word as ONE number
+        # and keeps the larger word whole, dropping the set bytes of the
+        # other (rows 0-2 of every 4; grants denied for B > 1). int32
+        # is exact. The CPU backend reduces bytes one by one, so no
+        # virtual-device run can see this: chip_smoke.py --chips 4 does,
+        # and tests/test_chip_compile.py pins the compiled join to s32.
+        joined = jax.lax.pmax(prop.astype(jnp.int32), "graph")
+        return joined.astype(jnp.uint8), is_push
 
     core_progs = [p for p in meta.programs if p.level == 0]
 
@@ -402,27 +406,28 @@ class ShardedGraph:
         if meta.n_levels + 1 != len(self._level_edges):
             raise AssertionError(
                 "level edge arrays out of step with stratification")
-        fn = partial(_run_sharded, meta, self._block_meta, self.ng,
-                     max_iters=max_iters, k_steps=self.k_steps)
-        smap_kw = dict(
-            mesh=mesh,
+        self._run = self._program(mesh)
+
+    def _program(self, mesh: Mesh):
+        """The jitted shard_map fixpoint of this graph over ``mesh`` (the
+        serving mesh; tests/test_chip_compile.py passes a described one
+        to ask the chip's compiler about the same program)."""
+        fn = partial(_run_sharded, self.cg.run_meta(), self._block_meta,
+                     self.ng, max_iters=self.max_iters,
+                     k_steps=self.k_steps)
+        # check_vma off: the all_gather'ed result and the pmax'ed flags
+        # ARE replicated, but the checker infers them varying over "data"
+        return jax.jit(shard_map(
+            fn, mesh=mesh, check_vma=False,
             in_specs=(
                 tuple((P("graph"),) * 4 for _ in self._level_edges),
-                tuple(P(None, "graph") for _ in kept),
+                tuple(P(None, "graph") for _ in self._block_meta),
                 P("graph"), P("graph"), P("graph"), P("graph"),
                 P(), P(),
                 P("data", None), P("data", None), P(), P(),
             ),
             out_specs=(P(None, None), P(), P(), P(), P(), P()),
-        )
-        try:
-            smapped = shard_map(fn, check_vma=False, **smap_kw)
-        except TypeError:
-            # older jax spells the replication-check toggle check_rep —
-            # and its default (True) has no replication rule for
-            # while_loop, so it must be disabled, not defaulted
-            smapped = shard_map(fn, check_rep=False, **smap_kw)
-        self._run = jax.jit(smapped)
+        ))
 
     @staticmethod
     def unsupported_reason(cg: CompiledGraph) -> Optional[str]:

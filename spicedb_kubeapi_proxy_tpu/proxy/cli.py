@@ -12,6 +12,7 @@ import logging
 import signal
 import sys
 
+from ..utils.compile_cache import place_compile_cache
 from .options import add_flags, options_from_args
 
 
@@ -27,6 +28,7 @@ def main(argv=None) -> int:
         level=logging.DEBUG if args.verbosity >= 3 else logging.INFO,
         format="%(asctime)s %(name)s %(levelname)s %(message)s",
     )
+    place_compile_cache()
     opts = options_from_args(args)
     cfg = opts.complete()
 

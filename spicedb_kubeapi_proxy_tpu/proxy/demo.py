@@ -22,6 +22,7 @@ from __future__ import annotations
 
 import asyncio
 
+from ..utils.compile_cache import place_compile_cache
 from .inmemkube import InMemoryKube
 
 
@@ -76,20 +77,10 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(
         prog="spicedb-kubeapi-proxy-tpu-demo", description=__doc__)
     ap.add_argument("--port", type=int, default=8080)
-    ap.add_argument("--tpu", action="store_true",
-                    help="run the engine on the TPU backend (default: "
-                         "CPU — the demo is a laptop flow, and a slow or "
-                         "absent TPU plugin would stall the boot warmup)")
     args = ap.parse_args(argv)
     logging.basicConfig(level=logging.INFO,
                         format="%(asctime)s %(levelname)s %(message)s")
-    if not args.tpu:
-        import jax
-
-        try:
-            jax.config.update("jax_platforms", "cpu")
-        except Exception:  # already initialized: keep whatever it picked
-            pass
+    place_compile_cache()
     cfg = build(args.port)
 
     async def serve():

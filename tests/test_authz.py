@@ -2,13 +2,14 @@
 
 Ports the shape of the reference e2e scenario suite
 (reference e2e/proxy_test.go): every verb through the full middleware
-against a fake kube upstream, using the reference's own deploy/rules.yaml
-rule set and bootstrap schema — per-user isolation on get/list/watch,
+against a fake kube upstream, using the repo's deploy/rules.yaml rule set
+(modelled on the reference's) and the default bootstrap schema — per-user isolation on get/list/watch,
 dual-write visibility, table filtering, postchecks, CEL `if` rules.
 """
 
 import asyncio
 import json
+import os
 
 import pytest
 
@@ -23,7 +24,9 @@ from spicedb_kubeapi_proxy_tpu.rules.input import UserInfo
 
 from fake_kube import FakeKube
 
-RULES = open("/root/reference/deploy/rules.yaml").read()
+with open(os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "deploy", "rules.yaml")) as _f:
+    RULES = _f.read()
 
 
 class Env:
@@ -114,7 +117,7 @@ def test_create_conflict_second_user():
     async def go():
         env = Env()
         assert (await env.create_ns("shared")).status == 201
-        # second create: precondition (cluster rel exists) -> 409
+        # second create: precondition (the name has a creator) -> 409
         resp = await env.create_ns("shared", user="mallory")
         assert resp.status == 409
         assert not env.engine.store.exists(RelationshipFilter(

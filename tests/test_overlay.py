@@ -235,6 +235,51 @@ def test_closured_block_delete_recloses_and_expiry_attach_falls_back(
     assert fallback_value("stratification-inversion") >= si0
 
 
+def test_first_dual_write_after_a_load_rides_the_overlay():
+    """The first dual-write after a load writes types and relations no
+    loaded tuple used (lock, workflow, activity, namespace#creator). The
+    compiled id tables and levels cover whatever the schema admits, so
+    those writes are overlay appends: no declined update, no recompile
+    (at 10M relationships a recompile answers 401 for ~12 s meanwhile)."""
+    import os
+
+    here = os.path.dirname(os.path.abspath(__file__))
+    with open(os.path.join(here, "..", "deploy", "bootstrap.yaml")) as f:
+        e = Engine(f.read())
+    e.write_relationships([WriteOp("touch", rel(r)) for r in (
+        "pod:ns/p1#viewer@user:u1", "pod:ns/p1#namespace@namespace:ns",
+        "namespace:ns#viewer@group:g1#member", "group:g1#member@user:u2")])
+    assert e.check_bulk([CheckItem("pod", "ns/p1", "view", "user", "u2")])[0]
+
+    def declined() -> float:
+        return sum(float(line.rsplit(" ", 1)[1])
+                   for line in metrics.render().splitlines()
+                   if line.startswith(
+                       "engine_graph_incremental_fallback_total"))
+
+    compiles0 = metrics.counter("engine_graph_compiles_total").value
+    declined0 = declined()
+    lock = rel("lock:l1#workflow@workflow:w1")
+    e.write_relationships([WriteOp("create", lock)])
+    assert e.lookup_resources("namespace", "view", "user", "u2") == ["ns"]
+    e.write_relationships([
+        WriteOp("touch", rel("namespace:new#creator@user:u2")),
+        WriteOp("touch", Relationship(
+            "workflow", "w1", "idempotency_key", "activity", "a1",
+            expiration=time.time() + 500))])
+    e.write_relationships([WriteOp("delete", lock)])
+    assert set(e.lookup_resources("namespace", "view", "user", "u2")) \
+        == {"ns", "new"}
+    assert e.check_bulk([
+        CheckItem("namespace", "new", "admin", "user", "u2"),
+        CheckItem("namespace", "new", "view", "user", "u1"),
+        CheckItem("workflow", "w1", "idempotency_key", "activity", "a1"),
+        CheckItem("lock", "l1", "workflow", "workflow", "w1"),
+    ]) == [True, False, True, False]
+    assert declined() == declined0
+    assert metrics.counter("engine_graph_compiles_total").value == compiles0
+
+
 def test_overlay_overflow_counted_fallback_without_compactor():
     """Without a compactor, overflowing the fixed-capacity overlay is a
     COUNTED fallback to one full recompile (which empties the overlay) —

@@ -3,6 +3,7 @@ config validation — modeled on the reference's pkg/rules and
 pkg/config/proxyrule test suites."""
 
 import json
+import os
 
 import pytest
 
@@ -167,20 +168,23 @@ def test_resolve_input_namespace_normalization():
 
 
 # ---------------------------------------------------------------------------
-# Rule config parsing + compilation (the reference deploy/rules.yaml)
+# Rule config parsing + compilation (the repo's own deploy/rules.yaml, the
+# rule set modelled on the reference's)
 # ---------------------------------------------------------------------------
 
-REFERENCE_RULES = open("/root/reference/deploy/rules.yaml").read()
+with open(os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "deploy", "rules.yaml")) as _f:
+    REFERENCE_RULES = _f.read()
 
 
 def test_parse_reference_deploy_rules():
     cfgs = parse_rule_configs(REFERENCE_RULES)
     assert len(cfgs) == 8
     byname = {c.name: c for c in cfgs}
-    cn = byname["create-namespaces"]
+    cn = byname["namespace-create"]
     assert cn.spec.locking == "Pessimistic"
     assert cn.spec.update.creates and cn.spec.update.precondition_does_not_exist
-    lw = byname["list-watch-pods"]
+    lw = byname["pod-list-watch"]
     assert lw.spec.pre_filters[0].from_object_id_namespace_expr
     # all of them compile
     for c in cfgs:
@@ -189,24 +193,25 @@ def test_parse_reference_deploy_rules():
 
 def test_rule_end_to_end_resolution():
     cfgs = {c.name: compile_rule(c) for c in parse_rule_configs(REFERENCE_RULES)}
-    # get-pods check template resolution
+    # pod-get check template resolution
     i = make_input(verb="get", resource="pods", name="nginx",
                    namespace="default", user="alice")
-    rels = cfgs["get-pods"].checks[0].generate(i)
+    rels = cfgs["pod-get"].checks[0].generate(i)
     assert str(rels[0]) == "pod:default/nginx#view@user:alice"
-    # create-namespaces update resolution
+    # namespace-create update resolution
     i2 = ResolveInput.create(
         RequestInfo(verb="create", resource="namespaces", name="",
                     namespace=""),
         UserInfo(name="admin"),
         body=json.dumps({"metadata": {"name": "newns"}}).encode())
-    upd = cfgs["create-namespaces"].update
+    upd = cfgs["namespace-create"].update
     assert [str(r) for r in upd.creates[0].generate(i2)] == \
         ["namespace:newns#creator@user:admin"]
+    # the precondition is a filter: any creator at all ($ = match any)
     assert [str(r) for r in upd.preconditions_do_not_exist[0].generate(i2)] == \
-        ["namespace:newns#cluster@cluster:cluster"]
+        ["namespace:newns#creator@user:$"]
     # prefilter: lookup rel has $ resource id
-    pf = cfgs["list-watch-pods"].pre_filters[0]
+    pf = cfgs["pod-list-watch"].pre_filters[0]
     i3 = make_input(verb="list", resource="pods", name="", namespace="")
     rel = pf.rel.generate(i3)[0]
     assert rel.resource_id == "$"
@@ -275,11 +280,11 @@ check:
 def test_matcher():
     m = MapMatcher.from_yaml(REFERENCE_RULES)
     got = m.match(RequestMeta("get", "", "v1", "pods"))
-    assert [r.name for r in got] == ["get-pods"]
+    assert [r.name for r in got] == ["pod-get"]
     assert m.match(RequestMeta("deletecollection", "", "v1", "pods")) == []
     assert m.match(RequestMeta("get", "apps", "v1", "deployments")) == []
     got = m.match(RequestMeta("watch", "", "v1", "namespaces"))
-    assert [r.name for r in got] == ["list-watch-namespaces"]
+    assert [r.name for r in got] == ["namespace-list-watch"]
 
 
 def test_structured_relationship_template():

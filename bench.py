@@ -609,9 +609,9 @@ def _measure(args, result: dict) -> None:
     stage0 = _stage_snapshot()
     profiling = False
     if args.profile_dir:
-        # device timeline for the measured queries (the fixpoint dispatch
-        # is annotated "sdbkp:fixpoint", ops/reachability.py); view with
-        # tensorboard or xprof
+        # device timeline for the measured queries (the program's stages
+        # are annotated "sdbkp:<stage>", obs/trace.py; the fixpoint is the
+        # module jit_sdbkp_fixpoint); view with tensorboard or xprof
         import jax
 
         try:

@@ -16,7 +16,6 @@ deploy/rules.yaml), names are materialized lazily only for allowed ids.
 
 from __future__ import annotations
 
-import asyncio
 import logging
 from dataclasses import dataclass, field
 from typing import Optional
@@ -24,10 +23,12 @@ from typing import Optional
 log = logging.getLogger(__name__)
 
 from ..engine import Engine
+from ..obs.trace import tracer
 from ..rules.compile import PreFilter, RunnableRule
 from ..rules.expr import ExprError
 from ..rules.input import ResolveInput
 from ..rules.proxyrule import MATCHING_ID_FIELD_VALUE
+from ..utils.metrics import metrics
 
 
 class PreFilterError(Exception):
@@ -132,6 +133,15 @@ def run_prefilter_sync(engine: Engine, pf: PreFilter,
             rel.resource_type, rel.resource_relation,
             rel.subject_type, rel.subject_id, rel.subject_relation or None,
         )
+    with tracer.stage("prefilter_map",
+                      metrics.histogram("proxy_prefilter_map_seconds"),
+                      ids=len(ids)):
+        return _map_ids(pf, input, ids, strict)
+
+
+def _map_ids(pf: PreFilter, input: ResolveInput, ids, strict: bool
+             ) -> AllowedSet:
+    """Looked-up object ids -> the allowed (namespace, name) pairs."""
     allowed = AllowedSet()
     pairs = allowed.pairs
     # Vectorized fast paths for the dominant mapping forms, classified
@@ -190,5 +200,5 @@ async def run_prefilter(engine: Engine, pf: PreFilter,
     """Async wrapper so the device query overlaps the upstream kube request
     (the reference overlaps via goroutine+channel,
     responsefilterer.go:165-183)."""
-    return await asyncio.to_thread(run_prefilter_sync, engine, pf, input,
-                                   strict, lookup, context)
+    return await tracer.to_thread(run_prefilter_sync, engine, pf, input,
+                                  strict, lookup, context)

@@ -23,7 +23,6 @@ Mirrors /root/reference/pkg/authz/authz.go:23-194 (WithAuthorization):
 from __future__ import annotations
 
 import asyncio
-import time
 from dataclasses import dataclass, field
 from typing import Optional
 
@@ -168,11 +167,11 @@ def _audit(deps: AuthzDeps, info, user, *, allow: bool,
 async def _traced_upstream(deps: AuthzDeps, req: ProxyRequest
                            ) -> ProxyResponse:
     """The ONE upstream call site wrapper: times the kube-apiserver RTT
-    as a named child span + histogram and forwards the trace context as
-    a W3C ``traceparent`` header so the upstream's own telemetry can
-    stitch to ours."""
-    t0 = time.perf_counter()
-    with tracer.span("upstream") as sp:
+    as stage ``upstream`` (``proxy_upstream_seconds``) and forwards the
+    trace context as a W3C ``traceparent`` header so the upstream's own
+    telemetry can stitch to ours."""
+    with tracer.stage("upstream",
+                      metrics.histogram("proxy_upstream_seconds")) as sp:
         tp = sp.traceparent()
         if tp is not None:
             req.headers = {k: v for k, v in req.headers.items()
@@ -180,8 +179,6 @@ async def _traced_upstream(deps: AuthzDeps, req: ProxyRequest
             req.headers["traceparent"] = tp
         resp = await deps.upstream(req)
         sp.set("status", resp.status)
-    metrics.histogram("proxy_upstream_seconds").observe(
-        time.perf_counter() - t0)
     return resp
 
 
@@ -407,7 +404,7 @@ async def _authorized(req: ProxyRequest, deps: AuthzDeps, info, user,
         engine_sampled = verdict is None
         if verdict is None:
             with tracer.span("engine_dispatch", items=len(items)):
-                verdict = await asyncio.to_thread(
+                verdict = await tracer.to_thread(
                     run_checks, deps.engine, rules, input, items=items,
                     context=caveat_ctx)
         if not verdict:
@@ -493,14 +490,12 @@ async def _authorized(req: ProxyRequest, deps: AuthzDeps, info, user,
     prefilter_task = None
     if pf is not None:
         async def _traced_prefilter():
-            # ensure_future copies the contextvar context, so the span
-            # lands on this request's trace even though the prefilter
-            # runs concurrently with the upstream round trip
             with tracer.span("prefilter"):
                 return await run_prefilter(deps.engine, pf[1], input,
                                            context=caveat_ctx)
 
-        prefilter_task = asyncio.ensure_future(_traced_prefilter())
+        # concurrent with the upstream round trip
+        prefilter_task = tracer.spawn(_traced_prefilter)
     if ticket is not None and prefilter_task is None \
             and not run_postfilter and not run_postchecks:
         # nothing engine-bound overlaps or follows the upstream call:
@@ -531,16 +526,18 @@ async def _authorized(req: ProxyRequest, deps: AuthzDeps, info, user,
         try:
             # reference waits ≤10s for the concurrent prefilter
             # (responsefilterer.go:44,196-204)
-            allowed = await asyncio.wait_for(prefilter_task, timeout=10.0)
+            allowed = await prefilter_task.wait(10.0)
         except asyncio.TimeoutError:
             return kube_status(401, "prefilter timed out")
         except (PreFilterError, ExprError) as e:
             return kube_status(401, f"prefilter: {e}")
-        resp = apply_filter(resp, allowed, input)
+        with tracer.stage("body_filter",
+                          metrics.histogram("proxy_body_filter_seconds")):
+            resp = apply_filter(resp, allowed, input)
     if run_postfilter:
         try:
             with tracer.span("postfilter"):
-                resp = await asyncio.to_thread(
+                resp = await tracer.to_thread(
                     filter_list_response, deps.engine, post_filters,
                     input, resp, caveat_ctx)
         except ExprError as e:
@@ -562,7 +559,7 @@ async def _authorized(req: ProxyRequest, deps: AuthzDeps, info, user,
                     context=caveat_ctx)
                 post_cached = post_verdict is not None
                 if post_verdict is None:
-                    post_verdict = await asyncio.to_thread(
+                    post_verdict = await tracer.to_thread(
                         run_checks, deps.engine, rules, input, post=True,
                         items=post_items, context=caveat_ctx)
             _audit(deps, info, user, allow=bool(post_verdict),

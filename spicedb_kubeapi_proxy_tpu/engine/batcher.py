@@ -134,7 +134,7 @@ class LookupBatcher:
         import time
 
         from ..utils.metrics import metrics
-        from .engine import mask_pseudo_objects
+        from .engine import _count_dispatch_rows, mask_pseudo_objects
 
         metrics.counter("engine_lookup_batches_total").inc()
         metrics.counter("engine_lookups_total").inc(len(batch))
@@ -193,6 +193,7 @@ class LookupBatcher:
                 np.asarray(seeds, dtype=np.int32),
                 np.concatenate(q_parts), np.concatenate(qb_parts),
                 q_cache_key=key, q_contig_grid=grid)
+            _count_dispatch_rows(len(seeds))
         else:
             qfut = None
         observed = threading.Event()

@@ -228,6 +228,16 @@ def validate_relationship(schema: Schema, rel: Relationship) -> None:
             )
 
 
+def _count_dispatch_rows(rows: int) -> None:
+    """One device dispatch of ``rows`` subject rows (the batch axis B
+    of the fixpoint's state), counted where it is enqueued."""
+    metrics.counter("engine_dispatch_rows_total").inc(rows)
+    metrics.histogram(
+        "engine_dispatch_batch_rows",
+        buckets=(1, 2, 4, 8, 16, 64, 256, 1024, 4096, 16384, 65536),
+    ).observe(rows)
+
+
 class EngineFuture:
     """A dispatched engine query: ``result()`` blocks and post-processes.
     ``fut`` is a :class:`~...ops.reachability.QueryFuture` or ``None`` for
@@ -563,6 +573,10 @@ class Engine:
         # nnz = adjacency edges, M = slot space). Called only when the
         # graph CHANGED — compiled() itself is per-dispatch hot path
         metrics.gauge("engine_csr_nnz").set(cg.n_edges)
+        # the edges no dense block took (unpadded): what the sparse
+        # gather/segment path walks on every hop
+        metrics.gauge("engine_residual_edges").set(
+            cg.n_edges if cg.res_idx is None else len(cg.res_idx))
         metrics.gauge("engine_graph_slots").set(cg.M)
         metrics.gauge("engine_delta_occupancy").set(cg.n_delta)
         if cg.tier is not None:
@@ -588,7 +602,8 @@ class Engine:
                     return inc
             if self._compiled is None or \
                self._compiled.revision != self.store.revision:
-                self._compiled = self._compile_fresh()
+                with tracer.stage("graph_compile"):
+                    self._compiled = self._compile_fresh()
                 self._publish_graph_gauges(self._compiled)
             return self._compiled
 
@@ -952,31 +967,30 @@ class Engine:
         # wall ≈ one_chunk_encode + transport + device, not encode + both
         futs = []
         for s in range(0, n, chunk):
-            seeds, q_slots, q_batch = self._encode_checks(
-                cg, objs, items[s:s + chunk])
+            with tracer.stage("engine_encode",
+                              metrics.histogram("engine_encode_seconds")):
+                seeds, q_slots, q_batch = self._encode_checks(
+                    cg, objs, items[s:s + chunk])
             futs.append(backend.query_async(seeds, q_slots, q_batch,
                                             now=now, context=context,
                                             cav_req=cav_req))
+            _count_dispatch_rows(len(seeds))
         metrics.counter("engine_checks_total").inc(n)
-        metrics.histogram(
-            "engine_dispatch_batch_rows",
-            buckets=(1, 2, 4, 8, 16, 64, 256, 1024, 4096, 16384, 65536),
-        ).observe(n)
-        # leaf span (finished by fin, possibly on another thread): the
-        # device-side share of a check when a trace is active
-        dev_span = tracer.begin("device", kind="check", rows=n)
 
         def iters():
             return max(f.iterations() for f in futs)
 
         def fin(_):
-            out = [bool(x) for f in futs for x in f.result()]
+            with tracer.stage("device_wait", metrics.histogram(
+                    "engine_device_wait_seconds")) as wait:
+                out = [bool(x) for f in futs for x in f.result()]
             # engine_check_seconds covers the WHOLE bulk call including
             # host-side encode (what a caller experiences), not just
             # dispatch+device+readback as before the chunked pipeline
             metrics.histogram("engine_check_seconds").observe(
                 time.perf_counter() - t0)
             it = iters()
+            wait.set("fixpoint_iters", it)
             metrics.histogram("engine_fixpoint_iterations").observe(it)
             self._count_semiring_modes(futs)
             # caveat instances that resolved missing-context this call:
@@ -993,9 +1007,6 @@ class Engine:
                 metrics.counter(
                     "engine_caveat_denied_missing_context_total").inc(
                     missing)
-            if dev_span is not None:
-                dev_span.set("fixpoint_iters", it)
-                dev_span.finish()
             return out
 
         return EngineFuture(None, fin, iters=iters)
@@ -1011,7 +1022,9 @@ class Engine:
         mask, interner = self.lookup_resources_mask(
             resource_type, permission, subject_type, subject_id,
             subject_relation, now=now, context=context)
-        return mask_to_ids(mask, interner)
+        with tracer.stage("mask_to_ids",
+                          metrics.histogram("engine_mask_to_ids_seconds")):
+            return mask_to_ids(mask, interner)
 
     def lookup_subjects(self, resource_type: str, resource_id: str,
                         permission: str, subject_type: str,
@@ -1227,27 +1240,30 @@ class Engine:
             # answered", cache hits excluded
             metrics.counter("engine_lookups_total").inc()
             return EngineFuture(None, lambda _: (None, None))
-        seeds = np.asarray(
-            [cg.encode_subject(subject_type, subject_id, subject_relation, objs)],
-            dtype=np.int32,
-        )
-        qk = (off, n)
-        ent = self._q_host.get(qk)
-        if ent is None:
-            if len(self._q_host) >= 64:
-                try:
-                    # pop-with-default: concurrent lookups may race the
-                    # same oldest key (no lock on this path by design);
-                    # RuntimeError = the dict mutated between iter() and
-                    # next() — skip this eviction, the cache is bounded
-                    # by whoever wins
-                    self._q_host.pop(next(iter(self._q_host)), None)
-                except (StopIteration, RuntimeError):
-                    pass
-            ent = (off + np.arange(n, dtype=np.int32),
-                   np.zeros(n, dtype=np.int32))
-            self._q_host[qk] = ent
-        q_slots, q_batch = ent
+        with tracer.stage("engine_encode",
+                          metrics.histogram("engine_encode_seconds")):
+            seeds = np.asarray(
+                [cg.encode_subject(subject_type, subject_id,
+                                   subject_relation, objs)],
+                dtype=np.int32,
+            )
+            qk = (off, n)
+            ent = self._q_host.get(qk)
+            if ent is None:
+                if len(self._q_host) >= 64:
+                    try:
+                        # pop-with-default: concurrent lookups may race
+                        # the same oldest key (no lock on this path by
+                        # design); RuntimeError = the dict mutated between
+                        # iter() and next() — skip this eviction, the
+                        # cache is bounded by whoever wins
+                        self._q_host.pop(next(iter(self._q_host)), None)
+                    except (StopIteration, RuntimeError):
+                        pass
+                ent = (off + np.arange(n, dtype=np.int32),
+                       np.zeros(n, dtype=np.int32))
+                self._q_host[qk] = ent
+            q_slots, q_batch = ent
         t0 = time.perf_counter()
         # the query arrays are a pure function of (type, permission) slot
         # layout: cache their device copies across queries (the ~0.5MB
@@ -1258,16 +1274,16 @@ class Engine:
             q_cache_key=("lookup", off, n), q_contiguous=True,
             context=context)
         metrics.counter("engine_lookups_total").inc()
-        metrics.histogram(
-            "engine_dispatch_batch_rows",
-            buckets=(1, 2, 4, 8, 16, 64, 256, 1024, 4096, 16384, 65536),
-        ).observe(n)
-        dev_span = tracer.begin("device", kind="lookup", rows=n)
+        _count_dispatch_rows(len(seeds))
 
-        def fin(out):
+        def fin(_):
+            with tracer.stage("device_wait", metrics.histogram(
+                    "engine_device_wait_seconds")) as wait:
+                out = fut.result()
             metrics.histogram("engine_lookup_seconds").observe(
                 time.perf_counter() - t0)
             it = fut.iterations()
+            wait.set("fixpoint_iters", it)
             metrics.histogram("engine_fixpoint_iterations").observe(it)
             missing = getattr(fut, "caveats_missing", lambda: 0)()
             if missing:
@@ -1293,13 +1309,9 @@ class Engine:
             # workloads drift the dense phase onto the MXU pull path
             self._observe_occupancy(float(occ) / max(m.size, 1))
             self._count_semiring_modes((fut,))
-            if dev_span is not None:
-                dev_span.set("fixpoint_iters", it)
-                dev_span.set("frontier_occupancy", occ)
-                dev_span.finish()
             return m, interner
 
-        return EngineFuture(fut, fin)
+        return EngineFuture(None, fin, iters=fut.iterations)
 
     # -- durability ---------------------------------------------------------
 

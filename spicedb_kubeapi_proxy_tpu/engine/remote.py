@@ -1367,9 +1367,9 @@ class RemoteEngine:
         # so the engine host's spans stitch into this request's trace;
         # the rpc span brackets every attempt of this logical call —
         # under failover each endpoint tried appears as its own span
-        rpc_span = tracer.begin("engine_rpc", op=op,
-                                endpoint=self.dependency)
-        if rpc_span is not None:
+        rpc_span = tracer.span("engine_rpc", op=op,
+                               endpoint=self.dependency)
+        if rpc_span.traceparent() is not None:
             msg["tr"] = rpc_span.traceparent()
         payload = _pack(msg)
         attempts = (self.retries + 1) if op in _IDEMPOTENT_OPS else 1
@@ -1437,12 +1437,10 @@ class RemoteEngine:
                         dependency="engine-admission")
                 raise _ERROR_KINDS.get(kind, RemoteEngineError)(err)
         except BaseException as e:
-            if rpc_span is not None:
-                rpc_span.set("error", repr(e))
+            rpc_span.set("error", repr(e))
             raise
         finally:
-            if rpc_span is not None:
-                rpc_span.finish()
+            rpc_span.finish()
 
     def _transact(self, payload: bytes,
                   deadline: Optional[Deadline] = None):

@@ -14,25 +14,41 @@ Recording is TAIL-sampled: spans are buffered on the live trace and the
 keep/drop decision happens when the root finishes — error, shed, and
 slow-threshold traces are always kept, the rest kept with probability
 ``sample``. Kept traces land in a lock-sharded ring buffer served by
-``/debug/traces``. ``sample == 0`` disables tracing entirely: every hook
-degrades to a couple of attribute reads, so the hot path pays nothing
-measurable (the bench acceptance pin).
+``/debug/traces``. ``sample == 0`` records no span at all: every hook
+degrades to the profiler annotation (while a session runs) and, for a
+stage, its histogram observation — under two microseconds (the bench
+acceptance pin).
 
 Spans cross threads explicitly: ``contextvars`` carry the active span
 through ``asyncio`` tasks and ``asyncio.to_thread``, and executor-pool
 hops (which do NOT copy context) re-enter via ``capture()`` /
 ``activate()``.
+
+A STAGE (``tracer.stage(name, metrics.histogram(...))``) is how a step
+of the served path is measured, three ways at once: a span as above;
+an observation in a histogram of its own name, for EVERY request and
+not only the tail-sampled ones (what ``/metrics`` and the benchmark
+read); and a
+``jax.profiler.TraceAnnotation("sdbkp:<name>")``, so that while a
+profiler session runs the stage lies on the device trace's clock.
+Every span carries the annotation; ``stage`` adds the histogram.
 """
 
 from __future__ import annotations
 
+import asyncio
 import contextvars
 import random
+import sys
 import threading
 import time
 from collections import deque
 from contextlib import contextmanager
 from typing import Optional
+
+from ..utils.metrics import metrics
+
+ANNOTATION_PREFIX = "sdbkp:"
 
 _FLAG_SAMPLED = 0x01
 
@@ -80,6 +96,34 @@ def _flag_exception(trace: "Trace", e: BaseException) -> None:
         trace.flag("shed")
     else:
         trace.flag("error")
+
+
+def _annotation(name: str):
+    """An entered ``TraceAnnotation("sdbkp:<name>")``, or None. Best
+    effort, and only in a process that has imported jax already: without
+    it no profiler session can be running, and a proxy in front of a
+    remote engine must not pay jax's import for a name nobody records.
+    Nor is one built while no session runs: it would record nothing.
+    The event is written whole when the annotation exits, on whatever
+    thread that is, so a stage may cross an ``await`` or finish on a
+    worker thread."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    try:
+        cls = jax.profiler.TraceAnnotation
+        if not cls.is_enabled():
+            return None
+        ann = cls(ANNOTATION_PREFIX + name)
+        ann.__enter__()
+    except Exception:  # noqa: BLE001 - API drift must not break serving
+        return None
+    return ann
+
+
+def _end_annotation(ann) -> None:
+    if ann is not None:
+        ann.__exit__(None, None, None)
 
 
 def _new_trace_id() -> str:
@@ -157,6 +201,128 @@ class _NullSpan:
 
 
 NULL_SPAN = _NullSpan()
+
+
+class Stage:
+    """One measured stage, running from construction to ``finish()``:
+    span (when a trace is active and tracing is on), profiler annotation
+    and, at the finish, one observation in ``histogram`` (the call site
+    registers it by its literal name: the metrics contract). As a
+    context manager the span nests what runs inside it; a bare
+    ``finish()`` — from any thread, once — leaves it a leaf, for a stage
+    that ends elsewhere than it began. Built by :meth:`Tracer.stage`."""
+
+    __slots__ = ("_span", "_ann", "_hist", "_t0", "_token")
+
+    def __init__(self, span: Optional[Span], name: str, histogram=None):
+        self._span = span
+        self._hist = histogram
+        self._token = None
+        self._ann = _annotation(name)
+        self._t0 = time.perf_counter()
+
+    # the span's surface, so a call site reads the same with tracing off
+    def set(self, key: str, value) -> None:
+        if self._span is not None:
+            self._span.set(key, value)
+
+    def traceparent(self):
+        return None if self._span is None else self._span.traceparent()
+
+    def finish(self) -> None:
+        if self._t0 is None:
+            return
+        dt, self._t0 = time.perf_counter() - self._t0, None
+        _end_annotation(self._ann)
+        if self._hist is not None:
+            self._hist.observe(dt)
+        if self._span is not None:
+            self._span.finish()
+
+    def __enter__(self) -> "Stage":
+        if self._span is not None:
+            self._token = _CURRENT.set((self._span.trace,
+                                        self._span.span_id))
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        if self._span is not None:
+            if exc is not None:
+                self._span.set("error", repr(exc))
+                _flag_exception(self._span.trace, exc)
+            _CURRENT.reset(self._token)
+        self.finish()
+
+
+class _LoopWait:
+    """Stage ``loop_wait`` (``proxy_loop_wait_seconds``) over one trip
+    through the event loop's queue: ``start()`` where the work ends, on
+    whatever thread; ``finish()`` where the coroutine that waited for it
+    runs again. Either may come alone, or ``start()`` late (the waiter
+    was cancelled): what started is finished, and after ``finish()``
+    nothing starts."""
+
+    __slots__ = ("_tracer", "_stage", "_closed")
+
+    def __init__(self, tracer: "Tracer"):
+        self._tracer = tracer
+        self._stage = None
+        self._closed = False
+
+    def start(self) -> None:
+        if self._closed:
+            return
+        self._stage = self._tracer.stage(
+            "loop_wait", metrics.histogram("proxy_loop_wait_seconds"))
+        if self._closed:  # finish() ran meanwhile, on the loop's thread
+            self._stage.finish()
+
+    def finish(self) -> None:
+        self._closed = True
+        if self._stage is not None:
+            self._stage.finish()
+
+
+class SpawnedTask:
+    """A task started beside the coroutine that will ``wait()`` for it.
+    It crosses the event loop's queue twice more than the ``to_thread``
+    inside it does, and both are stage ``loop_wait`` too: from the spawn
+    until its first step runs, and from its last line until the waiting
+    coroutine runs again — the latter only if that coroutine was in
+    ``wait()`` by then (before, it waits for something else, and the
+    time is that thing's). On a loop busy with other requests' work
+    these are most of a request. Built by :meth:`Tracer.spawn`."""
+
+    __slots__ = ("task", "_tracer", "_first", "_last")
+
+    def __init__(self, tracer: "Tracer", fn):
+        self._tracer = tracer
+        self._first = _LoopWait(tracer)
+        self._first.start()
+        self._last = None
+        # ensure_future copies the contextvar context: spans opened in
+        # the task land on the spawning request's trace
+        self.task = asyncio.ensure_future(self._run(fn))
+
+    async def _run(self, fn):
+        self._first.finish()
+        try:
+            return await fn()
+        finally:
+            if self._last is not None:
+                self._last.start()
+
+    def cancel(self) -> None:
+        self.task.cancel()
+        self._first.finish()  # its first step may never run
+
+    async def wait(self, timeout: float):
+        """The task's result, as ``asyncio.wait_for`` gives it."""
+        self._last = _LoopWait(self._tracer)
+        try:
+            return await asyncio.wait_for(self.task, timeout)
+        finally:
+            self._last.finish()
 
 
 class Trace:
@@ -298,8 +464,12 @@ class Tracer:
         """Open a ROOT span (proxy ingress): adopts the trace_id from a
         valid incoming ``traceparent``, mints one otherwise. Exiting the
         context finishes the trace and runs the tail-sampling decision."""
+        ann = _annotation(name)
         if not self.enabled:
-            yield NULL_SPAN
+            try:
+                yield NULL_SPAN
+            finally:
+                _end_annotation(ann)
             return
         parsed = parse_traceparent(traceparent)
         trace = Trace(parsed[0] if parsed else None)
@@ -325,43 +495,62 @@ class Tracer:
             raise
         finally:
             _CURRENT.reset(token)
+            _end_annotation(ann)
             root.finish()
             with self._live_lock:
                 if self._live.get(trace.trace_id) is trace:
                     del self._live[trace.trace_id]
             self._tail_decide(trace, root)
 
-    @contextmanager
-    def span(self, name: str, **attrs):
-        """A child span of whatever is active; a no-op stand-in when
-        nothing is (or tracing is off). Exceptions mark the span AND flag
-        the trace as error before propagating."""
+    def stage(self, name: str, histogram=None, **attrs) -> Stage:
+        """Start a :class:`Stage`: a child span of whatever is active
+        (none when nothing is, or tracing is off), the profiler
+        annotation ``sdbkp:<name>`` and, given a ``histogram``
+        (``metrics.histogram("<stage>_seconds")`` at the call site), an
+        observation of the stage's seconds at its finish. ``with``
+        it where it nests other spans; ``finish()`` it by hand where it
+        ends on another thread. Exceptions mark the span AND flag the
+        trace as error before propagating."""
         cur = _CURRENT.get()
-        if cur is None or not self.enabled:
-            yield NULL_SPAN
-            return
-        trace, parent = cur
-        sp = Span(trace, parent, name, attrs)
-        token = _CURRENT.set((trace, sp.span_id))
-        try:
-            yield sp
-        except BaseException as e:
-            sp.set("error", repr(e))
-            _flag_exception(trace, e)
-            raise
-        finally:
-            _CURRENT.reset(token)
-            sp.finish()
+        span = None
+        if cur is not None and self.enabled:
+            span = Span(cur[0], cur[1], name, attrs)
+        return Stage(span, name, histogram)
 
-    def begin(self, name: str, **attrs) -> Optional[Span]:
-        """Open a LEAF span without touching the context — for async
-        dispatch paths whose completion callback runs elsewhere; the
-        caller owns ``finish()``. Children never nest under it."""
-        cur = _CURRENT.get()
-        if cur is None or not self.enabled:
-            return None
-        trace, parent = cur
-        return Span(trace, parent, name, attrs)
+    def span(self, name: str, **attrs) -> Stage:
+        """A stage with no histogram: span + annotation. ``with`` it to
+        nest children under it; or, for async dispatch paths whose
+        completion callback runs elsewhere, keep it a LEAF: never
+        entered, the caller owns ``finish()``."""
+        return self.stage(name, None, **attrs)
+
+    async def to_thread(self, fn, *args, **kwargs):
+        """``asyncio.to_thread`` with both hand-overs measured: stage
+        ``executor_wait`` runs from the submit to the first line in the
+        worker (``proxy_executor_wait_seconds``), stage ``loop_wait``
+        from the worker's last line until the event loop runs the
+        awaiting coroutine again (``proxy_loop_wait_seconds``)."""
+        wait = self.stage("executor_wait",
+                          metrics.histogram("proxy_executor_wait_seconds"))
+        back = _LoopWait(self)
+
+        def in_worker():
+            wait.finish()
+            try:
+                return fn(*args, **kwargs)
+            finally:
+                back.start()
+
+        try:
+            return await asyncio.to_thread(in_worker)
+        finally:
+            wait.finish()  # cancelled before a worker took it
+            back.finish()
+
+    def spawn(self, fn) -> "SpawnedTask":
+        """``asyncio.ensure_future(fn())`` with the task's own trips
+        through the event loop's queue measured: :class:`SpawnedTask`."""
+        return SpawnedTask(self, fn)
 
     @contextmanager
     def adopt(self, wire: Optional[str], name: str, **attrs):

@@ -171,6 +171,7 @@ def bit_or_matmul(a_bits: jax.Array, v_bits: jax.Array, n_b: int) -> jax.Array:
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((n_dst, LANES), jnp.int32),
         interpret=_interpret(),
+        name="sdbkp_bit_hop",
     )(a_bits, v_bits)
     return out[:, :n_b].astype(jnp.uint8)
 
@@ -283,6 +284,7 @@ def dense_or_matmul(A: jax.Array, frontier: jax.Array) -> jax.Array:
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((b32, n_dst), jnp.int32),
         interpret=_dense_interpret(),
+        name="sdbkp_dense_hop",
     )(f, A)
     return (out[:b] > 0).astype(jnp.uint8)
 

@@ -58,6 +58,7 @@ import jax.numpy as jnp
 
 from . import bitprop, semiring
 from .. import native
+from ..obs.trace import tracer
 from ..utils.metrics import metrics
 from ..models.schema import (
     Arrow,
@@ -153,12 +154,21 @@ def _jit_run_for(cg: "CompiledGraph", active: Optional[tuple] = None):
         run = _JIT_CACHE.get(sig)
         if run is None:
             _TRACE_BUILDS += 1
-            run = jax.jit(partial(_run, cg.run_meta(active)),
+            run = jax.jit(fixpoint_program(cg.run_meta(active)),
                           static_argnames=("max_iters", "q_contig_len",
                                            "q_contig_rows"))
             if len(_JIT_CACHE) >= _JIT_CACHE_MAX:
                 _JIT_CACHE.pop(next(iter(_JIT_CACHE)))
             _JIT_CACHE[sig] = run
+    return run
+
+
+def fixpoint_program(meta: "RunMeta"):
+    """``_run`` over ``meta``, under the name the device trace shows:
+    jitted, it is the XLA module ``jit_sdbkp_fixpoint`` (a bare partial
+    has no name, and the profiler's module line read ``jit__unknown``)."""
+    run = partial(_run, meta)
+    run.__name__ = run.__qualname__ = "sdbkp_fixpoint"
     return run
 
 
@@ -1083,9 +1093,6 @@ class CompiledGraph:
                 cav_req, _ = cav.encode_request(context, now_abs)
         else:
             cav_req = ()
-        # named span in jax.profiler traces (bench --profile-dir / any
-        # caller-managed jax.profiler.trace): lets a device timeline
-        # attribute time to the reachability dispatch specifically
         # per-mode jitted entry (force_mode flips between dispatches must
         # hit their own trace); built lazily under the shared cache lock
         mk = semiring.resolved_mode()
@@ -1110,7 +1117,11 @@ class CompiledGraph:
             if run is None:
                 run = _jit_run_for(self, active)
                 d[rk] = run
-        with jax.profiler.TraceAnnotation("sdbkp:fixpoint"):
+        # the enqueue alone: the call returns once the program is handed
+        # to the device's queue, not when it has run
+        with tracer.stage("engine_enqueue",
+                          metrics.histogram("engine_enqueue_seconds"),
+                          rows=B):
             # seeds ride the jit call as a host array: jax folds the
             # transfer into the dispatch instead of a separate device_put
             # round trip
@@ -1502,6 +1513,9 @@ def _run(cg: "RunMeta", blocks, blocks_bits, src, dst, exp_rel, cav,
             dsrc, ddst, dact, Vflat, occ, crossover,
             level=k, mode=cg.spmm_mode)
 
+    # jax.named_scope below names the phases for HLO dumps and xprof
+    # (op_name metadata only: the computation is the same)
+
     def step(V):
         prop, is_push = prop_level(V, 0)
         return _apply_program(
@@ -1516,42 +1530,47 @@ def _run(cg: "RunMeta", blocks, blocks_bits, src, dst, exp_rel, cav,
         V2, is_push = step(V)
         return V2, jnp.any(V2 != V), it + 1, n_push + is_push
 
-    V, still_changing, iters, n_push = jax.lax.while_loop(
-        cond, body, (base, jnp.bool_(True), 0, jnp.int32(0)))
+    with jax.named_scope("core"):
+        V, still_changing, iters, n_push = jax.lax.while_loop(
+            cond, body, (base, jnp.bool_(True), 0, jnp.int32(0)))
     # acyclic levels: one application each. No phase may be skipped —
     # incremental delta edges can target any level and only this phase's
     # re-application establishes their values. The merge writes only the
     # level's (row-aligned) slot ranges, so finalized lower levels are
     # untouched and no dense masks exist anywhere.
     for k in range(1, cg.n_levels + 1):
-        progs_k = [p for p in cg.programs if p.level == k]
-        prop, is_push = prop_level(V, k)
-        n_push = n_push + is_push
-        propb = prop | baseflat
-        Vflat = V.reshape(B, Mp)
-        for off, size in cg.level_ranges[k - 1]:
-            Vflat = jax.lax.dynamic_update_slice(
-                Vflat, jax.lax.dynamic_slice(propb, (0, off), (B, size)),
-                (0, off))
-        V = _apply_program(cg, Vflat.reshape(B, rows, LANE), progs_k)
+        with jax.named_scope(f"level{k}"):
+            progs_k = [p for p in cg.programs if p.level == k]
+            prop, is_push = prop_level(V, k)
+            n_push = n_push + is_push
+            propb = prop | baseflat
+            Vflat = V.reshape(B, Mp)
+            for off, size in cg.level_ranges[k - 1]:
+                Vflat = jax.lax.dynamic_update_slice(
+                    Vflat,
+                    jax.lax.dynamic_slice(propb, (0, off), (B, size)),
+                    (0, off))
+            V = _apply_program(cg, Vflat.reshape(B, rows, LANE), progs_k)
     # still_changing at loop exit means we hit max_iters before convergence;
     # surface it so the host can raise instead of silently denying
-    if q_contig_len:
-        # contiguous query window (q_slots/q_batch are scalars: start slot
-        # and start row): a dynamic_slice streams the window at HBM rate,
-        # where the general fancy-index gather below is latency-bound
-        # random access — on a v5e chip that gather was 31% of the whole
-        # query's device time for the list-filter shape (which always
-        # reads one type's full, contiguous permission range).
-        # q_contig_rows > 1 is the fused-batch grid (engine/batcher.py:
-        # R same-window rows); [R, L] row-major flatten is exactly the
-        # concatenated per-row query order, so no re-mapping is needed.
-        out = jax.lax.dynamic_slice(
-            V.reshape(B, Mp), (q_batch, q_slots),
-            (q_contig_rows, q_contig_len)
-        ).reshape(q_contig_rows * q_contig_len).astype(jnp.bool_)
-    else:
-        out = V.reshape(B, Mp)[q_batch, q_slots].astype(jnp.bool_)
+    with jax.named_scope("readout"):
+        if q_contig_len:
+            # contiguous query window (q_slots/q_batch are scalars: start
+            # slot and start row): a dynamic_slice streams the window at
+            # HBM rate, where the general fancy-index gather below is
+            # latency-bound random access — on a v5e chip that gather was
+            # 31% of the whole query's device time for the list-filter
+            # shape (which always reads one type's full, contiguous
+            # permission range). q_contig_rows > 1 is the fused-batch grid
+            # (engine/batcher.py: R same-window rows); [R, L] row-major
+            # flatten is exactly the concatenated per-row query order, so
+            # no re-mapping is needed.
+            out = jax.lax.dynamic_slice(
+                V.reshape(B, Mp), (q_batch, q_slots),
+                (q_contig_rows, q_contig_len)
+            ).reshape(q_contig_rows * q_contig_len).astype(jnp.bool_)
+        else:
+            out = V.reshape(B, Mp)[q_batch, q_slots].astype(jnp.bool_)
     return out, jnp.logical_not(still_changing), iters, n_push, cav_missing
 
 

@@ -152,19 +152,21 @@ def propagate(block_meta, blocks, blocks_bits, src, dst, act,
     Mp = Vflat.shape[1]
     # residual edges: gather + segment-max (boolean OR) over the slot
     # axis; trash padding lands in the trash row
-    if src.shape[0]:
-        gathered = (Vflat[:, src] & act[None, :]).T  # [E_slice, B]
-        prop = jax.ops.segment_max(
-            gathered, dst, num_segments=Mp, indices_are_sorted=True
-        ).T  # [B, Mp]
-    else:
-        prop = jnp.zeros((B, Mp), dtype=jnp.uint8)
-    # delta overlay: applied at EVERY level (contributions outside the
-    # level's ranges are dropped by the caller's range-scoped merge)
-    gathered_d = (Vflat[:, dsrc] & dact[None, :]).T  # [D_pad, B]
-    prop = prop | jax.ops.segment_max(
-        gathered_d, ddst, num_segments=Mp, indices_are_sorted=False
-    ).T
+    with jax.named_scope("residual"):
+        if src.shape[0]:
+            gathered = (Vflat[:, src] & act[None, :]).T  # [E_slice, B]
+            prop = jax.ops.segment_max(
+                gathered, dst, num_segments=Mp, indices_are_sorted=True
+            ).T  # [B, Mp]
+        else:
+            prop = jnp.zeros((B, Mp), dtype=jnp.uint8)
+        # delta overlay: applied at EVERY level (contributions outside
+        # the level's ranges are dropped by the caller's range-scoped
+        # merge)
+        gathered_d = (Vflat[:, dsrc] & dact[None, :]).T  # [D_pad, B]
+        prop = prop | jax.ops.segment_max(
+            gathered_d, ddst, num_segments=Mp, indices_are_sorted=False
+        ).T
 
     sel = [(bm, A, Ab)
            for bm, A, Ab in zip(block_meta, blocks, blocks_bits)
@@ -205,15 +207,16 @@ def propagate(block_meta, blocks, blocks_bits, src, dst, act,
         return pull_one(bm, A, frontier)
 
     def apply_blocks(p, use_push: bool):
-        for bm, A, Ab in sel:
-            f = frontier_of(bm)
-            contrib = (push_one(bm, A, Ab, f) if use_push
-                       else pull_one(bm, A, f))
-            cur = jax.lax.dynamic_slice(
-                p, (0, bm.dst_off), (B, bm.n_dst))
-            p = jax.lax.dynamic_update_slice(
-                p, cur | contrib, (0, bm.dst_off))
-        return p
+        with jax.named_scope("dense/push" if use_push else "dense/pull"):
+            for bm, A, Ab in sel:
+                f = frontier_of(bm)
+                contrib = (push_one(bm, A, Ab, f) if use_push
+                           else pull_one(bm, A, f))
+                cur = jax.lax.dynamic_slice(
+                    p, (0, bm.dst_off), (B, bm.n_dst))
+                p = jax.lax.dynamic_update_slice(
+                    p, cur | contrib, (0, bm.dst_off))
+            return p
 
     push_differs = any(Ab is not None and B <= bitprop.BIT_B_MAX
                        for _, _, Ab in sel)

@@ -365,20 +365,16 @@ class MirroredEngine:
         the mutation is applied locally (outcome: unknown to the
         caller, exactly like a write whose response connection died),
         never acknowledged as durable when it is not."""
-        import time as _time
-
         from ..obs.trace import tracer
         from ..utils.metrics import metrics
 
-        t_wait0 = _time.perf_counter()
-        ack_span = tracer.begin("replication_ack_wait", seq=seq)
+        ack = tracer.stage(
+            "replication_ack_wait",
+            metrics.histogram("engine_replication_ack_seconds"), seq=seq)
         try:
             self._wait_replicated_inner(seq)
         finally:
-            metrics.histogram("engine_replication_ack_seconds").observe(
-                _time.perf_counter() - t_wait0)
-            if ack_span is not None:
-                ack_span.finish()
+            ack.finish()
 
     def _wait_replicated_inner(self, seq: int) -> None:
         import time as _time

@@ -83,7 +83,9 @@ import jax.numpy as jnp
 from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..obs.trace import tracer
 from ..ops import semiring
+from ..utils.metrics import metrics
 from ..ops.reachability import (
     CompiledGraph,
     ConvergenceError,
@@ -415,6 +417,8 @@ class ShardedGraph:
         fn = partial(_run_sharded, self.cg.run_meta(), self._block_meta,
                      self.ng, max_iters=self.max_iters,
                      k_steps=self.k_steps)
+        # the name the device trace shows (a bare partial has none)
+        fn.__name__ = fn.__qualname__ = "sdbkp_fixpoint_sharded"
         # check_vma off: the all_gather'ed result and the pmax'ed flags
         # ARE replicated, but the checker infers them varying over "data"
         return jax.jit(shard_map(
@@ -781,12 +785,15 @@ class ShardedGraph:
         # process or many (a committed local array would need a reshard
         # from a non-global placement under multi-controller)
         crossover = np.float32(getattr(self.cg, "spmm_crossover", 1.0))
-        out, converged, iters, checks, n_push, cav_missing = self._run(
-            self._level_edges, self._blocks,
-            self._dsrc, self._ddst, self._dexp, self._dcav,
-            self._cav_static, cav_req,
-            seeds_pad, grid, now_rel, crossover,
-        )
+        with tracer.stage("engine_enqueue",
+                          metrics.histogram("engine_enqueue_seconds"),
+                          rows=len(seeds_pad)):
+            out, converged, iters, checks, n_push, cav_missing = self._run(
+                self._level_edges, self._blocks,
+                self._dsrc, self._ddst, self._dexp, self._dcav,
+                self._cav_static, cav_req,
+                seeds_pad, grid, now_rel, crossover,
+            )
         try:
             out.copy_to_host_async()
             converged.copy_to_host_async()

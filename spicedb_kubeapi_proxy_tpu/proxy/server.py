@@ -22,6 +22,7 @@ from typing import Optional
 from urllib.parse import parse_qs, unquote, urlsplit
 
 from ..authz import AuthzDeps, authorize
+from ..obs.profile import install_gc_hook
 from ..obs.trace import tracer
 from ..proxy.authn import (
     AuthenticationError,
@@ -379,6 +380,7 @@ class Server:
     # -- TCP serving ---------------------------------------------------------
 
     async def start(self) -> int:
+        install_gc_hook()
         self._server = await asyncio.start_server(
             self._serve_connection, self.host, self.port,
             ssl=self.ssl_context)
@@ -445,8 +447,16 @@ class Server:
                 conn_hdr = next((v for k, v in req.headers.items()
                                  if k.lower() == "connection"), "")
                 keep_alive = conn_hdr.lower() != "close"
-                await _write_response(writer, resp)
-                if resp.stream is not None or not keep_alive:
+                if resp.stream is not None:
+                    # lasts as long as the watch does: not a stage
+                    await _write_response(writer, resp)
+                    return
+                # after the root span and proxy_request_seconds closed:
+                # histogram and annotation, no span
+                with tracer.stage("response_write", metrics.histogram(
+                        "proxy_response_write_seconds")):
+                    await _write_response(writer, resp)
+                if not keep_alive:
                     return
         except (asyncio.IncompleteReadError, ConnectionResetError):
             pass

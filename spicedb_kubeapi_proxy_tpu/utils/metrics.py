@@ -65,7 +65,10 @@ class Histogram:
         self.total = 0.0
         self.n = 0
         self.max = 0.0  # largest observation: bounds the overflow bucket
-        self._lock = threading.Lock()
+        # re-entrant: the collector's callback (obs/profile.py) observes
+        # its histogram from inside whatever allocation started the
+        # collection, snapshot()'s own list copy among them
+        self._lock = threading.RLock()
 
     def observe(self, v: float) -> None:
         with self._lock:

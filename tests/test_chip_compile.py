@@ -14,7 +14,6 @@ module: an entry compiled for a described device cannot be read back.
 """
 
 import time
-from functools import partial
 
 import numpy as np
 import pytest
@@ -161,7 +160,7 @@ def test_whole_fixpoint_compiles_in_auto(one_chip, compiled_kernels, graph):
     cav_req, _ = cg.caveats.encode_request({"ip": "10.1.2.3"}, time.time())
     n_pod = cg.type_sizes["pod"]
     with semiring.force_mode("auto"):
-        run = jax.jit(partial(reachability._run, cg.run_meta()),
+        run = jax.jit(reachability.fixpoint_program(cg.run_meta()),
                       static_argnames=("max_iters", "q_contig_len",
                                        "q_contig_rows"))
         text = run.lower(
@@ -176,6 +175,10 @@ def test_whole_fixpoint_compiles_in_auto(one_chip, compiled_kernels, graph):
     # every block: its dense kernel in the pull branch, its bit kernel in
     # the push branch
     assert text.count("tpu_custom_call") >= 2 * len(cg.blocks)
+    # the names a device trace is reduced by: the module line reads
+    # jit_sdbkp_fixpoint, the kernels' operations sdbkp_*_hop
+    assert text.startswith("HloModule jit_sdbkp_fixpoint")
+    assert "sdbkp_bit_hop" in text and "sdbkp_dense_hop" in text
 
 
 def test_mesh_fixpoint_compiles_and_joins_as_int32(topo, one_chip,
@@ -221,6 +224,8 @@ def test_mesh_fixpoint_compiles_and_joins_as_int32(topo, one_chip,
         ).compile().as_text()
     assert "tpu_custom_call" in text
     assert "all-gather" in text
+    # shard_map keeps the partial's given name: what XLA Modules reads
+    assert text.startswith("HloModule jit_sdbkp_fixpoint_sharded")
     joins = re.findall(r"= \(?(\w+)\[[^\]]*\][^=]* all-reduce(?:-start)?\(",
                        text)
     assert joins and set(joins) == {"s32"}, joins

@@ -627,8 +627,389 @@ def test_trace_overhead_disabled_is_negligible():
     for _ in range(n):
         with tracer.span("x"):
             pass
-        tracer.begin("y")
+        tracer.span("y").finish()
     per_call = (time.perf_counter() - t0) / (2 * n)
     # generous bound: even a slow CI box does a no-op contextvar check in
     # well under 20us
     assert per_call < 20e-6, f"{per_call * 1e6:.2f}us per disabled hook"
+
+
+# -- stages: span + histogram + profiler annotation (ISSUE 26) ----------------
+
+
+def _hist_count(name, **labels):
+    s = metrics.hist_snapshot(name, **labels)
+    return 0 if s is None else s["n"]
+
+
+def _profiled(tmp_path, body):
+    """Run ``body()`` under a CPU ``jax.profiler`` session -> the host
+    plane's ``sdbkp:`` events as {name: [duration_ns]}."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                            / "*.xplane.pb"))
+    out = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:CPU"):
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name.startswith("sdbkp:"):
+                        out.setdefault(e.name, []).append(e.duration_ns)
+    return out
+
+
+def test_stage_across_an_await_is_span_histogram_and_annotation(tmp_path):
+    """Two stages interleave on one event loop: each is written whole,
+    with its own length, three ways at once."""
+    n0 = _hist_count("test_stage_await_seconds")
+
+    async def one(name, seconds):
+        with tracer.stage(
+                name, metrics.histogram("test_stage_await_seconds")):
+            await asyncio.sleep(seconds)
+
+    async def both():
+        with tracer.start("request"):
+            await asyncio.gather(one("long_stage", 0.06),
+                                 one("short_stage", 0.02))
+
+    events = _profiled(tmp_path, lambda: asyncio.run(both()))
+    (long_ns,), (short_ns,) = (events["sdbkp:long_stage"],
+                               events["sdbkp:short_stage"])
+    assert long_ns >= 0.06e9 > short_ns >= 0.02e9
+    assert len(events["sdbkp:request"]) == 1  # the root carries one too
+    assert _hist_count("test_stage_await_seconds") == n0 + 2
+    (t,) = tracer.recent()
+    spans = {s["name"]: s for s in t["spans"]}
+    assert spans["long_stage"]["duration_us"] >= 60_000
+    assert 20_000 <= spans["short_stage"]["duration_us"] < 60_000
+
+
+def test_stage_finished_on_another_thread(tmp_path):
+    """A stage begun here and finished by a worker thread (the shape of
+    ``executor_wait``): one span, one observation, one annotation."""
+    import threading
+
+    n0 = _hist_count("test_stage_thread_seconds")
+
+    def body():
+        with tracer.start("request"):
+            st = tracer.stage("handed_over", metrics.histogram(
+                "test_stage_thread_seconds"))
+            t = threading.Thread(target=st.finish)
+            t.start()
+            t.join()
+            st.finish()  # a second finish is ignored
+
+    events = _profiled(tmp_path, body)
+    assert len(events["sdbkp:handed_over"]) == 1
+    assert _hist_count("test_stage_thread_seconds") == n0 + 1
+    (t,) = tracer.recent()
+    assert [s["name"] for s in t["spans"]].count("handed_over") == 1
+
+
+def test_stage_with_tracing_off_still_feeds_its_histogram():
+    tracer.configure(sample=0.0)
+    n0 = _hist_count("test_stage_off_seconds")
+    with tracer.start("request"):
+        with tracer.stage("unsampled", metrics.histogram(
+                "test_stage_off_seconds")) as st:
+            assert st.traceparent() is None
+            st.set("ignored", 1)
+    assert _hist_count("test_stage_off_seconds") == n0 + 1
+    assert tracer.recent() == []
+
+
+def test_executor_wait_grows_when_the_pool_is_saturated():
+    """``tracer.to_thread`` measures submit -> first line in the worker:
+    with every worker of a one-thread pool busy, the wait is the busy
+    time; with the pool free it is a thread hand-off. The way back is
+    ``loop_wait``: it grows while the loop is kept from the coroutine."""
+    from concurrent.futures import ThreadPoolExecutor
+
+    def waits_since(before):
+        s = metrics.hist_snapshot("proxy_executor_wait_seconds")
+        return s["total"] - before["total"], s["n"] - before["n"]
+
+    async def go():
+        loop = asyncio.get_running_loop()
+        loop.set_default_executor(ThreadPoolExecutor(max_workers=1))
+        await tracer.to_thread(time.sleep, 0)  # the histogram exists
+        before = metrics.hist_snapshot("proxy_executor_wait_seconds")
+        await tracer.to_thread(time.sleep, 0)
+        free, n = waits_since(before)
+        assert n == 1 and free < 0.1
+        before = metrics.hist_snapshot("proxy_executor_wait_seconds")
+        busy = loop.run_in_executor(None, time.sleep, 0.3)
+        assert await tracer.to_thread(lambda: "ran") == "ran"
+        await busy
+        saturated, n = waits_since(before)
+        assert n == 1 and saturated >= 0.25 > free
+        back = metrics.hist_snapshot("proxy_loop_wait_seconds")
+        # the worker is done at once; the loop is held for 0.2 s more
+        loop.call_later(0.02, time.sleep, 0.2)
+        await tracer.to_thread(time.sleep, 0.05)
+        held = metrics.hist_snapshot("proxy_loop_wait_seconds")
+        assert held["n"] == back["n"] + 1
+        assert held["total"] - back["total"] >= 0.1
+
+    asyncio.run(go())
+
+
+async def _spawn_cancelled_before_its_first_step():
+    task = tracer.spawn(lambda: asyncio.sleep(0))
+    task.cancel()
+    with pytest.raises(asyncio.CancelledError):
+        await task.task
+    return 1  # the spawn's crossing, closed by cancel()
+
+
+async def _spawn_waited_for_while_it_runs():
+    task = tracer.spawn(lambda: asyncio.sleep(0.02))
+    await task.wait(1.0)
+    return 2  # to its first step, and back to the waiter
+
+
+async def _spawn_done_before_anyone_waits():
+    task = tracer.spawn(lambda: asyncio.sleep(0))
+    await asyncio.sleep(0.02)
+    await task.wait(1.0)
+    return 1  # the way back was nobody's wait
+
+
+async def _to_thread_cancelled_while_the_worker_runs():
+    import threading
+
+    release = threading.Event()
+    waiter = asyncio.ensure_future(tracer.to_thread(release.wait, 5))
+    await asyncio.sleep(0.02)
+    waiter.cancel()
+    with pytest.raises(asyncio.CancelledError):
+        await waiter
+    release.set()
+    await asyncio.sleep(0.05)  # the worker's last line has run
+    return 0  # nobody is left to hand over to
+
+
+@pytest.mark.parametrize("case", [
+    _spawn_cancelled_before_its_first_step,
+    _spawn_waited_for_while_it_runs,
+    _spawn_done_before_anyone_waits,
+    _to_thread_cancelled_while_the_worker_runs])
+def test_loop_wait_crossings_close_whatever_is_cancelled(case, monkeypatch):
+    """Every ``loop_wait`` stage that opens is finished (annotation
+    exited, histogram fed), and none opens for a waiter that is gone."""
+    opened = []
+    stage = tracer.stage
+
+    def recording(name, *a, **kw):
+        opened.append((name, stage(name, *a, **kw)))
+        return opened[-1][1]
+
+    monkeypatch.setattr(tracer, "stage", recording)
+    n0 = _hist_count("proxy_loop_wait_seconds")
+    expected = asyncio.run(case())
+    assert [st._t0 for _, st in opened] == [None] * len(opened)
+    assert [n for n, _ in opened].count("loop_wait") == expected
+    assert _hist_count("proxy_loop_wait_seconds") == n0 + expected
+
+
+LIST_STAGES = {"rule_match", "cache_probe", "prefilter", "executor_wait",
+               "engine_encode", "engine_enqueue", "device_wait",
+               "mask_to_ids", "prefilter_map", "loop_wait", "upstream",
+               "body_filter"}
+GET_STAGES = {"rule_match", "cache_probe", "engine_dispatch",
+              "executor_wait", "engine_encode", "engine_enqueue",
+              "device_wait", "loop_wait", "upstream"}
+STAGE_HISTOGRAMS = (
+    "proxy_executor_wait_seconds", "proxy_loop_wait_seconds",
+    "engine_encode_seconds",
+    "engine_enqueue_seconds", "engine_device_wait_seconds",
+    "engine_mask_to_ids_seconds", "proxy_prefilter_map_seconds",
+    "proxy_body_filter_seconds", "proxy_upstream_seconds",
+    "proxy_response_write_seconds")
+
+
+async def _serve_and_get(tmp_path, trace_sample, paths):
+    """A tiny served deployment over real TCP (so the response write is
+    there), its upstream 20 ms away as a near apiserver is. -> status
+    per path, after a namespace was created through the proxy."""
+    from fake_kube import FakeKube
+    from spicedb_kubeapi_proxy_tpu.proxy.options import Options
+
+    kube = FakeKube()
+
+    async def upstream(req):
+        await asyncio.sleep(0.02)
+        return await kube(req)
+
+    cfg = Options(
+        rule_content=RULES, upstream=upstream, bind_host="127.0.0.1",
+        bind_port=0, workflow_database_path=str(tmp_path / "dtx.sqlite"),
+        trace_sample=trace_sample,
+    ).complete()
+    await cfg.run()
+
+    async def http(method, path, body=b""):
+        reader, writer = await asyncio.open_connection(
+            "127.0.0.1", cfg.server.port)
+        writer.write((f"{method} {path} HTTP/1.1\r\nHost: x\r\n"
+                      "X-Remote-User: alice\r\nConnection: close\r\n"
+                      "Content-Type: application/json\r\n"
+                      f"Content-Length: {len(body)}\r\n\r\n").encode()
+                     + body)
+        await writer.drain()
+        raw = await reader.read()
+        writer.close()
+        return int(raw.split(b" ", 2)[1])
+
+    try:
+        assert await http("POST", "/api/v1/namespaces", json.dumps(
+            {"metadata": {"name": "team-a"}}).encode()) == 201
+        tracer.reset()
+        return [await http("GET", p) for p in paths]
+    finally:
+        await cfg.server.stop()
+        await cfg.workflow.shutdown()
+
+
+@pytest.mark.parametrize("path,stages", [
+    ("/api/v1/namespaces", LIST_STAGES),
+    ("/api/v1/namespaces/team-a", GET_STAGES),
+], ids=["list", "get"])
+def test_served_read_carries_every_stage(tmp_path, path, stages):
+    """Each step of a served read is a span of its own, so the root has
+    next to no time that is nobody's: under a tenth of it."""
+    assert asyncio.run(_serve_and_get(tmp_path, 1.0, [path])) == [200]
+    (t,) = [t for t in tracer.recent()
+            if t["spans"][-1]["attrs"].get("path") == path]
+    names = {s["name"] for s in t["spans"]}
+    assert stages <= names, stages - names
+    assert "device" not in names  # its three parts replaced it
+    root = next(s for s in t["spans"] if s["name"] == "request")
+    lo = root["start"]
+    covered = sorted(
+        (s["start"], s["start"] + s["duration_us"] / 1e6)
+        for s in t["spans"] if s["parent_id"] == root["span_id"])
+    own, at = root["duration_us"] / 1e6, lo
+    for a, b in covered:
+        own -= max(0.0, b - max(a, at))
+        at = max(at, b)
+    assert own < 0.1 * root["duration_us"] / 1e6, (own, root)
+
+
+def test_served_reads_feed_stage_histograms_with_tracing_off(tmp_path):
+    """``trace_sample`` 0: no span anywhere, and every stage histogram
+    of the two read paths still moves — they are what ``/metrics`` and
+    the benchmark read, for every request."""
+    before = {h: _hist_count(h) for h in STAGE_HISTOGRAMS}
+    rows0 = metrics.counter("engine_dispatch_rows_total").value
+    assert asyncio.run(_serve_and_get(
+        tmp_path, 0.0, ["/api/v1/namespaces",
+                        "/api/v1/namespaces/team-a"])) == [200, 200]
+    assert tracer.recent() == []
+    moved = {h: _hist_count(h) - before[h] for h in STAGE_HISTOGRAMS}
+    assert all(n >= 1 for n in moved.values()), moved
+    # one lookup and one check, a subject row each
+    assert metrics.counter("engine_dispatch_rows_total").value - rows0 >= 2
+    assert metrics.gauge("engine_residual_edges").value >= 1
+
+
+def test_gc_hook_times_collections_by_generation():
+    import gc
+
+    from spicedb_kubeapi_proxy_tpu.obs.profile import install_gc_hook
+
+    install_gc_hook()
+    install_gc_hook()  # once per process, however often it is asked
+    n0 = _hist_count("process_gc_seconds", generation=2)
+    gc.collect()
+    assert _hist_count("process_gc_seconds", generation=2) == n0 + 1
+
+
+def test_dispatch_batch_rows_counts_subject_rows_not_slots():
+    """``engine_dispatch_batch_rows`` is rows per device dispatch: a
+    lookup over many objects is one subject row, a bulk check of two
+    subjects two."""
+    from spicedb_kubeapi_proxy_tpu.engine import CheckItem, Engine
+    from spicedb_kubeapi_proxy_tpu.engine.store import WriteOp
+    from spicedb_kubeapi_proxy_tpu.models.tuples import Relationship
+
+    e = Engine()
+    e.write_relationships([WriteOp("touch", Relationship(
+        "namespace", f"ns{i}", "viewer", "user", "alice"))
+        for i in range(40)])
+
+    def moved(fn):
+        h = metrics.hist_snapshot("engine_dispatch_batch_rows") \
+            or {"n": 0, "total": 0.0}
+        c = metrics.counter("engine_dispatch_rows_total").value
+        fn()
+        h2 = metrics.hist_snapshot("engine_dispatch_batch_rows")
+        return (h2["n"] - h["n"], h2["total"] - h["total"],
+                metrics.counter("engine_dispatch_rows_total").value - c)
+
+    assert len(e.lookup_resources("namespace", "view", "user",
+                                  "alice")) == 40
+    assert moved(lambda: e.lookup_resources(
+        "namespace", "view", "user", "bob")) == (1, 1.0, 1.0)
+    assert moved(lambda: e.check_bulk(
+        [CheckItem("namespace", "ns1", "view", "user", "alice"),
+         CheckItem("namespace", "ns2", "view", "user", "alice"),
+         CheckItem("namespace", "ns1", "view", "user", "bob")])) \
+        == (1, 2.0, 2.0)
+
+
+def test_compile_hook_counts_backend_compiles_and_cache_hits_apart():
+    from spicedb_kubeapi_proxy_tpu.obs import profile
+
+    c0 = metrics.counter("jax_backend_compiles_total").value
+    h0 = metrics.counter("jax_compile_cache_hits_total").value
+    s0 = _hist_count("jax_compile_seconds")
+    profile._on_event_duration("/jax/core/compile/jaxpr_trace_duration", 1.0)
+    profile._on_event_duration(
+        "/jax/core/compile/backend_compile_duration", 0.5)
+    profile._on_event("/jax/compilation_cache/cache_hits")
+    profile._on_event("/jax/compilation_cache/cache_misses")
+    assert metrics.counter("jax_backend_compiles_total").value == c0 + 1
+    assert metrics.counter("jax_compile_cache_hits_total").value == h0 + 1
+    assert _hist_count("jax_compile_seconds") == s0 + 1
+
+
+def test_gc_hook_waits_for_no_lock_the_collecting_thread_holds():
+    """A collection starts at whatever allocation fills the collector's
+    count: also one made while this thread renders a scrape under the
+    registry's lock, or copies the collector's own histogram under that
+    histogram's lock. The callback must come back from both."""
+    import gc
+    import threading
+
+    from spicedb_kubeapi_proxy_tpu.obs.profile import install_gc_hook
+
+    install_gc_hook()
+    n0 = _hist_count("process_gc_seconds", generation=2)
+    done = []
+
+    def body():
+        with metrics._lock:
+            gc.collect()
+        with metrics.histogram("process_gc_seconds", generation=2)._lock:
+            gc.collect()
+        done.append(True)
+
+    t = threading.Thread(target=body, daemon=True)
+    t.start()
+    t.join(10)
+    assert done, "the collector's callback deadlocked on a held lock"
+    assert _hist_count("process_gc_seconds", generation=2) == n0 + 2

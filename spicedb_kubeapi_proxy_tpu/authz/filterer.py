@@ -17,14 +17,37 @@ from __future__ import annotations
 import json
 from typing import Optional
 
+from ..obs.trace import tracer
 from ..proxy import kubeproto
 from ..proxy.types import ProxyResponse, kube_status
 from ..rules.input import ResolveInput
+from ..utils.metrics import metrics
 from .lookups import AllowedSet
+
+# A body this long is filtered on a worker thread, a shorter one where the
+# request's coroutine runs (filter_response). The hop to a worker and back
+# costs the request about 2 ms under load (executor_wait_ms 0.9 +
+# loop_wait_ms 1.1 a crossing, PERF.md §5, ledger PR 26) and nobody else
+# anything; the filter on the loop costs EVERY request in flight its
+# length, about 2 ns a byte by the native call (body_filter_ms over the
+# list cell's 11.6 MB, PERF.md §6 PR 27) and some 50 by the json.loads
+# path. 256 KiB is half a millisecond of the one, 13 ms of the other: a
+# single object or a namespace's pods stay inline, a cluster-wide list
+# never does.
+OFF_LOOP_BYTES = 256 * 1024
 
 
 class FilterError(Exception):
     pass
+
+
+def _count(path: str, body: bytes) -> None:
+    """One filtered body, by who decided it: the ``fused`` native call or
+    the ``python`` walkers; a body short enough to stay on the event loop
+    counts as ``inline`` whoever decides it."""
+    metrics.counter(
+        "proxy_body_filter_total",
+        path=path if len(body) >= OFF_LOOP_BYTES else "inline").inc()
 
 
 def _meta_pair(obj: dict) -> tuple[str, str]:
@@ -33,82 +56,56 @@ def _meta_pair(obj: dict) -> tuple[str, str]:
 
 
 def _filter_list_wire(body: bytes, allowed: AllowedSet):
-    """Native wire-level JSON list filtering (graphcore.cpp
-    json_list_spans): drop disallowed items by byte span — kept items AND
-    the whole wrapper stay byte-identical, and a 15 MB 100k-item body
-    never goes through json.loads (~4x faster; numbers in
-    bench_results/proxy_path_r5_cpu.json). Handles *List bodies (items,
-    metadata at item top level) and Tables (rows, metadata under each
-    row's ``object``). Returns (status, new_body) or None to fall back
-    to the Python path (scanner bailed, single objects, native
+    """Native wire-level JSON list filtering: ONE call that holds no
+    interpreter lock (graphcore.cpp json_list_filter) scans the body,
+    decides every item against the allowed records and hands back the
+    byte runs to keep — kept items AND the whole wrapper stay
+    byte-identical, and a 15 MB 100k-item body never goes through
+    json.loads nor makes a Python object per item. Handles *List bodies
+    (items, metadata at item top level) and Tables (rows, metadata under
+    each row's ``object``). Returns (status, new_body) or None to fall
+    back to the Python path (scanner bailed, single objects, native
     unavailable)."""
     from .. import native
 
-    # cheap kind sniff picks the scan key so the common case is ONE pass
-    # (a Table with unusual kind spacing just pays a second scan)
-    looks_table = b'"kind":"Table"' in body or b'"kind": "Table"' in body
-    first_key, first_nested = (b"rows", True) if looks_table \
-        else (b"items", False)
-    scan = native.json_list_spans(body, first_key, nested=first_nested)
+    scan = native.json_list_filter(body, *allowed.packed_records())
     if scan is None:
         return None
-    kind_b, arr_span, item_spans, keys = scan
-    kind = kind_b.decode("utf-8", "replace")
-    if (kind == "Table") != looks_table:
-        # sniff guessed wrong: rescan with the other key
-        key, nested = (b"rows", True) if kind == "Table" \
-            else (b"items", False)
-        scan = native.json_list_spans(body, key, nested=nested)
-        if scan is None:
-            return None
-        _, arr_span, item_spans, keys = scan
-    if kind != "Table" and not kind.endswith("List"):
-        return None  # single objects: Python path
-    if arr_span[0] < 0:
+    (lo, hi), runs, esc, dropped = scan
+    if lo < 0:
         # kind says list/table but the array key is absent: nothing to
         # filter (`doc.get(...) or []` semantics) — body passes through
         return 200, body
-    # per-item records [esc] ns 0x1f name 0x1e, split in ONE C call; an
-    # unescaped item's WHOLE record compares against the precomputed
-    # record set — one set lookup, no per-item slicing or decoding
-    # (escaped names, rare, take the exact json.loads route)
-    recs = keys.split(b"\x1e")
-    pairs_rec = allowed.pairs_records()
-    pairs = allowed.pairs
-    loads = json.loads
-    kept_idx: list = []
-    dropped = False
-    idx = 0
-    for rec in recs[:len(recs) - 1]:
-        if rec in pairs_rec:
-            ok = True
-        elif rec[0] == 0x31:  # b'1': escapes present, decode exactly
-            ns_b, _, nm_b = rec[1:].partition(b"\x1f")
+    if not dropped and not len(esc):
+        return 200, body  # byte-identical passthrough
+    keep = runs.tolist()
+    if len(esc):
+        # names with escapes (rare: kube names are DNS labels) come back
+        # undecided, each a run of its own: decode exactly, decide here
+        pairs = allowed.pairs
+        denied = set()
+        for run, ns_s, ns_e, nm_s, nm_e in esc.tolist():
+            ns_b, nm_b = body[ns_s:ns_e], body[nm_s:nm_e]
             try:
-                ns = loads(b'"%s"' % ns_b) if b"\\" in ns_b \
+                ns = json.loads(b'"%s"' % ns_b) if b"\\" in ns_b \
                     else ns_b.decode("utf-8")
-                nm = loads(b'"%s"' % nm_b) if b"\\" in nm_b \
+                nm = json.loads(b'"%s"' % nm_b) if b"\\" in nm_b \
                     else nm_b.decode("utf-8")
             except ValueError:
                 # invalid escape / invalid utf-8: json.loads would have
                 # rejected the whole body — fall back so the Python path
                 # produces its clean 401, not an unhandled 500
                 return None
-            ok = (ns, nm) in pairs
-        else:
-            ok = False
-        if ok:
-            kept_idx.append(idx)
-        else:
-            dropped = True
-        idx += 1
+            if (ns, nm) not in pairs:
+                denied.add(run)
+        if denied:
+            dropped += len(denied)
+            keep = [r for i, r in enumerate(keep) if i not in denied]
     if not dropped:
-        return 200, body  # byte-identical passthrough
-    spans = item_spans[kept_idx].tolist() if kept_idx else []
-    parts = [body[:int(arr_span[0])],
-             b",".join(body[s:e] for s, e in spans),
-             body[int(arr_span[1]):]]
-    return 200, b"".join(parts)
+        return 200, body
+    return 200, b"".join((body[:lo],
+                          b",".join([body[s:e] for s, e in keep]),
+                          body[hi:]))
 
 
 def filter_body(body: bytes, allowed: AllowedSet,
@@ -116,7 +113,9 @@ def filter_body(body: bytes, allowed: AllowedSet,
     """Filter a JSON response body; returns (status, new_body)."""
     wire = _filter_list_wire(body, allowed)
     if wire is not None:
+        _count("fused", body)
         return wire
+    _count("python", body)
     try:
         doc = json.loads(body)
     except ValueError as e:
@@ -194,6 +193,7 @@ def filter_body_proto(body: bytes, allowed: AllowedSet,
     message (kept bytes are untouched); single objects never need parsing
     — the request path already names the object, so the decision is the
     allowed-set test and the body passes through byte-identical."""
+    _count("python", body)  # native scan or not, a Python loop decides
     try:
         _, kind, raw = kubeproto.decode_unknown(body)
         if kind == "Table":
@@ -227,7 +227,9 @@ def apply_filter(resp: ProxyResponse, allowed: AllowedSet,
     """Filter an upstream response in place (the reference hooks
     ReverseProxy.ModifyResponse, pkg/proxy/server.go:103-112)."""
     if resp.status != 200:
-        return resp  # upstream errors pass through unfiltered
+        # upstream errors pass through unfiltered
+        metrics.counter("proxy_body_filter_total", path="passthrough").inc()
+        return resp
     ctype = resp.content_type
     try:
         if ctype and "protobuf" in ctype:
@@ -249,3 +251,18 @@ def apply_filter(resp: ProxyResponse, allowed: AllowedSet,
     headers = dict(resp.headers)
     headers["Content-Length"] = str(len(body))
     return ProxyResponse(status=200, headers=headers, body=body)
+
+
+def runs_off_loop(resp: ProxyResponse) -> bool:
+    """Whether :func:`filter_response` belongs on a worker thread."""
+    return resp.status == 200 and len(resp.body) >= OFF_LOOP_BYTES
+
+
+def filter_response(resp: ProxyResponse, allowed: AllowedSet,
+                    input: ResolveInput) -> ProxyResponse:
+    """:func:`apply_filter` as stage ``body_filter``, on whatever thread
+    the caller chose by :func:`runs_off_loop`: the stage begins and ends
+    around the filter alone, so a hop to a worker is not in it."""
+    with tracer.stage("body_filter",
+                      metrics.histogram("proxy_body_filter_seconds")):
+        return apply_filter(resp, allowed, input)

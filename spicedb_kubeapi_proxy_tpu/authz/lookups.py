@@ -20,6 +20,8 @@ import logging
 from dataclasses import dataclass, field
 from typing import Optional
 
+import numpy as np
+
 log = logging.getLogger(__name__)
 
 from ..engine import Engine
@@ -45,10 +47,12 @@ class AllowedSet:
     # per-item decode on the hot loop
     _pairs_bytes: Optional[set] = field(default=None, repr=False,
                                         compare=False)
+    _packed: Optional[tuple] = field(default=None, repr=False,
+                                     compare=False)
 
     def add(self, namespace: str, name: str) -> None:
         self.pairs.add((namespace or "", name))
-        self._pairs_bytes = None
+        self._pairs_bytes = self._packed = None
 
     def allows(self, namespace: str, name: str) -> bool:
         return (namespace or "", name) in self.pairs
@@ -70,7 +74,21 @@ class AllowedSet:
                     # decoded-str path against .pairs
                     pass
             self._pairs_bytes = out
+            self._packed = None
         return self._pairs_bytes
+
+    def packed_records(self) -> tuple:
+        """``pairs_records()`` as one buffer and its ``len + 1`` int64
+        offsets: what the fused native filter builds its hash set from
+        (native.json_list_filter)."""
+        recs = self.pairs_records()  # a rebuild there drops a stale pack
+        if self._packed is None:
+            recs = list(recs)
+            offsets = np.zeros(len(recs) + 1, dtype=np.int64)
+            np.cumsum(np.fromiter(map(len, recs), dtype=np.int64,
+                                  count=len(recs)), out=offsets[1:])
+            self._packed = (b"".join(recs), offsets)
+        return self._packed
 
     def __len__(self) -> int:
         return len(self.pairs)

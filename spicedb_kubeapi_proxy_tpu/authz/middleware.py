@@ -38,7 +38,7 @@ from ..rules.expr import ExprError
 from ..rules.input import ResolveInput, UserInfo
 from ..rules.matcher import MapMatcher, RequestMeta
 from .check import cached_verdict, run_checks
-from .filterer import apply_filter
+from .filterer import filter_response, runs_off_loop
 from .lookups import PreFilterError, run_prefilter, single_prefilter
 from .postfilter import filter_list_response
 from .update import UpdateError, build_workflow_input, single_update_rule
@@ -531,9 +531,13 @@ async def _authorized(req: ProxyRequest, deps: AuthzDeps, info, user,
             return kube_status(401, "prefilter timed out")
         except (PreFilterError, ExprError) as e:
             return kube_status(401, f"prefilter: {e}")
-        with tracer.stage("body_filter",
-                          metrics.histogram("proxy_body_filter_seconds")):
-            resp = apply_filter(resp, allowed, input)
+        if runs_off_loop(resp):
+            # a long list: the native filter holds no interpreter lock,
+            # so on a worker it costs the requests in flight nothing
+            resp = await tracer.to_thread(filter_response, resp, allowed,
+                                          input)
+        else:
+            resp = filter_response(resp, allowed, input)
     if run_postfilter:
         try:
             with tracer.span("postfilter"):

@@ -95,7 +95,7 @@ def _load() -> Optional[ctypes.CDLL]:
 # exported-signature change; _bind refuses a mismatching cached .so (the
 # rebuild path then fires) — binding by symbol NAME alone would let a
 # stale library misread argument slots silently
-_ABI_VERSION = 4
+_ABI_VERSION = 5
 
 
 def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
@@ -125,12 +125,12 @@ def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
         ctypes.c_int64,
         ctypes.POINTER(ctypes.c_uint64), ctypes.POINTER(ctypes.c_int64),
     ]
-    lib.json_list_spans.restype = ctypes.c_int64
-    lib.json_list_spans.argtypes = [
-        ctypes.c_char_p, ctypes.c_int64, ctypes.c_char_p,
-        ctypes.POINTER(ctypes.c_int64), ctypes.POINTER(ctypes.c_int64),
-        ctypes.POINTER(ctypes.c_int64), ctypes.c_char_p,
-        ctypes.POINTER(ctypes.c_int64), ctypes.c_int64, ctypes.c_int64,
+    p64 = ctypes.POINTER(ctypes.c_int64)
+    lib.json_list_filter.restype = ctypes.c_int64
+    lib.json_list_filter.argtypes = [
+        ctypes.c_char_p, ctypes.c_int64,
+        ctypes.c_char_p, p64, ctypes.c_int64,
+        p64, p64, ctypes.c_int64, p64, ctypes.c_int64, p64,
     ]
     lib.proto_list_spans.restype = ctypes.c_int64
     lib.proto_list_spans.argtypes = [
@@ -192,40 +192,52 @@ def index_build(rt, rid, rl, st, sid, srl):
     return hashes, order
 
 
-def json_list_spans(body: bytes, items_key: bytes = b"items",
-                    nested: bool = False):
-    """One-pass scan of a kube List response body (graphcore.cpp
-    json_list_spans): returns ``(kind, arr_span, item_spans, keys)`` —
-    kind as bytes (b"" when absent), spans as int64 arrays of byte
-    offsets into ``body`` (``arr_span[0] < 0`` when ``items_key`` is
-    absent), and ``keys`` as one packed bytes buffer of per-item records
-    ``[esc '0'|'1'] ns_raw 0x1f name_raw 0x1e`` (raw = undecoded string
-    content; JSON forbids unescaped control bytes, so the separators
-    cannot collide) — or None when the native path does not apply or the
-    scanner bailed (caller falls back to json.loads; the scanner is
-    strictly conservative). ``nested`` reads each item's metadata from
-    ``item["object"]`` instead of the item itself (Table rows)."""
+def json_list_filter(body: bytes, records: bytes, offsets: np.ndarray):
+    """A kube *List or Table response body decided against the allowed
+    records in ONE native call that holds no interpreter lock
+    (graphcore.cpp json_list_filter). ``records`` are the allowed
+    ``'0' ns 0x1f name`` records back to back, ``offsets`` their
+    ``len + 1`` int64 offsets (``AllowedSet.packed_records``). Returns
+    ``(arr_span, runs, esc, dropped)``: ``arr_span`` the byte span inside
+    the array's brackets, two ints (``arr_span[0] < 0`` when the array
+    key is absent); ``runs`` an int64 ``[k, 2]`` array of the byte runs of
+    ``body`` to keep, in order, to be joined with ``b","``; ``esc`` an
+    int64 ``[m, 5]`` array, one row per item whose name or namespace
+    holds an escape — (index of its run, namespace span, name span; an
+    empty span where the key is missing), raw string content the
+    caller decodes and decides, removing the run where it denies;
+    ``dropped`` the count of items already left out. None when the
+    native path does not apply, the body is neither a Table nor a
+    *List, or the scanner bailed (caller falls back to json.loads; the
+    scanner is strictly conservative)."""
     lib = _load()
     if lib is None or not isinstance(body, bytes) or not body:
         return None
-    # every object item contains at least one '{': a cheap upper bound
-    max_items = body.count(b"{") + 1
-    kind_span = np.empty(2, dtype=np.int64)
-    arr_span = np.empty(2, dtype=np.int64)
-    item_spans = np.empty(2 * max_items, dtype=np.int64)
-    key_buf = ctypes.create_string_buffer(len(body) + 3 * max_items + 16)
-    key_len = ctypes.c_int64(0)
     p64 = ctypes.POINTER(ctypes.c_int64)
-    count = lib.json_list_spans(
-        body, len(body), items_key,
-        kind_span.ctypes.data_as(p64), arr_span.ctypes.data_as(p64),
-        item_spans.ctypes.data_as(p64), key_buf,
-        ctypes.byref(key_len), 1 if nested else 0, max_items)
-    if count < 0:
+    offsets = np.ascontiguousarray(offsets, dtype=np.int64)
+    n_recs = len(offsets) - 1
+    if n_recs < 0 or offsets[0] != 0 or offsets[-1] != len(records):
+        raise ValueError("record offsets do not span the record buffer")
+    arr_span = np.empty(2, dtype=np.int64)
+    counts = np.zeros(3, dtype=np.int64)  # items dropped, runs, undecided
+    # a kept item names an allowed record, so there are rarely more runs
+    # than records and escapes are rare (kube names are DNS labels): start
+    # there and grow to what the scanner counted on its overflow code
+    max_runs, max_esc = n_recs + 64, 64
+    while True:
+        runs = np.empty((max_runs, 2), dtype=np.int64)
+        esc = np.empty((max_esc, 5), dtype=np.int64)
+        rc = lib.json_list_filter(
+            body, len(body), records, offsets.ctypes.data_as(p64), n_recs,
+            arr_span.ctypes.data_as(p64), runs.ctypes.data_as(p64), max_runs,
+            esc.ctypes.data_as(p64), max_esc, counts.ctypes.data_as(p64))
+        if rc != -2:
+            break
+        max_runs, max_esc = int(counts[1]), int(counts[2])
+    if rc < 0:
         return None
-    kind = body[kind_span[0]:kind_span[1]] if kind_span[0] >= 0 else b""
-    return (kind, arr_span, item_spans[:2 * count].reshape(-1, 2),
-            ctypes.string_at(key_buf, key_len.value))
+    return (arr_span.tolist(), runs[:counts[1]], esc[:counts[2]],
+            int(counts[0]))
 
 
 def proto_list_spans(raw: bytes):

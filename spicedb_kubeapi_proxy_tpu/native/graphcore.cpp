@@ -15,8 +15,10 @@
 //
 // Build: g++ -O3 -std=c++17 -fPIC -shared graphcore.cpp -o libgraphcore.so
 
+#include <climits>
 #include <cstdint>
 #include <cstring>
+#include <string>
 #include <thread>
 #include <vector>
 
@@ -217,19 +219,27 @@ extern "C" void index_build_u64(
 
 
 // ---------------------------------------------------------------------------
-// JSON list scanner (authz/filterer.py): one pass over a kube List response
-// body locating the top-level "kind" value, the top-level `items_key` array,
-// every element's byte span, and each element's metadata.name /
-// metadata.namespace string-value spans (raw bytes between the quotes —
-// escape decoding, when needed, happens Python-side). Lets the filter keep
-// items BYTE-IDENTICAL and skip json.loads on multi-MB bodies.
+// JSON list filter (authz/filterer.py): ONE call over a kube *List or Table
+// response body that decides every element against the caller's allowed
+// records and gives back the byte runs to keep. A pass over the body
+// locates the top-level "kind" value, the top-level array (`items`, or
+// `rows` for a Table), every element's byte span and its metadata.name /
+// metadata.namespace string values (raw bytes between the quotes). An
+// unescaped element's record '0' ns 0x1f name is looked up in a hash set
+// built once per call from the allowed records; an escape-flagged element
+// is handed back for the caller to decode exactly (never guessed here).
+// No Python object is made per element and ctypes holds no interpreter
+// lock meanwhile: kept items stay BYTE-IDENTICAL, a multi-MB body never
+// goes through json.loads, and a worker thread filtering it costs the
+// other threads nothing.
 //
-// Returns the item count (>= 0) on success, or a negative bail code — the
-// caller then falls back to the Python json path, so this scanner is
-// conservative: anything structurally surprising (escaped keys,
-// non-object items, duplicate items keys, trailing garbage, malformed
-// strings or scalar tokens anywhere) bails rather than risking
-// semantics that differ from json.loads. Known disclosed laxity: the
+// Returns 0 on success, -2 when an output array is too small (counts[]
+// then says what it takes), or -1 to bail (also where the body is neither
+// a Table nor a *List) — the caller then falls back to the Python json
+// path, so the scanner is conservative: anything structurally surprising
+// (escaped keys, non-object items, duplicate items keys, trailing
+// garbage, malformed strings or scalar tokens anywhere) bails rather than
+// risking semantics that differ from json.loads. Known disclosed laxity: the
 // comma/colon PLACEMENT inside skipped substructure is not re-validated
 // — a body like {"spec":{"a" "b"}} passes here where json.loads raises
 // (which the Python path turns into a 401); an apiserver never emits
@@ -412,28 +422,120 @@ struct Scan {
   }
 };
 
-}  // namespace jsonscan
+// The allowed records of one call ('0' ns 0x1f name, packed back to back
+// in `buf` with n + 1 offsets): open addressing, power-of-two capacity
+// >= 2n, a 32-bit tag of the hash beside each index so a probe that
+// misses (most do: ~1% of a large list is kept) compares no bytes.
+struct RecordSet {
+  struct Slot {
+    int32_t idx;  // -1 = empty
+    uint32_t tag;
+  };
+  const char* buf;
+  const int64_t* off;
+  std::vector<Slot> table;
+  uint64_t mask;
 
-extern "C" int64_t json_list_spans(
+  static uint64_t hash(const char* p, int64_t len) {
+    uint64_t h = 0x9E3779B97F4A7C15ull ^ static_cast<uint64_t>(len);
+    uint64_t w;
+    for (; len >= 8; p += 8, len -= 8) {
+      memcpy(&w, p, 8);
+      h = (h ^ w) * 0xFF51AFD7ED558CCDull;
+      h ^= h >> 32;
+    }
+    w = 0;
+    memcpy(&w, p, static_cast<size_t>(len));
+    h = (h ^ w) * 0xFF51AFD7ED558CCDull;
+    return h ^ (h >> 32);
+  }
+
+  RecordSet(const char* b, const int64_t* o, int64_t n) : buf(b), off(o) {
+    uint64_t cap = 16;
+    while (cap < static_cast<uint64_t>(n) * 2) cap <<= 1;
+    table.assign(cap, Slot{-1, 0});
+    mask = cap - 1;
+    for (int64_t r = 0; r < n; ++r) {
+      const uint64_t h = hash(buf + off[r], off[r + 1] - off[r]);
+      uint64_t pos = h & mask;
+      while (table[pos].idx >= 0) pos = (pos + 1) & mask;  // duplicates
+      table[pos] = Slot{static_cast<int32_t>(r),           // are harmless
+                        static_cast<uint32_t>(h >> 32)};
+    }
+  }
+
+  bool has(const char* rec, int64_t len) const {
+    const uint64_t h = hash(rec, len);
+    const uint32_t tag = static_cast<uint32_t>(h >> 32);
+    for (uint64_t pos = h & mask;; pos = (pos + 1) & mask) {
+      const Slot s = table[pos];
+      if (s.idx < 0) return false;
+      if (s.tag == tag && off[s.idx + 1] - off[s.idx] == len &&
+          memcmp(buf + off[s.idx], rec, static_cast<size_t>(len)) == 0)
+        return true;
+    }
+  }
+};
+
+// What one scan keeps: byte runs of the body in document order. A kept
+// item that follows the last kept one after exactly one byte (the bare
+// ',' of a compact body) extends its run, so joining the runs with ','
+// gives the same bytes as joining the items; an escape-flagged item is a
+// run of its own plus five numbers in `esc` (its run's index and the raw
+// spans of namespace and name) for the caller to decide. Past an array's
+// capacity nothing is written and the counting goes on (-2).
+struct Kept {
+  int64_t* runs;
+  int64_t max_runs;
+  int64_t* esc;
+  int64_t max_esc;
+  int64_t n_dropped = 0, n_runs = 0, n_esc = 0;
+  int64_t last_end = -1;  // end of the run the next item may extend
+
+  void keep(int64_t s, int64_t e, bool extends) {
+    if (extends && last_end >= 0 && s == last_end + 1) {
+      if (n_runs <= max_runs) runs[2 * n_runs - 1] = e;
+    } else {
+      if (n_runs < max_runs) {
+        runs[2 * n_runs] = s;
+        runs[2 * n_runs + 1] = e;
+      }
+      ++n_runs;
+    }
+    last_end = extends ? e : -1;
+  }
+  void undecided(int64_t s, int64_t e, int64_t ns_s, int64_t ns_e,
+                 int64_t nm_s, int64_t nm_e) {
+    if (n_esc < max_esc) {
+      int64_t* o = esc + 5 * n_esc;
+      o[0] = n_runs;  // a missing key reads as the empty span 0, 0
+      o[1] = ns_s < 0 ? 0 : ns_s;
+      o[2] = ns_s < 0 ? 0 : ns_e;
+      o[3] = nm_s < 0 ? 0 : nm_s;
+      o[4] = nm_s < 0 ? 0 : nm_e;
+    }
+    ++n_esc;
+    keep(s, e, false);
+  }
+};
+
+// One pass under one array key. -1 bails; else 0 with kind_span, arr_span
+// (-1,-1 when the key is absent: legal, the caller may only need the
+// kind to rescan a Table under "rows") and `out` filled.
+static int64_t scan_list(
     const char* buf, int64_t n, const char* items_key,
+    bool nested,          // false: metadata at item top level (List items);
+                          // true: inside item["object"] (Table rows)
+    const RecordSet& allowed, std::string& rec,
     int64_t* kind_span,   // [2] raw value span, -1,-1 when absent
     int64_t* arr_span,    // [2] start = after '[', end = index of ']'
-    int64_t* item_spans,  // [2 * max_items]
-    char* key_buf,        // >= n + 3*max_items bytes; per item one record
-                          // [esc '0'|'1'] ns_raw 0x1f name_raw 0x1e (raw =
-                          // undecoded string content; missing -> empty)
-    int64_t* key_len,     // out: bytes written into key_buf
-    int64_t nested,       // 0: metadata at item top level (List items);
-                          // 1: inside item["object"] (Table rows)
-    int64_t max_items) {
-  jsonscan::Scan sc{buf, n};
+    Kept& out) {
+  Scan sc{buf, n};
   kind_span[0] = kind_span[1] = -1;
   arr_span[0] = arr_span[1] = -1;
-  *key_len = 0;
-  int64_t count = 0;
   bool items_seen = false;
   // per-item metadata string spans (last-wins under duplicate keys, so
-  // the record is emitted only when the item closes)
+  // the item is decided only when it closes)
   int64_t nm_s, nm_e, ns_s, ns_e;
   bool nm_esc, ns_esc;
 
@@ -491,8 +593,6 @@ extern "C" int64_t json_list_spans(
   };
 
   auto parse_item = [&]() -> bool {
-    if (count >= max_items) { sc.fail = true; return false; }
-    const int64_t idx = count;
     nm_s = nm_e = ns_s = ns_e = -1;
     nm_esc = ns_esc = false;
     sc.ws();
@@ -520,22 +620,21 @@ extern "C" int64_t json_list_spans(
                 return parse_metadata();
               });
     if (!walked) return false;
-    item_spans[2 * idx] = start;
-    item_spans[2 * idx + 1] = sc.i;  // exclusive, after the closing '}'
-    char* kb = key_buf + *key_len;
-    *kb++ = (nm_esc || ns_esc) ? '1' : '0';
-    if (ns_s >= 0) {
-      memcpy(kb, buf + ns_s, (size_t)(ns_e - ns_s));
-      kb += ns_e - ns_s;
+    // the item's span ends exclusive, after its closing '}'
+    if (nm_esc || ns_esc) {
+      out.undecided(start, sc.i, ns_s, ns_e, nm_s, nm_e);
+      return true;
     }
-    *kb++ = '\x1f';
-    if (nm_s >= 0) {
-      memcpy(kb, buf + nm_s, (size_t)(nm_e - nm_s));
-      kb += nm_e - nm_s;
-    }
-    *kb++ = '\x1e';
-    *key_len = kb - key_buf;
-    ++count;
+    // the record '0' ns 0x1f name (missing -> empty); JSON forbids raw
+    // control bytes in a string, so the separator cannot collide
+    rec.assign(1, '0');
+    if (ns_s >= 0) rec.append(buf + ns_s, static_cast<size_t>(ns_e - ns_s));
+    rec.push_back('\x1f');
+    if (nm_s >= 0) rec.append(buf + nm_s, static_cast<size_t>(nm_e - nm_s));
+    if (allowed.has(rec.data(), static_cast<int64_t>(rec.size())))
+      out.keep(start, sc.i, true);
+    else
+      ++out.n_dropped;
     return true;
   };
 
@@ -580,15 +679,57 @@ extern "C" int64_t json_list_spans(
   if (!ok || sc.fail) return -1;
   sc.ws();
   if (sc.i != n) return -1;  // trailing garbage: json.loads would raise
-  // items_key absent entirely: legal (count 0, arr_span -1) — the
-  // caller may only need the kind (e.g. to rescan a Table under "rows")
-  return count;
+  return 0;
+}
+
+}  // namespace jsonscan
+
+extern "C" int64_t json_list_filter(
+    const char* buf, int64_t n,
+    const char* rec_buf,     // the allowed records, back to back
+    const int64_t* rec_off,  // [n_recs + 1] offsets into rec_buf
+    int64_t n_recs,
+    int64_t* arr_span,       // [2] out; -1,-1: the array key is absent
+    int64_t* runs,           // [2 * max_runs] out: byte runs to keep
+    int64_t max_runs,
+    int64_t* esc,            // [5 * max_esc] out: undecided items
+    int64_t max_esc,
+    int64_t* counts) {       // [3] out: items dropped, runs, undecided
+  if (n_recs < 0 || n_recs > INT32_MAX) return -1;
+  const jsonscan::RecordSet allowed(rec_buf, rec_off, n_recs);
+  std::string rec;
+  int64_t kind_span[2];
+  auto holds = [&](const char* lit) {
+    return memmem(buf, static_cast<size_t>(n), lit, strlen(lit)) != nullptr;
+  };
+  // a cheap sniff of the kind picks the array key, so the common case is
+  // ONE pass; a Table with unusual kind spacing pays a second
+  bool table = holds("\"kind\":\"Table\"") || holds("\"kind\": \"Table\"");
+  while (true) {
+    jsonscan::Kept out{runs, max_runs, esc, max_esc};
+    if (jsonscan::scan_list(buf, n, table ? "rows" : "items", table,
+                            allowed, rec, kind_span, arr_span, out) < 0)
+      return -1;
+    const int64_t klen = kind_span[0] < 0 ? 0 : kind_span[1] - kind_span[0];
+    const char* kind = buf + (klen ? kind_span[0] : 0);
+    const bool is_table = klen == 5 && memcmp(kind, "Table", 5) == 0;
+    if (is_table != table) {
+      table = is_table;  // the sniff guessed wrong: once more, the other
+      continue;          // key (the kind read is the same, so only once)
+    }
+    if (!is_table && !(klen >= 4 && memcmp(kind + klen - 4, "List", 4) == 0))
+      return -1;  // a single object: the Python path
+    counts[0] = out.n_dropped;
+    counts[1] = out.n_runs;
+    counts[2] = out.n_esc;
+    return (out.n_runs > max_runs || out.n_esc > max_esc) ? -2 : 0;
+  }
 }
 
 // Bumped on ANY exported-signature change: the loader refuses a library
 // whose ABI differs (a stale cached .so with preserved mtimes would
 // otherwise bind by name and silently misread arguments).
-extern "C" int64_t graphcore_abi_version() { return 4; }
+extern "C" int64_t graphcore_abi_version() { return 5; }
 
 // ---------------------------------------------------------------------------
 // Protobuf list scanner (authz/filterer.py filter_body_proto): one pass

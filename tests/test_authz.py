@@ -1849,3 +1849,179 @@ def test_watch_churn_no_leaked_hub_state():
         t.cancel()
         env.kube.stop_watches()
     run(go())
+
+
+# -- the body filter's call site: off the event loop by the body's length -----
+
+def _padded_upstream(kube, pad_items: int):
+    """``kube``, its list bodies lengthened by ``pad_items`` namespaces
+    nobody may see (the filter has to drop every one)."""
+    async def upstream(req):
+        resp = await kube(req)
+        if req.method == "GET" and req.path == "/api/v1/namespaces":
+            doc = json.loads(resp.body)
+            doc["items"] += [{"metadata": {"name": f"pad-{i:06d}"}}
+                             for i in range(pad_items)]
+            resp.body = json.dumps(doc).encode()
+        return resp
+    return upstream
+
+
+async def _traced_list(env):
+    """One traced ``GET /api/v1/namespaces`` as alice beside a coroutine
+    that ticks on the same loop -> (response, spans of the trace, and for
+    each run of the filter ``(on the loop's thread?, the ticker moved
+    while it ran?)``). ``apply_filter`` is held, off the loop, until the
+    ticker has moved three times or five seconds have gone; on the loop
+    it would see no tick however long it waited, for the ticker needs
+    the loop."""
+    import threading
+    import time
+
+    from spicedb_kubeapi_proxy_tpu.authz import filterer
+    from spicedb_kubeapi_proxy_tpu.obs.trace import tracer
+
+    ticks = 0
+    loop_thread = threading.get_ident()
+    runs = []
+    real = filterer.apply_filter
+
+    def held(resp, allowed, input):
+        t0, deadline = ticks, time.monotonic() + 5.0
+        on_loop = threading.get_ident() == loop_thread
+        while not on_loop and ticks < t0 + 3 and time.monotonic() < deadline:
+            time.sleep(0.001)
+        runs.append((on_loop, ticks >= t0 + 3))
+        return real(resp, allowed, input)
+
+    async def ticker():
+        nonlocal ticks
+        while True:
+            ticks += 1
+            await asyncio.sleep(0)
+
+    tracer.configure(sample=1.0)
+    tracer.reset()
+    filterer.apply_filter = held
+    tick = asyncio.ensure_future(ticker())
+    try:
+        with tracer.start("request"):
+            resp = await env.request("GET", "/api/v1/namespaces")
+    finally:
+        tick.cancel()
+        filterer.apply_filter = real
+        tracer.configure(sample=0.1)
+    (trace,) = tracer.recent()
+    tracer.reset()
+    return resp, trace["spans"], runs
+
+
+def _filter_hops(spans):
+    """``executor_wait`` spans directly under the root: the body filter's
+    hop to a worker (the prefilter's own lies under span ``prefilter``)."""
+    root = next(s for s in spans if s["name"] == "request")
+    return [s for s in spans if s["name"] == "executor_wait"
+            and s["parent_id"] == root["span_id"]]
+
+
+def test_long_list_is_filtered_off_the_event_loop():
+    """While a long list is being filtered, a coroutine on the same loop
+    goes on running: the filter is on a worker thread, one hop away."""
+    from spicedb_kubeapi_proxy_tpu.authz.filterer import OFF_LOOP_BYTES
+
+    async def go():
+        env = Env()
+        await env.create_ns("alpha")
+        env.deps.upstream = _padded_upstream(env.kube, 12_000)
+        resp, spans, runs = await _traced_list(env)
+        assert resp.status == 200
+        assert [o["metadata"]["name"]
+                for o in json.loads(resp.body)["items"]] == ["alpha"]
+        assert len(resp.body) < OFF_LOOP_BYTES  # what came from upstream
+        # ... was longer: one filter, not on the loop, the ticker moving
+        assert runs == [(False, True)], runs
+        assert len(_filter_hops(spans)) == 1
+        assert [s["name"] for s in spans].count("body_filter") == 1
+    run(go())
+
+
+def test_short_body_is_filtered_where_the_coroutine_runs():
+    """A short body is not worth a hop: filtered on the loop, and the
+    trace holds no ``executor_wait`` but the prefilter's."""
+    async def go():
+        env = Env()
+        await env.create_ns("alpha")
+        await env.create_ns("beta", user="bob")
+        resp, spans, runs = await _traced_list(env)
+        assert resp.status == 200
+        assert [o["metadata"]["name"]
+                for o in json.loads(resp.body)["items"]] == ["alpha"]
+        assert runs == [(True, False)], runs
+        assert _filter_hops(spans) == []
+        assert [s["name"] for s in spans].count("executor_wait") == 1
+        assert [s["name"] for s in spans].count("body_filter") == 1
+    run(go())
+
+
+def test_body_filter_counter_names_the_path():
+    """``proxy_body_filter_total{path}``: a long list the native call
+    decides reads ``fused``, a short body ``inline``, a long body the
+    scanner refuses ``python`` — and that one still gets the Python
+    path's answer; an upstream error is a ``passthrough``."""
+    from spicedb_kubeapi_proxy_tpu import native
+    from spicedb_kubeapi_proxy_tpu.utils.metrics import metrics
+
+    if not native.available():
+        pytest.skip("native library unavailable")
+
+    def counts():
+        return {p: metrics.counter("proxy_body_filter_total", path=p).value
+                for p in ("fused", "python", "inline", "passthrough")}
+
+    async def listed(env):
+        before = counts()
+        resp = await env.request("GET", "/api/v1/namespaces")
+        names = [o["metadata"]["name"]
+                 for o in json.loads(resp.body).get("items") or []]
+        return resp.status, names, {
+            p: n - before[p] for p, n in counts().items() if n != before[p]}
+
+    async def go():
+        env = Env()
+        await env.create_ns("alpha")
+        assert await listed(env) == (200, ["alpha"], {"inline": 1})
+        env.deps.upstream = _padded_upstream(env.kube, 12_000)
+        assert await listed(env) == (200, ["alpha"], {"fused": 1})
+
+        refused = _padded_upstream(env.kube, 12_000)
+
+        async def numeric_name(req):
+            resp = await refused(req)
+            # a name that is no string: the scanner bails, and the
+            # json.loads path keeps authority (and drops the item)
+            resp.body = resp.body.replace(
+                b'"items": [', b'"items": [{"metadata": {"name": 7}}, ', 1)
+            return resp
+
+        env.deps.upstream = numeric_name
+        assert await listed(env) == (200, ["alpha"], {"python": 1})
+
+        async def null_items(req):
+            resp = await refused(req)
+            doc = json.loads(resp.body)
+            doc["spec"] = doc.pop("items")  # a long body, "items": null
+            doc["items"] = None
+            resp.body = json.dumps(doc).encode()
+            return resp
+
+        env.deps.upstream = null_items
+        assert await listed(env) == (200, [], {"python": 1})
+
+        async def unavailable(req):
+            from spicedb_kubeapi_proxy_tpu.proxy.types import kube_status
+            return kube_status(503, "apiserver is down")
+
+        env.deps.upstream = unavailable
+        status, _, moved = await listed(env)
+        assert (status, moved) == (503, {"passthrough": 1})
+    run(go())

@@ -1,4 +1,4 @@
-"""Native JSON list scanner (graphcore.cpp json_list_spans): the
+"""Native JSON list filter (graphcore.cpp json_list_filter): the
 wire-level filter must agree with the Python json path on every input —
 differential-fuzzed over documents with escapes, unicode, nested
 containers, odd whitespace, and missing/duplicate fields; anything the
@@ -409,3 +409,152 @@ def test_kind_and_whitespace_variants():
     assert _filter_list_wire(body, allowed) == (200, body)
     status, out = _filter_list_wire(body, AllowedSet(set()))
     assert json.loads(out)["items"] == []
+
+
+# -- the fused call against the json.loads path, case by case -----------------
+
+COMPACT, SPACED = (",", ":"), (",\n  ", " : ")
+
+
+def _wrap(kind: str, metas, sep, ensure_ascii=False) -> bytes:
+    """A ``*List`` of items or a ``Table`` of rows over ``metas`` (each a
+    metadata dict, or None for an entry without one)."""
+    def entry(i, meta):
+        obj = {"spec": {"n": i}} if meta is None \
+            else {"metadata": meta, "spec": {"n": i}}
+        return {"cells": [i], "object": obj} if kind == "Table" else obj
+    doc = {"kind": kind, "apiVersion": "v1",
+           "metadata": {"resourceVersion": "9"},
+           "rows" if kind == "Table" else "items":
+               [entry(i, m) for i, m in enumerate(metas)]}
+    return json.dumps(doc, separators=sep, ensure_ascii=ensure_ascii).encode()
+
+
+def _pair(meta) -> tuple:
+    return ((meta or {}).get("namespace") or "", (meta or {}).get("name") or "")
+
+
+def _generated_cases():
+    plain = [{"name": f"p{i}", "namespace": f"ns{i % 3}"} for i in range(9)]
+    escaped = [{"name": n, "namespace": "ns"} for n in
+               ['quo"te', "back\\slash", "tab\there", "new\nline", "plain"]]
+    unicode_ = [{"name": n, "namespace": "ns-日本"} for n in
+                ["uni-日本", "café", "\U0001f600", "plain"]]
+    cluster = [{"name": "a"}, {"name": "b", "namespace": ""}, None,
+               {"namespace": "only-ns"}, {}]
+    # more kept runs than the first guess (records + 64): one allowed
+    # name, 100 times, each time behind an item that is dropped
+    many_runs = [{"name": "dup" if i % 2 else f"drop{i}"}
+                 for i in range(200)]
+    # more escape-flagged items than the first guess (64)
+    many_esc = [{"name": f"e\t{i}"} for i in range(150)]
+    for kind in ("PodList", "Table"):
+        for sep_id, sep in (("compact", COMPACT), ("spaced", SPACED)):
+            def case(name, metas, allowed, **kw):
+                return pytest.param(
+                    _wrap(kind, metas, sep, **kw), allowed, "fused",
+                    id=f"{kind}-{sep_id}-{name}")
+            yield case("some", plain,
+                       {_pair(m) for m in plain[::2]} | {("x", "noise")})
+            yield case("adjacent", plain, {_pair(m) for m in plain[2:7]})
+            yield case("escaped", escaped,
+                       {_pair(m) for m in escaped[1::2]})
+            yield case("escaped-ascii", escaped + unicode_,
+                       {_pair(m) for m in (escaped + unicode_)[::2]},
+                       ensure_ascii=True)
+            yield case("non-ascii", unicode_,
+                       {_pair(m) for m in unicode_[:2]})
+            yield case("no-namespace", cluster, {("", "a"), ("", "")})
+            yield case("nothing", plain, set())
+            yield case("everything", plain, {_pair(m) for m in plain})
+            yield case("grow-runs", many_runs, {("", "dup")})
+            yield case("grow-escapes", many_esc,
+                       {("", f"e\t{i}") for i in range(0, 150, 3)})
+
+
+def _handwritten_cases():
+    def case(name, body, allowed, expect="fused"):
+        return pytest.param(body, allowed, expect, id=name)
+    # duplicate keys: the last one wins, as in dict construction
+    yield case("List-duplicate-metadata-last-wins",
+               b'{"kind":"PodList","items":[{"metadata":{"name":"a"},'
+               b'"metadata":{"name":"b"}},{"metadata":{"name":"a"}}]}',
+               {("", "b")})
+    yield case("List-duplicate-name-last-wins",
+               b'{"kind":"PodList","items":[{"metadata":{"name":"a",'
+               b'"namespace":"n","name":"b"}},{"metadata":{"name":"c"}}]}',
+               {("n", "a")})
+    yield case("Table-duplicate-object-last-wins",
+               b'{"kind":"Table","rows":[{"object":{"metadata":{"name":"a"}},'
+               b'"object":{"spec":1}},{"object":{"metadata":{"name":"a"}}}]}',
+               {("", "a")})
+    for kind, key in (("PodList", "items"), ("Table", "rows")):
+        k = kind.encode()
+        yield case(f"{kind}-array-absent",
+                   b'{"kind":"%s","metadata":{}}' % k, {("", "a")})
+        yield case(f"{kind}-array-empty",
+                   b'{"kind":"%s","%s":[]}' % (k, key.encode()), {("", "a")})
+        yield case(f"{kind}-array-null",
+                   b'{"kind":"%s","%s":null}' % (k, key.encode()),
+                   {("", "a")}, "python")
+        item = b'{"metadata":{"name":"%s"}}'
+        row = b'{"object":' + item + b'}' if kind == "Table" else item
+        yield case(f"{kind}-invalid-escape",
+                   b'{"kind":"%s","%s":[%s]}' % (k, key.encode(),
+                                                 row % b'a\\qb'),
+                   {("", "a")}, "python")
+        yield case(f"{kind}-invalid-utf8",
+                   b'{"kind":"%s","%s":[%s]}' % (k, key.encode(),
+                                                 row % b'\xff\xfe'),
+                   {("", "a")}, "python")
+    # a Table whose kind the sniff cannot see: the rescan under "rows"
+    yield case("Table-kind-spaced-oddly",
+               b'{"kind"  :  "Table","rows":[{"object":{"metadata":'
+               b'{"name":"a"}}},{"object":{"metadata":{"name":"b"}}}],'
+               b'"items":[]}', {("", "b")})
+
+
+@pytest.mark.parametrize(
+    "body,allowed,expect",
+    list(_generated_cases()) + list(_handwritten_cases()))
+def test_fused_filter_agrees_with_the_json_path(body, allowed, expect):
+    """The one native call (``_filter_list_wire``) against the
+    ``json.loads`` path of ``filter_body`` on the same body: the same
+    status and the same document; kept entries byte for byte; nothing
+    dropped gives back the very ``body``; and a body the scanner
+    refuses (``expect`` "python") gets the Python path's answer, its
+    401 included, through ``apply_filter``."""
+    from spicedb_kubeapi_proxy_tpu.authz.filterer import apply_filter
+    from spicedb_kubeapi_proxy_tpu.proxy.types import ProxyResponse
+
+    allowed = AllowedSet(set(allowed))
+    wire = _filter_list_wire(body, allowed)
+    if expect == "python":
+        assert wire is None
+        import spicedb_kubeapi_proxy_tpu.authz.filterer as f
+
+        resp = ProxyResponse(status=200, headers={}, body=body)
+        both = apply_filter(resp, allowed, INPUT)
+        orig, f._filter_list_wire = f._filter_list_wire, lambda *a: None
+        try:
+            alone = apply_filter(resp, allowed, INPUT)
+        finally:
+            f._filter_list_wire = orig
+        assert (both.status, both.body) == (alone.status, alone.body)
+        assert both.status in (200, 401)
+        return
+    assert wire is not None, "the scanner bailed"
+    py_status, py_out = py_filter(body, allowed)
+    assert wire[0] == py_status == 200
+    assert json.loads(wire[1]) == json.loads(py_out)
+    if py_out is body:
+        assert wire[1] is body
+        return
+    # kept entries are the upstream's own bytes, joined by a bare comma
+    doc = json.loads(body)
+    key = "rows" if doc["kind"] == "Table" else "items"
+    sep = COMPACT if b",\n" not in body else SPACED
+    kept = [json.dumps(e, separators=sep, ensure_ascii=False).encode()
+            for e in json.loads(py_out)[key]]
+    if all(k in body for k in kept):  # (not so under ensure_ascii)
+        assert b",".join(kept) in wire[1]

@@ -432,6 +432,10 @@ definition namespace {
     result["config3_fixpoint_iters"] = iters
 
     # -- config 4: 10-hop tupleset-to-userset chains ------------------------
+    # (built as chains of group#member USERSETS, with no arrow anywhere;
+    # BASELINE's "tupleset-to-userset" form, rights inherited through a
+    # recursive arrow, is the benchmark's measured ns-tree-10hop
+    # deployment, benchmark/configs/ns-tree-10hop/)
     n_chains = 2_000 // scale
     cols = {k: [] for k in cols}
     hops = []

@@ -578,6 +578,10 @@ class Engine:
         metrics.gauge("engine_residual_edges").set(
             cg.n_edges if cg.res_idx is None else len(cg.res_idx))
         metrics.gauge("engine_graph_slots").set(cg.M)
+        # what the fixpoint's loop re-walks on every trip, and how many
+        # slot ranges iterate with it (a cycle drags in all that feeds it)
+        metrics.gauge("engine_core_edges").set(cg.core_edges())
+        metrics.gauge("engine_core_ranges").set(cg.core_ranges())
         metrics.gauge("engine_delta_occupancy").set(cg.n_delta)
         if cg.tier is not None:
             cg.tier.publish_gauges()
@@ -991,6 +995,7 @@ class Engine:
                 time.perf_counter() - t0)
             it = iters()
             wait.set("fixpoint_iters", it)
+            wait.set("core_edges", cg.core_edges())
             metrics.histogram("engine_fixpoint_iterations").observe(it)
             self._count_semiring_modes(futs)
             # caveat instances that resolved missing-context this call:
@@ -1284,6 +1289,7 @@ class Engine:
                 time.perf_counter() - t0)
             it = fut.iterations()
             wait.set("fixpoint_iters", it)
+            wait.set("core_edges", cg.core_edges())
             metrics.histogram("engine_fixpoint_iterations").observe(it)
             missing = getattr(fut, "caveats_missing", lambda: 0)()
             if missing:

@@ -675,6 +675,14 @@ class CompiledGraph:
         return self.host_lock if self.host_lock is not None \
             else nullcontext()
 
+    def _level_bounds(self) -> tuple:
+        """``res_level_bounds``, or one slice for an unstratified
+        (hand-built) graph: everything is core."""
+        if self.res_level_bounds is not None:
+            return tuple(self.res_level_bounds)
+        return (0, len(self.res_src) if self.res_src is not None
+                else len(self.src))
+
     def run_meta(self, active: Optional[tuple] = None) -> "RunMeta":
         """Slim static-metadata view for jit closures: everything the
         traced fixpoint reads from the graph object, nothing that holds
@@ -686,11 +694,7 @@ class CompiledGraph:
         block's range merges plain propagation values, which is safe
         because demand closure guarantees excluded ranges cannot
         influence any queried slot."""
-        bounds = self.res_level_bounds
-        if bounds is None:
-            n_res = (len(self.res_src) if self.res_src is not None
-                     else len(self.src))
-            bounds = (0, n_res)  # unstratified: everything is core
+        bounds = self._level_bounds()
         level_ranges = []
         if self.n_levels and self.range_levels is not None:
             offs = self.range_offs
@@ -713,7 +717,7 @@ class CompiledGraph:
             M=self.M,
             programs=tuple(self.programs),
             blocks=tuple(b.slim() for b in kept),
-            res_level_bounds=tuple(bounds),
+            res_level_bounds=bounds,
             n_levels=self.n_levels,
             level_ranges=tuple(level_ranges),
             caveats=cav.metas if cav is not None else (),
@@ -1159,6 +1163,20 @@ class CompiledGraph:
         return self.query_async(
             seed_slots, q_slots, q_batch, now=now, max_iters=max_iters
         ).result()
+
+    def core_edges(self) -> int:
+        """What every trip of ``_run``'s while_loop walks again: the
+        padded residual edges of level 0 plus the cells of level-0 dense
+        blocks."""
+        bounds = self._level_bounds()
+        return int(bounds[1] - bounds[0] + sum(
+            b.n_dst * b.n_src for b in self.blocks if b.level == 0))
+
+    def core_ranges(self) -> int:
+        """Slot ranges at level 0: a cycle and everything that feeds it
+        (see ``_stratify``)."""
+        return (0 if self.range_levels is None
+                else int(np.count_nonzero(self.range_levels == 0)))
 
     def hop_bytes(self, batch: int = 1) -> dict:
         """Estimated HBM traffic (bytes) for roofline reporting, split by

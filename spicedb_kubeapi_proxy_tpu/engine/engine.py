@@ -578,10 +578,13 @@ class Engine:
         metrics.gauge("engine_residual_edges").set(
             cg.n_edges if cg.res_idx is None else len(cg.res_idx))
         metrics.gauge("engine_graph_slots").set(cg.M)
-        # what the fixpoint's loop re-walks on every trip, and how many
-        # slot ranges iterate with it (a cycle drags in all that feeds it)
+        # what the fixpoint's loop re-walks on every trip and how many
+        # slot ranges iterate with it (those on a cycle or between two),
+        # and what feeds them: walked once, before the loop
         metrics.gauge("engine_core_edges").set(cg.core_edges())
         metrics.gauge("engine_core_ranges").set(cg.core_ranges())
+        metrics.gauge("engine_feeder_edges").set(cg.feeder_edges())
+        metrics.gauge("engine_feeder_ranges").set(cg.feeder_ranges())
         metrics.gauge("engine_delta_occupancy").set(cg.n_delta)
         if cg.tier is not None:
             cg.tier.publish_gauges()

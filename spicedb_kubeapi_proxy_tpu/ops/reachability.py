@@ -211,8 +211,10 @@ class _BlockMeta:
     # None in the slim run_meta() view (the traced code reads offsets only)
     dst_local: Optional[np.ndarray]
     src_local: Optional[np.ndarray]
-    # stratification level of the dst range (0 = iterated core; k>=1 =
-    # applied once at phase k — see _stratify)
+    # the phase that applies the block (see _stratify): its dst range's
+    # level (0 = on every trip of the loop; otherwise once, before the
+    # loop if negative, after it if positive), or -1, the entry phase,
+    # when the dst range is core and the src range a feeder
     level: int = 0
     # True when dst_local/src_local hold the REFLEXIVE-TRANSITIVE CLOSURE
     # of a self-pair (src range == dst range) instead of its base edges:
@@ -330,33 +332,57 @@ def _closure_pairs(dst_local: np.ndarray, src_local: np.ndarray,
 
 def _stratify(offs: np.ndarray, src_rid: np.ndarray, dst_rid: np.ndarray,
               programs: list, ignore_self: frozenset = frozenset(),
-              potential: frozenset = frozenset()) -> tuple[dict, int]:
-    """Range-level stratification of the dependency graph.
+              potential: frozenset = frozenset()) -> tuple[dict, int, int]:
+    """Range-level stratification of the dependency graph: where every
+    slot range sits in the order of evaluation.
 
     Build the range-granularity dependency graph (edges: src range feeds
     dst range; programs: every leaf range feeds the permission range) and
-    iteratively peel ranges NOTHING still depends on. What cannot be
-    peeled — cycles (recursive groups/orgs) and their ancestors — is the
-    **core** (level 0), the only part the fixpoint must iterate. Peeled
-    ranges get levels 1..L in reverse peel order, so every level-k
-    range's inputs sit strictly below k and one application per level
-    suffices.
+    peel it from both ends:
 
-    Why it matters: in kube-shaped graphs the overwhelmingly largest
-    ranges (per-pod relations) are acyclic sinks — iterating them with
-    the core multiplies the dominant per-hop HBM traffic by the graph
-    diameter for nothing. Returns ({range_id: level}, n_levels).
+    1. from the **sink** end, repeatedly, every range NOTHING still
+       depends on. Peeled ranges get levels 1..L in reverse peel order,
+       so every level-k range's inputs sit strictly below k and one
+       application per level suffices, after the loop. What is left is
+       every cycle and all that feeds one.
+    2. from the **source** end of what is left, repeatedly, every range
+       nothing left feeds: the *feeder* levels, final before the loop
+       starts and applied once each, in order, before it. Ranges nothing
+       feeds at all (the subjects' own ``__self`` ranges) are *roots*:
+       final the moment they are seeded, so they take the lowest level
+       and no phase.
+
+    What neither peel removes — the ranges on a cycle (recursive
+    groups/orgs, a permission that rests on itself through an arrow) and
+    the ranges between two cycles — is the **core** (level 0), the only
+    part the fixpoint iterates. The sink-end peel comes first, so a graph
+    without a cycle is peeled whole by it and has neither feeders nor
+    core.
+
+    Levels are the order of execution: feeders at ``-n_pre .. -2``
+    (roots one lower still), ``-1`` for the *entry* phase (the core's
+    in-edges whose source is a feeder range, walked once; no range sits
+    there), the core at 0, the rest at ``1 .. n_levels``. Returns
+    ``({range_id: level}, n_levels, n_pre)``: ``n_pre`` counts the
+    one-shot phases before the loop, entry included (0 = no feeders).
+
+    Why it matters: an edge is worth walking again only if its source
+    can still change. In kube-shaped graphs the largest ranges (per-pod
+    relations) are acyclic sinks, and where a cycle exists nearly every
+    edge that reaches it starts in a range that is final before the
+    loop (users into groups, grants into a namespace tree).
 
     ``ignore_self``: range ids whose self-dependency (r -> r edges) is
     satisfied by a closured dense block (one application = all hops), so
     the self-edge must not force the range into the core.
 
     ``potential``: (src range, dst range) pairs the SCHEMA admits whether
-    or not a tuple uses them yet. They order the peeled levels too, so the
-    first write along one (a relation or type no loaded tuple had) fits
-    the frozen levels and rides the overlay. The core stays what the data
-    makes it: a pair that would close a cycle the data does not have, or
-    pull a peeled range into the core, is left out and stays a
+    or not a tuple uses them yet. They order the peeled levels too (sink
+    end and feeder end alike), so the first write along one (a relation
+    or type no loaded tuple had) fits the frozen levels and rides the
+    overlay. The core stays what the data makes it: a pair that would
+    close a cycle the data does not have, pull a peeled range into the
+    core, or feed a feeder from the core is left out and stays a
     ``stratification-inversion`` when it is first written.
     """
     n_ranges = len(offs)
@@ -375,7 +401,7 @@ def _stratify(offs: np.ndarray, src_rid: np.ndarray, dst_rid: np.ndarray,
         for off in set(p.leaf_off.values()):
             consumers[_range_id(offs, off)].add(p_rid)
 
-    def peel_all() -> tuple[list, set]:
+    def peel_sinks() -> tuple[list, set]:
         remaining = set(range(n_ranges))
         peel: list[list[int]] = []
         while True:
@@ -385,6 +411,27 @@ def _stratify(offs: np.ndarray, src_rid: np.ndarray, dst_rid: np.ndarray,
                 return peel, remaining
             peel.append(removable)
             remaining -= set(removable)
+
+    def peel_sources(left: set) -> tuple[set, list, set]:
+        """(roots, feeder groups in order of execution, the core) of what
+        the sink-end peel left. Every producer of such a range is such a
+        range itself (it feeds a cycle too)."""
+        producers: list[set] = [set() for _ in range(n_ranges)]
+        for s in left:
+            for d in consumers[s]:
+                producers[d].add(s)
+        # a closured self-pair nothing else feeds still needs its closure
+        # applied: it is a feeder with phases, not a root
+        roots = {r for r in left
+                 if not producers[r] and r not in ignore_self}
+        left = left - roots
+        groups: list[list[int]] = []
+        while True:
+            ready = [r for r in left if not (producers[r] & left)]
+            if not ready:
+                return roots, groups, left
+            groups.append(ready)
+            left -= set(ready)
 
     def feeds(a: int, b: int) -> bool:
         """Whether range a's values reach range b along ``consumers``."""
@@ -399,32 +446,52 @@ def _stratify(offs: np.ndarray, src_rid: np.ndarray, dst_rid: np.ndarray,
         return False
 
     if potential:
-        _, core = peel_all()
+        _, left = peel_sinks()
+        roots, groups, _ = peel_sources(left)
+        feeders = roots.union(*groups)
         for s, d in sorted(potential):
-            if s != d and s not in core and d not in core \
-                    and not feeds(d, s):
+            # an acyclic pair among feeders leaves both feeders; feeder
+            # -> core and feeder -> peeled are in order as they stand
+            if s != d and not feeds(d, s) and (
+                    (s not in left and d not in left)
+                    or (s in feeders and d in feeders)):
                 consumers[s].add(d)
-    peel, remaining = peel_all()
+    peel, left = peel_sinks()
+    roots, groups, core = peel_sources(left)
     n_levels = len(peel)
-    level = {r: 0 for r in remaining}  # cyclic core + its ancestors
+    n_feed = len(groups)
+    level = {r: 0 for r in core}  # on a cycle, or between two
     for i, grp in enumerate(peel):  # peeled first -> evaluated last
         for r in grp:
             level[r] = n_levels - i
-    return level, n_levels
+    for j, grp in enumerate(groups):  # peeled first -> evaluated first
+        for r in grp:
+            level[r] = -(n_feed - j + 1)
+    for r in roots:
+        level[r] = -(n_feed + 2)
+    return level, n_levels, (n_feed + 1 if roots or groups else 0)
 
 
 @dataclass(frozen=True)
 class RunMeta:
     """What the traced fixpoint reads from the graph: slot count,
-    permission programs, dense-block offsets, stratification (residual
-    level bounds + per-level edge-dst masks), and the caveat VM's
-    static shapes. Captured by jit closures in place of the full
-    CompiledGraph (see _jit_run_for)."""
+    permission programs, dense-block offsets, the schedule (see
+    ``_stratify``), and the caveat VM's static shapes. Captured by jit
+    closures in place of the full CompiledGraph (see _jit_run_for).
+
+    The schedule is a run of phases numbered in the order they execute:
+    ``-n_pre .. -2`` the feeder levels, ``-1`` the entry phase, ``0`` the
+    loop over the core, ``1 .. n_levels`` the levels after it. Every
+    phase but the loop is applied exactly once (``apply_level_once``).
+    ``res_level_bounds`` slices the residual in that order, so phase k's
+    edges are ``bounds[k + n_pre] : bounds[k + n_pre + 1]``; blocks and
+    programs carry their phase in ``level`` (a core range's programs
+    carry 0 and run at the entry phase and on every trip)."""
 
     M: int
     programs: tuple
     blocks: tuple
-    res_level_bounds: tuple  # len n_levels+2: slice bounds into residual
+    res_level_bounds: tuple  # len n_pre + n_levels + 2
     n_levels: int
     # per level 1..L: tuple of (offset, size) slot ranges finalized at
     # that level (merged via per-range slice writes — no dense masks)
@@ -438,6 +505,33 @@ class RunMeta:
     # per-iteration lax.cond on traced occupancy; "push"/"pull" force one
     # branch (ops/semiring.py force_mode / SDBKP_SEMIRING_MODE)
     spmm_mode: str = "auto"
+    # one-shot phases before the loop (0 = none: no cycle, or nothing
+    # feeds it), and per phase -n_pre..-1 the slot ranges it finalizes;
+    # the entry phase (last) merges into the core's ranges
+    n_pre: int = 0
+    pre_ranges: tuple = ()
+
+    def level_slice(self, k: int) -> tuple[int, int]:
+        """Bounds of phase k's residual slice."""
+        i = k + self.n_pre
+        return self.res_level_bounds[i], self.res_level_bounds[i + 1]
+
+    def scope(self, k: int) -> str:
+        """Phase k's named scope in the device trace."""
+        if k >= 0:
+            return f"level{k}" if k else "core"
+        return "entry" if k == -1 else f"feed{k + self.n_pre + 1}"
+
+    def windows(self, k: int) -> tuple:
+        """The (offset, size) slot ranges one-shot phase k merges."""
+        return (self.level_ranges[k - 1] if k > 0
+                else self.pre_ranges[k + self.n_pre])
+
+    def programs_at(self, k: int) -> list:
+        """The programs phase k runs: its own ranges', and at the entry
+        phase the core's (their feeder leaves are final by then)."""
+        level = 0 if k == -1 else k
+        return [p for p in self.programs if p.level == level]
 
 
 def convergence_fuse_steps(meta: "RunMeta") -> int:
@@ -446,14 +540,14 @@ def convergence_fuse_steps(meta: "RunMeta") -> int:
 
     Derived from the compiled graph's stratification: a stratified graph
     iterates only its small cyclic core (recursive groups/orgs, which
-    converge in a few hops — the per-pod bulk is peeled into one-shot
-    acyclic levels), so K=2 halves the convergence collectives without
-    wasting propagation work; an unstratified graph (hand-built, no
-    level split) iterates everything with unknown diameter, so a deeper
-    fuse amortizes better. The fixpoint is monotone — steps past
+    converge in a few hops — what feeds them and the per-pod bulk are
+    peeled into one-shot levels), so K=2 halves the convergence
+    collectives without wasting propagation work; an unstratified graph
+    (hand-built, no level split) iterates everything with unknown
+    diameter, so a deeper fuse amortizes better. The fixpoint is monotone — steps past
     convergence are no-ops — so K only trades at most K-1 cheap wasted
     hops against saved cross-axis collectives and host syncs."""
-    return 2 if meta.n_levels else 4
+    return 2 if meta.n_levels or meta.n_pre else 4
 
 
 @dataclass
@@ -518,10 +612,13 @@ class CompiledGraph:
     # compiled caveat table (caveats/vm.py CompiledCaveats): instance
     # context columns + op tapes, shared across incremental descendants
     caveats: Optional[object] = None
-    # stratification: residual slice bounds per level (len n_levels+2)
-    # and the level of every slot range (range_offs-aligned)
+    # stratification (see _stratify / RunMeta): residual slice bounds per
+    # phase in order of execution (len n_pre + n_levels + 2), the count
+    # of one-shot phases before and after the loop, and the level of
+    # every slot range (range_offs-aligned; negative = feeder)
     res_level_bounds: Optional[tuple] = None
     n_levels: int = 0
+    n_pre: int = 0
     range_levels: Optional[np.ndarray] = None
     # compile-time lookup tables reused by the incremental path
     range_offs: Optional[np.ndarray] = None  # ascending slot-range offsets
@@ -695,21 +792,28 @@ class CompiledGraph:
         because demand closure guarantees excluded ranges cannot
         influence any queried slot."""
         bounds = self._level_bounds()
-        level_ranges = []
-        if self.n_levels and self.range_levels is not None:
+
+        def windows(k: int) -> tuple:
+            """Slot ranges phase k finalizes (the entry phase, -1, holds
+            no range of its own: it merges into the core's)."""
             offs = self.range_offs
             ends = np.append(offs[1:], self.M)
-            for k in range(1, self.n_levels + 1):
-                wins = [
-                    (int(offs[rid]), int(ends[rid]) - int(offs[rid]))
-                    for rid in np.flatnonzero(
-                        self.range_levels == k).tolist()]
-                # even phases merge exactly the closured blocks' ranges
-                # (their in-edges merged at the odd phase just before;
-                # the closure application finalizes them here)
-                wins += [(b.dst_off, b.n_dst) for b in self.blocks
-                         if b.closured and b.level == k]
-                level_ranges.append(tuple(wins))
+            wins = [
+                (int(offs[rid]), int(ends[rid]) - int(offs[rid]))
+                for rid in np.flatnonzero(
+                    self.range_levels == (0 if k == -1 else k)).tolist()]
+            # even phases merge exactly the closured blocks' ranges
+            # (their in-edges merged at the odd phase just before;
+            # the closure application finalizes them here)
+            wins += [(b.dst_off, b.n_dst) for b in self.blocks
+                     if b.closured and b.level == k]
+            return tuple(wins)
+
+        level_ranges = pre_ranges = ()
+        if self.range_levels is not None:
+            level_ranges = tuple(
+                windows(k) for k in range(1, self.n_levels + 1))
+            pre_ranges = tuple(windows(k) for k in range(-self.n_pre, 0))
         cav = self.caveats
         kept = (self.blocks if active is None
                 else [self.blocks[i] for i in active])
@@ -719,10 +823,12 @@ class CompiledGraph:
             blocks=tuple(b.slim() for b in kept),
             res_level_bounds=bounds,
             n_levels=self.n_levels,
-            level_ranges=tuple(level_ranges),
+            level_ranges=level_ranges,
             caveats=cav.metas if cav is not None else (),
             cav_rows=cav.n_rows if cav is not None else 1,
             spmm_mode=semiring.resolved_mode(),
+            n_pre=self.n_pre,
+            pre_ranges=pre_ranges,
         )
 
     def _dev(self):
@@ -1167,22 +1273,37 @@ class CompiledGraph:
     def core_edges(self) -> int:
         """What every trip of ``_run``'s while_loop walks again: the
         padded residual edges of level 0 plus the cells of level-0 dense
-        blocks."""
+        blocks, source and destination both in a core range."""
         bounds = self._level_bounds()
-        return int(bounds[1] - bounds[0] + sum(
+        return int(bounds[self.n_pre + 1] - bounds[self.n_pre] + sum(
             b.n_dst * b.n_src for b in self.blocks if b.level == 0))
 
     def core_ranges(self) -> int:
-        """Slot ranges at level 0: a cycle and everything that feeds it
-        (see ``_stratify``)."""
+        """Slot ranges at level 0: those on a cycle or between two (see
+        ``_stratify``)."""
         return (0 if self.range_levels is None
                 else int(np.count_nonzero(self.range_levels == 0)))
+
+    def feeder_edges(self) -> int:
+        """What is walked once before the loop: the padded residual
+        edges and dense-block cells of the feeder levels and of the
+        entry phase."""
+        bounds = self._level_bounds()
+        return int(bounds[self.n_pre] - bounds[0] + sum(
+            b.n_dst * b.n_src for b in self.blocks if b.level < 0))
+
+    def feeder_ranges(self) -> int:
+        """Slot ranges final before the loop starts: all that feeds a
+        cycle and lies on none, roots included."""
+        return (0 if self.range_levels is None
+                else int(np.count_nonzero(self.range_levels < 0)))
 
     def hop_bytes(self, batch: int = 1) -> dict:
         """Estimated HBM traffic (bytes) for roofline reporting, split by
         the stratified schedule: ``total`` is the per-ITERATION cost of
         the cyclic core (what multiplies by the fixpoint iteration count);
-        ``tail_once`` is the one-shot cost of all acyclic levels. Streams
+        ``tail_once`` is the one-shot cost of every other phase (feeder
+        levels and entry before the loop, acyclic levels after it). Streams
         counted: residual gather/segment, dense-block operands (bit-packed
         or int8 A), elementwise program passes. An estimate of bytes
         *touched* — XLA fusion can only reduce it.
@@ -1241,8 +1362,8 @@ class CompiledGraph:
                       else self.n_edges)
             tail_res = 0
         else:
-            n_core = bounds[1] - bounds[0]
-            tail_res = bounds[-1] - bounds[1]
+            n_core = bounds[self.n_pre + 1] - bounds[self.n_pre]
+            tail_res = bounds[-1] - bounds[0] - n_core
         delta = self._delta_pad() * (4 + 4 + 1 + batch)
         core_res = res_bytes(n_core) + delta
         core_blk = [b for b in self.blocks if b.level == 0]
@@ -1250,9 +1371,10 @@ class CompiledGraph:
         core_prog = sum(2 * p.size * batch for p in self.programs
                         if p.level == 0)
         tail = (res_bytes(tail_res) if tail_res else 0) \
-            + sum(block_bytes(b) for b in self.blocks if b.level > 0) \
-            + sum(2 * p.size * batch for p in self.programs if p.level > 0) \
-            + self.n_levels * (delta + 2 * batch * Mp)  # merges + delta
+            + sum(block_bytes(b) for b in self.blocks if b.level) \
+            + sum(2 * p.size * batch for p in self.programs if p.level) \
+            + (self.n_pre + self.n_levels) \
+            * (delta + 2 * batch * Mp)  # merges + delta
         return {"residual": core_res, "blocks": core_blocks,
                 "programs": core_prog, "tail_once": tail,
                 "total": core_res + core_blocks + core_prog,
@@ -1472,6 +1594,31 @@ def _seed_base(cg: CompiledGraph, seeds):
     return _apply_program(cg, base.reshape(B, rows, LANE))
 
 
+def apply_level_once(meta: "RunMeta", prop_level, V, baseflat, k: int):
+    """One-shot phase ``k`` of the schedule (any phase but the loop):
+    propagate the phase's edges, blocks and the delta overlay from ``V``,
+    whose sources are final by now; write the result into the phase's
+    slot ranges and no other (a replacing merge, so finalized levels are
+    untouched and no dense masks exist anywhere); run the phase's
+    programs. ``prop_level(V, k) -> (prop [B, Mp], is_push)`` is the
+    caller's hop (the mesh joins its shards inside it), so the
+    single-chip and the sharded fixpoint share this step letter for
+    letter. Returns ``(V, is_push)``."""
+    B, rows = V.shape[0], V.shape[1]
+    Mp = rows * LANE
+    with jax.named_scope(meta.scope(k)):
+        prop, is_push = prop_level(V, k)
+        propb = prop | baseflat
+        Vflat = V.reshape(B, Mp)
+        for off, size in meta.windows(k):
+            Vflat = jax.lax.dynamic_update_slice(
+                Vflat,
+                jax.lax.dynamic_slice(propb, (0, off), (B, size)),
+                (0, off))
+        return _apply_program(meta, Vflat.reshape(B, rows, LANE),
+                              meta.programs_at(k)), is_push
+
+
 def _run(cg: "RunMeta", blocks, blocks_bits, src, dst, exp_rel, cav,
          dsrc, ddst, dexp, dcav, cav_static, cav_req,
          seeds, q_slots, q_batch, now_rel, crossover, *,
@@ -1481,11 +1628,24 @@ def _run(cg: "RunMeta", blocks, blocks_bits, src, dst, exp_rel, cav,
     bytes per elementwise pass instead of a lane-padded 128x that; slot s
     lives at (s // LANE, s % LANE) and every range is row-aligned.
 
-    Schedule (see _stratify): only the cyclic CORE (level 0) iterates in
-    the while_loop; each acyclic level k=1..n_levels is then applied
-    exactly once — its ranges' in-edges all live at level k and their
-    sources are already final. In kube-shaped graphs this keeps the
-    dominant per-pod blocks out of the loop entirely.
+    Schedule (see _stratify, RunMeta): an edge is walked again only if
+    its source can still change.
+
+    1. feeder levels ``-n_pre .. -2``, once each, in order: the ranges
+       that feed a cycle and lie on none are final before the loop;
+    2. the entry phase ``-1``, once: the core's in-edges whose source is
+       a feeder range, merged into the core's ranges, and the core's
+       programs over their (final) feeder leaves. The state this leaves
+       is the loop's start and its constant term;
+    3. the while_loop over the CORE (level 0): only edges whose source
+       and destination are both core ranges;
+    4. each level ``1 .. n_levels`` after it, once: their ranges'
+       in-edges all live at their level and their sources are final.
+
+    A graph without a cycle has no feeders and no core: it runs 3 over
+    an empty slice (the convergence probe) and 4. In kube-shaped graphs
+    4 keeps the dominant per-pod blocks out of the loop entirely; where a
+    cycle exists 1 and 2 keep out of it nearly every edge that reaches it.
 
     Every hop is ONE call into the masked-semiring primitive
     (ops/semiring.propagate) — the same primitive the shard_map body
@@ -1515,29 +1675,34 @@ def _run(cg: "RunMeta", blocks, blocks_bits, src, dst, exp_rel, cav,
     dact = semiring.edge_activation(dexp, now_rel, dcav, cav_ok)
     base = _seed_base(cg, seeds)
     baseflat = base.reshape(B, Mp)
-    bounds = cg.res_level_bounds
-    core_progs = [p for p in cg.programs if p.level == 0]
-
-    def level_slice(k):
-        lo, hi = bounds[k], bounds[k + 1]
-        return src[lo:hi], dst[lo:hi], act[lo:hi]
+    core_progs = cg.programs_at(0)
 
     def prop_level(V, k):
         Vflat = V.reshape(B, Mp)
-        s, d, a = level_slice(k)
+        lo, hi = cg.level_slice(k)
         occ = semiring.frontier_occupancy(Vflat)
         return semiring.propagate(
-            cg.blocks, blocks, blocks_bits, s, d, a,
-            dsrc, ddst, dact, Vflat, occ, crossover,
+            cg.blocks, blocks, blocks_bits, src[lo:hi], dst[lo:hi],
+            act[lo:hi], dsrc, ddst, dact, Vflat, occ, crossover,
             level=k, mode=cg.spmm_mode)
 
-    # jax.named_scope below names the phases for HLO dumps and xprof
-    # (op_name metadata only: the computation is the same)
+    # jax.named_scope names the phases for HLO dumps and xprof (op_name
+    # metadata only: the computation is the same). No one-shot phase may
+    # be skipped — incremental delta edges can target any level and only
+    # that phase's re-application establishes their values.
+    V, n_push = base, jnp.int32(0)
+    for k in range(-cg.n_pre, 0):
+        V, is_push = apply_level_once(cg, prop_level, V, baseflat, k)
+        n_push = n_push + is_push
+    # what the loop ORs into every trip: the seeds, the feeders' final
+    # values and the entry edges' contribution (``base`` itself where
+    # nothing feeds the core)
+    const = V
 
     def step(V):
         prop, is_push = prop_level(V, 0)
         return _apply_program(
-            cg, prop.reshape(B, rows, LANE) | base, core_progs), is_push
+            cg, prop.reshape(B, rows, LANE) | const, core_progs), is_push
 
     def cond(state):
         V, prev_changed, it, _ = state
@@ -1548,27 +1713,12 @@ def _run(cg: "RunMeta", blocks, blocks_bits, src, dst, exp_rel, cav,
         V2, is_push = step(V)
         return V2, jnp.any(V2 != V), it + 1, n_push + is_push
 
-    with jax.named_scope("core"):
+    with jax.named_scope(cg.scope(0)):
         V, still_changing, iters, n_push = jax.lax.while_loop(
-            cond, body, (base, jnp.bool_(True), 0, jnp.int32(0)))
-    # acyclic levels: one application each. No phase may be skipped —
-    # incremental delta edges can target any level and only this phase's
-    # re-application establishes their values. The merge writes only the
-    # level's (row-aligned) slot ranges, so finalized lower levels are
-    # untouched and no dense masks exist anywhere.
+            cond, body, (V, jnp.bool_(True), 0, n_push))
     for k in range(1, cg.n_levels + 1):
-        with jax.named_scope(f"level{k}"):
-            progs_k = [p for p in cg.programs if p.level == k]
-            prop, is_push = prop_level(V, k)
-            n_push = n_push + is_push
-            propb = prop | baseflat
-            Vflat = V.reshape(B, Mp)
-            for off, size in cg.level_ranges[k - 1]:
-                Vflat = jax.lax.dynamic_update_slice(
-                    Vflat,
-                    jax.lax.dynamic_slice(propb, (0, off), (B, size)),
-                    (0, off))
-            V = _apply_program(cg, Vflat.reshape(B, rows, LANE), progs_k)
+        V, is_push = apply_level_once(cg, prop_level, V, baseflat, k)
+        n_push = n_push + is_push
     # still_changing at loop exit means we hit max_iters before convergence;
     # surface it so the host can raise instead of silently denying
     with jax.named_scope("readout"):
@@ -1929,7 +2079,7 @@ def compile_graph(schema: Schema, snapshot: Snapshot,
                             in slot_offset:
                         admitted.add(((a.type, arrow.target),
                                       (tname, f"__arrow_{pname}_{k}")))
-    level_map, n_levels = _stratify(
+    level_map, n_levels, n_pre = _stratify(
         offs, src_rid, dst_rid, programs,
         ignore_self=frozenset(closure_rids),
         potential=frozenset(
@@ -1951,30 +2101,36 @@ def compile_graph(schema: Schema, snapshot: Snapshot,
         for off_ in set(p.leaf_off.values()):
             adj_pairs.add((_range_id(offs, off_), p_rid))
     range_adj = tuple(sorted(adj_pairs))
+    range_levels = np.asarray(
+        [level_map[r] for r in range(len(offs))], dtype=np.int32)
     if closure_rids:
         # Levels are DOUBLED so a peeled closured range gets two ordered
         # phases at its position in the topo order: odd phase 2k-1
         # applies the range's in-edges (+ normal blocks + programs) and
         # merges; even phase 2k applies only closure blocks, whose
         # diagonal re-gathers the freshly merged values and whose closure
-        # cells complete every multi-hop chain. Without closured blocks
-        # the schedule keeps its original single phase per level.
-        range_levels = np.asarray(
-            [0 if level_map[r] == 0 else 2 * level_map[r] - 1
-             for r in range(len(offs))], dtype=np.int32)
+        # cells complete every multi-hop chain. Feeder levels double the
+        # same way below zero (-2, -3, .. become the odd phases -3, -5,
+        # .. with the closure phases -2, -4, .. after each; the entry
+        # phase stays -1). Without closured blocks the schedule keeps
+        # its original single phase per level.
+        range_levels = 2 * range_levels - np.sign(range_levels)
         n_levels *= 2
-    else:
-        range_levels = np.asarray(
-            [level_map[r] for r in range(len(offs))], dtype=np.int32)
+        n_pre = max(2 * n_pre - 1, 0)
     for p in programs:
         p.level = int(range_levels[_range_id(offs, p.dst_off)])
 
     blocks: list[_BlockMeta] = []
     if n_edges:
-        edge_level = range_levels[dst_rid]
+        # an edge is applied at its destination's level; into a core
+        # range from a feeder range it is an entry edge, walked once
+        # before the loop (phase -1) and not on every trip
+        edge_level = np.where(
+            (range_levels[dst_rid] == 0) & (range_levels[src_rid] < 0),
+            -1, range_levels[dst_rid])
         for k, sel in dense_sel.items():
             d_rid, s_rid = divmod(k, len(offs))
-            lvl = int(range_levels[d_rid])
+            lvl = int(edge_level[sel[0]])  # one range pair, one phase
             if d_rid == s_rid and d_rid in closure_rids:
                 dl, sl = closure_coo[d_rid]
                 blocks.append(_BlockMeta(
@@ -1996,20 +2152,22 @@ def compile_graph(schema: Schema, snapshot: Snapshot,
     res_idx = (np.sort(np.concatenate(res_parts)) if res_parts
                else np.empty(0, dtype=np.int64))
 
-    # padded host residual views ordered by (level, dst) — the traced
-    # program slices the residual per level (res_level_bounds), each slice
+    # padded host residual views ordered by (phase, dst) — the traced
+    # program slices the residual per phase, in order of execution
+    # (res_level_bounds: feeder levels, entry, core, levels), each slice
     # dst-sorted for segment_max's indices_are_sorted and padded to its
     # own power-of-two bucket so the bounds (part of the jit signature)
     # stay stable as edge counts drift between recompiles
     n_res = len(res_idx)
+    n_slices = n_pre + n_levels + 1
     if n_res:
-        res_lvl = edge_level[res_idx]
+        res_lvl = edge_level[res_idx] + n_pre
         order = np.lexsort((dst[res_idx], res_lvl))
         res_idx = res_idx[order]
         res_lvl = res_lvl[order]
-        counts_per_level = np.bincount(res_lvl, minlength=n_levels + 1)
+        counts_per_level = np.bincount(res_lvl, minlength=n_slices)
     else:
-        counts_per_level = np.zeros(n_levels + 1, dtype=np.int64)
+        counts_per_level = np.zeros(n_slices, dtype=np.int64)
     pads = [_next_bucket(max(int(c), 1)) for c in counts_per_level]
     res_level_bounds = tuple(int(x) for x in np.concatenate(
         [[0], np.cumsum(pads)]))
@@ -2018,7 +2176,7 @@ def compile_graph(schema: Schema, snapshot: Snapshot,
     res_exp = np.full(res_level_bounds[-1], -np.inf, dtype=np.float32)
     res_cav = np.zeros(res_level_bounds[-1], dtype=np.int32)
     pos = 0
-    for k in range(n_levels + 1):
+    for k in range(n_slices):
         n_k = int(counts_per_level[k])
         lo = res_level_bounds[k]
         sel = res_idx[pos:pos + n_k]
@@ -2071,6 +2229,7 @@ def compile_graph(schema: Schema, snapshot: Snapshot,
         caveats=caveat_table,
         res_level_bounds=res_level_bounds,
         n_levels=n_levels,
+        n_pre=n_pre,
         range_levels=range_levels,
         range_offs=offs,
         block_index={(b.dst_off, b.src_off): i
@@ -2140,9 +2299,11 @@ def _edges_for_tuple(cg: CompiledGraph, store, rel):
 def _level_order_ok(cg: CompiledGraph, src: int, dst: int) -> bool:
     """A delta edge is compatible with the frozen stratification iff its
     source finalizes before (or iterates with) its destination: both in
-    the iterated core, or level(src) < level(dst). Violations — a
-    first-ever dependency direction between two ranges — need a
-    re-stratifying full recompile."""
+    the iterated core, or level(src) < level(dst). Levels are the order
+    of execution (feeders below zero, the core at 0, the rest above), so
+    feeder -> core and core -> level are in order and core -> feeder is
+    not. Violations — a first-ever dependency direction between two
+    ranges — need a re-stratifying full recompile."""
     if cg.range_levels is None:
         return True  # unstratified graph: single full fixpoint
     offs = cg.range_offs
@@ -2163,7 +2324,7 @@ def _pair_block(cg: CompiledGraph, src: int, dst: int):
 
 def _res_positions(cg: CompiledGraph, src: int, dst: int) -> list[int]:
     """Base-residual positions holding the (src, dst) edge. The residual
-    is ordered by (level, dst), so each level slice is binary-searched
+    is ordered by (phase, dst), so each phase's slice is binary-searched
     and its per-dst run scanned for the src match."""
     bounds = cg.res_level_bounds or (0, len(cg.res_dst))
     out: list[int] = []

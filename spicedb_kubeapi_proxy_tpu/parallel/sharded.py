@@ -94,6 +94,7 @@ from ..ops.reachability import (
     _apply_program,
     _next_bucket,
     _seed_base,
+    apply_level_once,
     convergence_fuse_steps,
 )
 
@@ -103,8 +104,9 @@ def _run_sharded(meta, block_meta, ng: int, level_edges, blocks,
                  seeds, q_slots, now_rel, crossover, *,
                  max_iters: int, k_steps: int):
     """Per-device body (inside shard_map). Shapes are the LOCAL shards:
-    level_edges[k] = (src, dst, exp, cav) [E_k/ng] (per stratification
-    level, each chunk dst-sorted); blocks[i] [n_dst, n_src/ng];
+    level_edges[k + n_pre] = (src, dst, exp, cav) [E_k/ng] (per phase of
+    the schedule, in order of execution, each chunk dst-sorted);
+    blocks[i] [n_dst, n_src/ng];
     dsrc/ddst/dexp/dcav [D/ng] (the incremental delta segment); seeds
     [B/nd, 2]; q_slots [B/nd, Q]. ``cav_static`` (instance tables + VM
     tapes) and ``cav_req`` (request context) are REPLICATED — every chip
@@ -113,11 +115,13 @@ def _run_sharded(meta, block_meta, ng: int, level_edges, blocks,
     the CompiledGraph — the closure must not pin host/device graph
     state).
 
-    Same stratified schedule as the single-chip _run, through the SAME
+    Same stratified schedule as the single-chip _run, read from the same
+    RunMeta (feeder levels and the entry phase once before the loop, each
+    through the same ``apply_level_once``), and the SAME
     masked-semiring primitive (ops/semiring.propagate, with
     ``shard=(g_idx, ng)`` so block frontiers cover only this chip's
-    src-axis chunk): only the cyclic core (level 0) iterates; each
-    acyclic level is applied once; partial propagations are joined with
+    src-axis chunk): only the cyclic core (level 0) iterates; every
+    other phase is applied once; partial propagations are joined with
     pmax over ICI AFTER the primitive returns (outside its push/pull
     lax.cond — a collective inside a branch devices may disagree on
     would deadlock). Edge activation (expiration ∧ caveat verdict) is
@@ -153,10 +157,10 @@ def _run_sharded(meta, block_meta, ng: int, level_edges, blocks,
 
     def prop_level(V, k):
         Vflat = V.reshape(B, Mp)
-        src, dst = level_edges[k][0], level_edges[k][1]
+        src, dst = level_edges[k + meta.n_pre][:2]
         occ = semiring.frontier_occupancy(Vflat)
         prop, is_push = semiring.propagate(
-            block_meta, blocks, no_bits, src, dst, acts[k],
+            block_meta, blocks, no_bits, src, dst, acts[k + meta.n_pre],
             dsrc, ddst, dact, Vflat, occ, crossover,
             level=k, mode=meta.spmm_mode, shard=(g_idx, ng))
         # join partials over ICI — outside the primitive's mode cond.
@@ -170,12 +174,20 @@ def _run_sharded(meta, block_meta, ng: int, level_edges, blocks,
         joined = jax.lax.pmax(prop.astype(jnp.int32), "graph")
         return joined.astype(jnp.uint8), is_push
 
-    core_progs = [p for p in meta.programs if p.level == 0]
+    core_progs = meta.programs_at(0)
+    # before the loop: feeder levels and the entry phase, once each (see
+    # ops/reachability._run); what they leave is the loop's start and
+    # its constant term
+    V, n_push = base, jnp.int32(0)
+    for k in range(-meta.n_pre, 0):
+        V, is_push = apply_level_once(meta, prop_level, V, baseflat, k)
+        n_push = n_push + is_push
+    const = V
 
     def step(V):
         prop, is_push = prop_level(V, 0)
         return _apply_program(
-            meta, prop.reshape(B, rows, LANE) | base, core_progs), is_push
+            meta, prop.reshape(B, rows, LANE) | const, core_progs), is_push
 
     def cond(state):
         _, prev_changed, it, _, _ = state
@@ -204,22 +216,14 @@ def _run_sharded(meta, block_meta, ng: int, level_edges, blocks,
         return (Vk, fl[k_steps - 1], it + fl.sum(), checks + 1,
                 n_push + pushes)
 
-    V, still_changing, iters, checks, n_push = jax.lax.while_loop(
-        cond, body, (base, jnp.int32(1), jnp.int32(0), jnp.int32(0),
-                     jnp.int32(0))
-    )
+    with jax.named_scope(meta.scope(0)):
+        V, still_changing, iters, checks, n_push = jax.lax.while_loop(
+            cond, body, (V, jnp.int32(1), jnp.int32(0), jnp.int32(0),
+                         n_push))
     # acyclic levels: one application each (see ops/reachability._run)
     for k in range(1, meta.n_levels + 1):
-        progs_k = [p for p in meta.programs if p.level == k]
-        prop, is_push = prop_level(V, k)
+        V, is_push = apply_level_once(meta, prop_level, V, baseflat, k)
         n_push = n_push + is_push
-        propb = prop | baseflat
-        Vflat = V.reshape(B, Mp)
-        for off, size in meta.level_ranges[k - 1]:
-            Vflat = jax.lax.dynamic_update_slice(
-                Vflat, jax.lax.dynamic_slice(propb, (0, off), (B, size)),
-                (0, off))
-        V = _apply_program(meta, Vflat.reshape(B, rows, LANE), progs_k)
     out = V.reshape(B, Mp)[brange[:, None], q_slots].astype(jnp.bool_)
     # replicate the (tiny, bool) result over the data axis so it is fully
     # addressable on EVERY process — under a multi-host mesh a
@@ -405,7 +409,7 @@ class ShardedGraph:
         # updated() generations: the slot layout is incremental-invariant)
         self._qgrid: dict = {}
 
-        if meta.n_levels + 1 != len(self._level_edges):
+        if meta.n_pre + meta.n_levels + 1 != len(self._level_edges):
             raise AssertionError(
                 "level edge arrays out of step with stratification")
         self._run = self._program(mesh)
@@ -482,9 +486,10 @@ class ShardedGraph:
         return s, d, e, c
 
     def _host_level_edges(self):
-        """(level_arrays, kept_blocks): per stratification level 0..L, the
+        """(level_arrays, kept_blocks): per phase of the schedule, in
+        order of execution (feeder levels, entry, core, levels), the
         (src, dst, exp, cav) edge arrays this mesh gathers over (base
-        residual slice + folded-back blocks of that level, dst-sorted,
+        residual slice + folded-back blocks of that phase, dst-sorted,
         padded to the graph axis) and the dense blocks that stay on the
         MXU path (src axis divisible by the graph-axis size). Folded
         block edges are never caveated (caveated edges are excluded from
@@ -519,13 +524,12 @@ class ShardedGraph:
         res_cav = cg.res_cav
         if res_cav is None or len(res_cav) != len(cg.res_src):
             res_cav = np.zeros(len(cg.res_src), dtype=np.int32)
-        n_levels = cg.n_levels
         out = []
-        for k in range(n_levels + 1):
-            # base residual slice for the level: already dst-sorted and
+        for i, k in enumerate(range(-cg.n_pre, cg.n_levels + 1)):
+            # base residual slice for the phase: already dst-sorted and
             # carrying incremental invalidations (res_exp -> -inf); its
             # trailing bucket padding is harmless trash
-            lo, hi = bounds[k], bounds[k + 1]
+            lo, hi = bounds[i], bounds[i + 1]
             parts = [(cg.res_src[lo:hi], cg.res_dst[lo:hi],
                       cg.res_exp[lo:hi], res_cav[lo:hi])]
             for bm in folded:

@@ -139,11 +139,9 @@ def graph():
     return cg
 
 
-def test_whole_fixpoint_compiles_in_auto(one_chip, compiled_kernels, graph):
-    """One whole jitted fixpoint (``_jit_run_for``'s program), in
-    ``auto``: both kernels sit in the two branches of the per-iteration
-    ``lax.cond``, so both must compile in one program."""
-    cg = graph
+def _compile_fixpoint(cg, one_chip, q_contig_len: int) -> str:
+    """One whole jitted fixpoint (``_jit_run_for``'s program) over ``cg``,
+    in ``auto``, compiled for the described chip: its HLO text."""
 
     def S(shape, dtype):
         return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
@@ -156,22 +154,33 @@ def test_whole_fixpoint_compiles_in_auto(one_chip, compiled_kernels, graph):
     bits = tuple(S((b.n_dst, bitprop._k_pad(b.n_src)), jnp.uint32)
                  if bitprop.eligible(b.n_dst, b.n_src) else None
                  for b in cg.blocks)
-    assert any(b is not None for b in bits)
-    cav_req, _ = cg.caveats.encode_request({"ip": "10.1.2.3"}, time.time())
-    n_pod = cg.type_sizes["pod"]
+    cav_static = cav_req = ()
+    if cg.caveats is not None and cg.caveats.metas:
+        cav_static = cg.caveats.device_static()
+        cav_req, _ = cg.caveats.encode_request({"ip": "10.1.2.3"},
+                                               time.time())
     with semiring.force_mode("auto"):
         run = jax.jit(reachability.fixpoint_program(cg.run_meta()),
                       static_argnames=("max_iters", "q_contig_len",
                                        "q_contig_rows"))
-        text = run.lower(
+        return run.lower(
             blocks, bits,
             *like((cg.res_src, cg.res_dst, cg.res_exp, cg.res_cav)),
             *like(cg._delta_host()),
-            like(cg.caveats.device_static()), like(cav_req),
+            like(cav_static), like(cav_req),
             S((1, 2), jnp.int32), S((), jnp.int32), S((), jnp.int32),
             S((), jnp.float32), S((), jnp.float32),
-            max_iters=reachability.DEFAULT_MAX_ITERS, q_contig_len=n_pod,
+            max_iters=reachability.DEFAULT_MAX_ITERS,
+            q_contig_len=q_contig_len,
         ).compile().as_text()
+
+
+def test_whole_fixpoint_compiles_in_auto(one_chip, compiled_kernels, graph):
+    """Both kernels sit in the two branches of the per-iteration
+    ``lax.cond``, so both must compile in one program."""
+    cg = graph
+    assert any(bitprop.eligible(b.n_dst, b.n_src) for b in cg.blocks)
+    text = _compile_fixpoint(cg, one_chip, cg.type_sizes["pod"])
     # every block: its dense kernel in the pull branch, its bit kernel in
     # the push branch
     assert text.count("tpu_custom_call") >= 2 * len(cg.blocks)
@@ -179,6 +188,67 @@ def test_whole_fixpoint_compiles_in_auto(one_chip, compiled_kernels, graph):
     # jit_sdbkp_fixpoint, the kernels' operations sdbkp_*_hop
     assert text.startswith("HloModule jit_sdbkp_fixpoint")
     assert "sdbkp_bit_hop" in text and "sdbkp_dense_hop" in text
+
+
+def test_fixpoint_with_feeder_levels_and_entry_edges_compiles(
+        one_chip, compiled_kernels):
+    """The schedule a graph with a cycle gets: a feeder level and an
+    entry phase, each with residual edges and a dense block, a loop over
+    the cycle, levels after it, the delta overlay riding every phase. The
+    phases' named scopes are in the compiled program's metadata: a
+    device trace's share by scope reads them."""
+    from spicedb_kubeapi_proxy_tpu.engine import Engine
+    from spicedb_kubeapi_proxy_tpu.engine.store import WriteOp
+    from spicedb_kubeapi_proxy_tpu.models import parse_schema
+    from spicedb_kubeapi_proxy_tpu.models.tuples import parse_relationship
+
+    e = Engine(schema=parse_schema("""
+definition user {}
+definition group { relation member: user }
+definition team { relation member: user | group#member | team#member }
+definition namespace {
+  relation parent: namespace
+  relation viewer: team#member
+  permission view = viewer + parent->view
+}
+definition pod {
+  relation namespace: namespace
+  permission view = namespace->view
+}
+"""))
+    rng = np.random.default_rng(29)
+    n_user, n_team = 4000, 2000  # buckets 4096 x 2048: a block
+    rels = {f"group:g{u % 64}#member@user:u{u}" for u in range(n_user)}
+    # user -> team#member: dense enough for an entry block
+    rels |= {f"team:t{t}#member@user:u{u}" for t in range(n_team)
+             for u in rng.integers(n_user, size=4).tolist()}
+    # group#member -> team#member: too few for a block, residual entry
+    rels |= {f"team:t{t}#member@group:g{t % 64}#member" for t in range(100)}
+    # team#member -> team#member: under DENSE_MIN_EDGES, so the
+    # self-pair is not closed on the host and stays a cycle
+    rels |= {f"team:t{t // 2}#member@team:t{t}#member"
+             for t in range(1, 1000)}
+    rels |= {f"namespace:n{n}#viewer@team:t{n}#member" for n in range(200)}
+    rels |= {f"namespace:n{n}#parent@namespace:n{n // 3}"
+             for n in range(1, 200)}
+    rels |= {f"pod:n{p % 200}/p{p}#namespace@namespace:n{p % 200}"
+             for p in range(400)}
+    e.write_relationships([WriteOp("touch", parse_relationship(r))
+                           for r in sorted(rels)])
+    e.compiled()  # the base; the write after it lands in the overlay
+    e.write_relationships([WriteOp("touch", parse_relationship(
+        "team:t7#member@user:u9"))])
+    cg = e.compiled()
+    assert cg.n_delta == 1 and cg.n_pre == 2 and cg.n_levels >= 1
+    lo, hi = cg.run_meta().level_slice(-1)
+    assert (cg.res_dst[lo:hi] != cg.M).sum() == 100
+    # users into groups at the feeder level, users into teams at the entry
+    assert sorted(b.level for b in cg.blocks) == [-2, -1]
+    text = _compile_fixpoint(cg, one_chip, cg.type_sizes["pod"])
+    assert text.startswith("HloModule jit_sdbkp_fixpoint")
+    for scope in ("feed1", "entry", "core", "level1", "readout"):
+        assert f"/{scope}/" in text, scope
+    assert text.count("tpu_custom_call") >= 2  # both blocks' kernels
 
 
 def test_mesh_fixpoint_compiles_and_joins_as_int32(topo, one_chip,

@@ -3,10 +3,11 @@
 ``ns-tree-10hop`` deployment at its rehearsal size, built by its own
 ``generate.py`` through ``benchmark/deployment.py``. The engine, the
 benchmark's plain reference and the oracle agree on lookups and checks at
-every depth of the tree; the cycle and what feeds it are the iterated
-core, whose trips follow the depth of the data; the gauges say what the
-loop walks; and the served namespace list names what the reference
-names.
+every depth of the tree; the cycle alone is the iterated core, what
+feeds it sits at feeder levels before the loop, and the loop's trips
+follow the depth of the data; the gauges say what the loop walks and
+what was walked once before it; and the served namespace list names what
+the reference names.
 """
 
 import asyncio
@@ -109,13 +110,14 @@ def test_checks_agree_at_every_depth(tree, depth):
                               "user", i.subject_id) for i in items] == want
 
 
-def test_the_core_holds_the_cycle_and_its_feeders_and_trips_follow_depth(
-        tree):
-    """Pins what the benchmark's cell measures: ``namespace#viewer`` (a
-    plain relation, acyclic by itself) iterates with the cycle it feeds,
-    and a right bound at a root takes one trip per level to reach a
-    leaf. A change that hoists the feeders out of the loop, or closes
-    ``parent`` on the host, changes this test knowingly."""
+def test_the_core_holds_the_cycle_alone_and_trips_follow_depth(tree):
+    """Pins what the benchmark's cell measures: the loop holds the cycle
+    ``namespace#view`` <-> its arrow term and nothing else; grants,
+    creators and membership (plain relations, acyclic by themselves) are
+    final before it starts; and a right bound at a root takes one trip
+    per level to reach a leaf. (Until PR 29 the feeders sat at level 0
+    and iterated with the cycle.) A change that closes ``parent`` on the
+    host changes this test knowingly."""
     cg = tree.engine.compiled()
 
     def level_of(typ, rel):
@@ -124,9 +126,12 @@ def test_the_core_holds_the_cycle_and_its_feeders_and_trips_follow_depth(
             cg.range_offs, off, side="right") - 1])
 
     assert level_of("namespace", "view") == 0
-    assert level_of("namespace", "viewer") == 0
-    assert level_of("namespace", "creator") == 0
-    assert level_of("group", "member") == 0
+    assert level_of("namespace", "__arrow_view_0") == 0
+    assert cg.core_ranges() == 2
+    # feeder levels, in order: membership before the grants that name it
+    assert level_of("user", "__self") < level_of("group", "member") \
+        < level_of("namespace", "viewer") < -1
+    assert level_of("user", "__self") < level_of("namespace", "creator") < -1
     assert level_of("pod", "view") > 0  # rests on the cycle, not in it
     leaf = int(np.flatnonzero(tree.level == 9)[0])
     heir = str(tree.users[tree.creator[tree.root[leaf]]])
@@ -134,16 +139,18 @@ def test_the_core_holds_the_cycle_and_its_feeders_and_trips_follow_depth(
         "namespace", str(tree.names["namespace"][leaf]), "view", "user",
         heir)])
     assert fut.result() == [True]
-    # nine arrows below the root: ten trips carry the right down (one
-    # more where it starts from a group), the loop's last finds nothing new
-    assert fut.iterations() in (11, 12)
+    # nine arrows below the root: the entry phase sets the root's view
+    # (creator and grants are final, through a group or not), nine trips
+    # carry it down, the loop's last finds nothing new. Until PR 29 the
+    # loop also spent a trip on the grant and one more on a group: 11, 12
+    assert fut.iterations() == 10
     low = next(u for u in range(len(tree.users)) if tree.level[
         tree.ref.lookup("namespace#view", u)].min() == 9)
     fut = tree.engine.check_bulk_async([CheckItem(
         "namespace", str(tree.names["namespace"][leaf]), "view", "user",
         str(tree.users[low]))])
     fut.result()
-    assert fut.iterations() <= 3  # bound at leaves only: nothing to carry
+    assert fut.iterations() <= 2  # bound at leaves only: nothing to carry
 
 
 def test_core_gauges_read_what_the_level_bounds_say(tree):
@@ -152,12 +159,20 @@ def test_core_gauges_read_what_the_level_bounds_say(tree):
     bounds = cg.res_level_bounds
     cells = sum(b.n_dst * b.n_src for b in cg.blocks if b.level == 0)
     edges, ranges = cg.core_edges(), cg.core_ranges()
-    assert edges == bounds[1] - bounds[0] + cells
-    assert ranges == int((cg.range_levels == 0).sum()) >= 5
+    lo, hi = cg.run_meta().level_slice(0)
+    assert (lo, hi) == (bounds[cg.n_pre], bounds[cg.n_pre + 1])
+    assert edges == hi - lo + cells
+    assert ranges == int((cg.range_levels == 0).sum()) == 2
     assert metrics.gauge("engine_core_edges").value == edges
     assert metrics.gauge("engine_core_ranges").value == ranges
+    # what moved out of the loop: every slice before the core's, and the
+    # ranges they finalize (the users' own range among them)
+    assert metrics.gauge("engine_feeder_edges").value \
+        == cg.feeder_edges() == lo - bounds[0] > edges
+    assert metrics.gauge("engine_feeder_ranges").value \
+        == cg.feeder_ranges() == int((cg.range_levels < 0).sum()) == 4
     # the core is part of the residual, not all of it: pods lie outside
-    assert bounds[1] < bounds[-1]
+    assert bounds[0] < lo < hi < bounds[-1]
     assert metrics.gauge("engine_residual_edges").value == len(cg.res_idx)
 
 

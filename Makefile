@@ -5,7 +5,7 @@ PY ?= python
 # `verify` uses pipefail, which /bin/sh (dash) lacks
 SHELL := /bin/bash
 
-.PHONY: test test-quick chaos chaos-campaign bench bench-quick bench-smoke bench-macro serve-dev demo native lint analyze verify image clean
+.PHONY: test test-quick chaos chaos-campaign serve-dev demo native lint analyze verify image clean
 
 # full suite on the virtual 8-device CPU mesh (tests/conftest.py)
 test:
@@ -36,38 +36,6 @@ CHAOS_EPISODES ?= short
 chaos-campaign:
 	$(PY) -m spicedb_kubeapi_proxy_tpu.chaos.campaign \
 	  --seeds $(CHAOS_SEEDS) --episodes $(CHAOS_EPISODES)
-
-# the headline benchmark: needs a TPU (no CPU fall-back; see bench-quick)
-bench:
-	$(PY) bench.py
-
-bench-quick:
-	$(PY) bench.py --quick
-
-# CI-sized bench exercising the full hot path including the decision
-# cache's repeat-traffic phase (cold vs warm p50 + hit rate on stderr),
-# gated by the relative regression checks (relative = internal to one
-# run, so any backend speed works):
-#  - tools/write_path_gate.py: zero recompiles under steady-state churn
-#    and read-after-write p50 within a fixed ratio of the same run's
-#    read-only p50 (the pre-overlay seed sat at 2.16x)
-#  - tools/tiered_gate.py: hot-working-set p50 under the 50% device
-#    budget within TIERED_RATIO (default 1.3x) of the same run's
-#    all-resident p50, oracle parity at the beyond-budget point, and
-#    zero recompiles across steady streaming
-# One bench run feeds both gates via a temp file (they can't share a
-# pipe), removed only on success so a failing run leaves the evidence.
-bench-smoke:
-	$(PY) bench.py --quick > /tmp/_bench_smoke.json
-	$(PY) tools/write_path_gate.py /tmp/_bench_smoke.json
-	$(PY) tools/tiered_gate.py /tmp/_bench_smoke.json
-	rm -f /tmp/_bench_smoke.json
-
-# open-loop macrobench smoke: ONLY the trace-shaped offered-load sweep
-# at --tiny scale (seconds, not minutes) — proves the goodput curve,
-# knee estimate, burst p99.9, and SLO attainment all emit
-bench-macro:
-	$(PY) bench.py --tiny --macro-only
 
 # fully self-contained demo: proxy + in-memory upstream + sample rules
 # on http://127.0.0.1:8080 (the reference's `mage dev:up`+`dev:run` flow

@@ -20,8 +20,8 @@ design:
 - a shed (``AdmissionRejected``) is an accounted outcome, not an error:
   the curves need goodput AND shed rate per offered-load point.
 
-Per-op latencies land in ``loadgen_op_seconds{op=...}`` histograms (the
-sweep reads p50/p99/p99.9 out of windowed snapshot deltas) and in raw
+Per-op latencies land in ``loadgen_op_seconds{op=...}`` histograms (a
+report carries the snapshots that window them) and in raw
 per-arrival records (burst windows are sliced from these, since a burst
 is a time window within one run, finer than a histogram window).
 """
@@ -99,8 +99,8 @@ class OpenLoopDriver:
     ``ops`` maps op-class name -> ``callable(arrival)``; an op raising
     ``AdmissionRejected`` records a shed, any other exception an error.
     ``slo_s`` (op -> seconds) marks traces over-SLO when ``trace_ops``
-    is on, so tail sampling keeps exactly the slow/shed evidence the
-    sweep's attribution step reads back."""
+    is on, so tail sampling keeps exactly the slow/shed evidence a
+    reader of the trace ring attributes tails from."""
 
     def __init__(self, ops: dict[str, Callable[[Arrival], None]],
                  max_workers: int = 32,
@@ -113,9 +113,8 @@ class OpenLoopDriver:
         self.slo_s = dict(slo_s or {})
         self.trace_ops = trace_ops
         self.drain_timeout = drain_timeout
-        # extra attrs stamped on every macro_op root span — the sweep
-        # tags each point so attribution can tell one run's traces from
-        # another's in the shared ring
+        # extra attrs stamped on every macro_op root span, so a reader
+        # can tell one run's traces from another's in the shared ring
         self.trace_attrs = dict(trace_attrs or {})
         self._hists = {
             op: metrics.histogram("loadgen_op_seconds", op=op)
@@ -194,7 +193,7 @@ class OpenLoopDriver:
                 # the report was finalized at the drain deadline: a
                 # straggler completing now must not observe into the
                 # NEXT run's histogram window or mutate a report the
-                # sweep is already reading
+                # caller is already reading
                 metrics.counter("loadgen_ops_total", op=a.op,
                                 outcome="abandoned").inc()
                 return

@@ -201,7 +201,7 @@ def build_schedule(cfg: ScheduleConfig) -> list[Arrival]:
 
 def burst_windows(cfg: ScheduleConfig) -> list[tuple[str, float, float]]:
     """[(name, start, end)] of the config's burst phases, clamped to the
-    schedule span — the sweep uses these to window burst-tail stats."""
+    schedule span (what windows a burst's outcomes in a report)."""
     return [(b.name, max(0.0, min(b.start, cfg.duration)),
              max(0.0, min(b.start + b.duration, cfg.duration)))
             for b in cfg.bursts]

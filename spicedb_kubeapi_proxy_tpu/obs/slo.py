@@ -7,8 +7,7 @@ Badness has two sources, both read from the EXISTING instrumentation —
 no new hot-path hooks:
 
 - latency: the objective's histogram family (``utils/metrics.py``
-  windowed snapshots — the same machinery bench.py stage breakdowns
-  use), counting observations above the threshold;
+  windowed snapshots), counting observations above the threshold;
 - availability: optional counter families (shed / error totals) whose
   window delta is added to the bad count AND the event total — a shed
   request never completed, so it can't hide in the latency histogram.
@@ -18,12 +17,11 @@ bounded ring, and computes, per objective and per window (default
 1m/5m/1h), the **burn rate**: ``bad_fraction / (1 - target)``. Burn 1.0
 means the error budget is being spent exactly at the rate that exhausts
 it by the end of the SLO period; >1 burns faster (the standard
-multi-window multi-burn alerting input). Exposed three ways:
+multi-window multi-burn alerting input). Exposed two ways:
 
 - ``slo_burn_rate{objective=..,window=..}`` / ``slo_attainment{..}``
   gauges in the shared registry (scraped at ``/metrics``),
-- :meth:`SLOMonitor.status` — the JSON document ``/debug/slo`` serves,
-- the bench macro phase, which reports end-of-sweep attainment per class.
+- :meth:`SLOMonitor.status` — the JSON document ``/debug/slo`` serves.
 
 Latency goodness is bucket-resolution: "good" counts observations in
 buckets whose upper bound is <= the threshold (+epsilon so a threshold
@@ -124,8 +122,7 @@ class SLOMonitor:
     samples; a window's burn rate is the delta between the newest sample
     and the oldest one inside the window. Ticking is either driven by
     the owned daemon thread (:meth:`start`) or called directly
-    (:meth:`tick`) — tests and the bench sweep inject their own clock
-    and cadence."""
+    (:meth:`tick`) — tests inject their own clock and cadence."""
 
     def __init__(self, objectives, windows=DEFAULT_WINDOWS,
                  tick_seconds: float = 5.0, clock=time.monotonic,

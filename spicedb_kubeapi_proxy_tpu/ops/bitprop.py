@@ -45,8 +45,7 @@ MIN_SRC = 32
 # whose packed rows blow this even at the smallest tile fall back to the MXU
 # matmul, which XLA tiles itself — otherwise Mosaic fails AT RUNTIME on the
 # first big-block query.
-VMEM_BUDGET = int(os.environ.get("SDBKP_BITPROP_VMEM_BYTES",
-                                 12 * 1024 * 1024))
+VMEM_BUDGET = 12 * 1024 * 1024
 
 
 def _k_pad(n_src: int) -> int:

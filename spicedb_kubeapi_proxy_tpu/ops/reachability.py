@@ -503,7 +503,7 @@ class RunMeta:
     cav_rows: int = 1
     # semiring propagation-mode policy baked into the trace: "auto" =
     # per-iteration lax.cond on traced occupancy; "push"/"pull" force one
-    # branch (ops/semiring.py force_mode / SDBKP_SEMIRING_MODE)
+    # branch (ops/semiring.py force_mode)
     spmm_mode: str = "auto"
     # one-shot phases before the loop (0 = none: no cycle, or nothing
     # feeds it), and per phase -n_pre..-1 the slot ranges it finalizes;
@@ -929,7 +929,7 @@ class CompiledGraph:
         d["blocks_bits"] = tuple(bits_dev)
         # kernel/mode toggles are baked into traces, so they are part of
         # the shared-function cache key; query_async keeps a per-mode
-        # entry so a force_mode() flip (bench baseline knob) cannot
+        # entry so a force_mode() flip (the differential tests) cannot
         # dispatch through a stale trace
         d[("run", semiring.resolved_mode())] = _jit_run_for(self)
         return d

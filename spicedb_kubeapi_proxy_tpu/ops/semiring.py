@@ -48,7 +48,6 @@ into dense blocks), so mode switching would only add latency there.
 
 from __future__ import annotations
 
-import os
 from contextlib import contextmanager
 from typing import Optional
 
@@ -58,26 +57,23 @@ import jax.numpy as jnp
 from . import bitprop
 
 # resolved_mode() values: "auto" = per-iteration lax.cond on occupancy;
-# "push"/"pull" force one branch (the bench's same-revision baseline knob)
+# "push"/"pull" force one branch (the differential tests' reference)
 _MODES = ("auto", "push", "pull")
 _FORCED: Optional[str] = None
 
 
 def resolved_mode() -> str:
     """The propagation-mode policy baked into the next trace: a
-    force_mode() override wins, then ``SDBKP_SEMIRING_MODE`` (auto /
-    push / pull), else auto. Part of the jit-cache key
+    force_mode() override, else auto. Part of the jit-cache key
     (reachability._jit_run_for), so flipping it never reuses a stale
     trace."""
-    if _FORCED is not None:
-        return _FORCED
-    mode = os.environ.get("SDBKP_SEMIRING_MODE", "auto")
-    return mode if mode in _MODES else "auto"
+    return _FORCED if _FORCED is not None else "auto"
 
 
 @contextmanager
 def force_mode(mode: str):
-    """Force push/pull/auto for the duration (bench baseline + tests)."""
+    """Force push/pull/auto for the duration (chip_smoke.py and the
+    push / pull / auto differential tests)."""
     global _FORCED
     if mode not in _MODES:
         raise ValueError(f"unknown semiring mode {mode!r}")

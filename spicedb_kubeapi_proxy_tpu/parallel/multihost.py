@@ -441,9 +441,8 @@ class MirroredEngine:
                  blob: Optional[bytes] = None) -> Optional[int]:
         """Serialize the action ONCE into wire bytes and fan the same
         bytes object out to every subscriber queue — at N followers the
-        leader must not pay N JSON encodes per device dispatch (measured
-        -33%/-52% leader throughput at 1/3 followers before this;
-        bench_results/multihost_r5_cpu.json). ``blob`` rides a binary
+        leader must not pay N JSON encodes per device dispatch (not
+        measured on the chip). ``blob`` rides a binary
         frame (meta + payload) for the hot check_bulk item batches.
         Returns the frame's sequence number, or None when nobody was
         subscribed (nothing to wait replicated on)."""
@@ -705,10 +704,8 @@ def encode_check_items(items) -> bytes:
     so client-controlled ids round-trip exactly and "" stays distinct
     from None; both matter — the engine groups device dispatches by
     subject key, so a lossy codec would desync SPMD dispatch shapes)
-    and ~16% smaller than the old nested list-of-lists frame. A
-    hand-rolled length-prefixed binary codec was measured SLOWER than
-    this (pure-Python per-field loops cost more than the bytes saved);
-    numbers in bench_results/multihost_r5_cpu.json."""
+    and smaller than a nested list-of-lists frame (6N fields against N
+    six-element arrays). Speed: not measured on the chip."""
     import json as _json
 
     flat = []

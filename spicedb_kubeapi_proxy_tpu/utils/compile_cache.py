@@ -6,7 +6,7 @@ again. JAX's persistent cache keeps them on disk; its directory is part
 of the cache key, so it must not move between runs. Library code never
 calls this: a process has one cache, chosen by whoever starts it
 (``proxy/cli.py``, the engine host's ``main``, ``proxy/demo.py``,
-``bench.py``, ``chip_smoke.py``).
+``chip_smoke.py``, ``benchmark/run.py``).
 """
 
 from __future__ import annotations

@@ -103,8 +103,7 @@ class Histogram:
 
     def snapshot(self) -> dict:
         """A consistent copy of the histogram state, for delta-quantile
-        computation across a measurement window (bench.py per-phase stage
-        breakdowns)."""
+        computation across a measurement window."""
         with self._lock:
             return {"buckets": self.buckets, "counts": list(self.counts),
                     "n": self.n, "total": self.total, "max": self.max}
@@ -235,8 +234,8 @@ def snapshot_delta_quantile(before: Optional[dict], after: Optional[dict],
                             q: float) -> Optional[float]:
     """Approximate quantile (upper bucket bound) of the observations that
     landed BETWEEN two :meth:`Histogram.snapshot`/:meth:`Registry.
-    hist_snapshot` calls — how bench.py attributes a phase's stage
-    latency without resetting shared histograms. ``None`` when the window
+    hist_snapshot` calls — how a reader windows a stage's latency
+    without resetting shared histograms. ``None`` when the window
     saw no observations; the overflow bucket clamps to the window's
     largest observed value (``after``'s max — an upper bound when earlier
     phases observed larger, never infinity)."""

@@ -1,8 +1,8 @@
 """Test configuration: force JAX onto a virtual 8-device CPU platform.
 
-Real-TPU runs happen via chip_smoke.py / bench.py; unit tests exercise
-the same jitted code paths on CPU, including multi-device sharding over a
-virtual 8-device mesh (SURVEY.md env notes).
+Real-TPU runs happen via chip_smoke.py / benchmark/run.py; unit tests
+exercise the same jitted code paths on CPU, including multi-device
+sharding over a virtual 8-device mesh (SURVEY.md env notes).
 """
 
 import os

@@ -1,7 +1,7 @@
 """Open-loop macrobench + live SLO layer tests (ISSUE 7).
 
-Covers the loadgen subsystem (schedule determinism, the open-loop pin,
-sweep/knee math), the SLO monitor (objective parsing, burn rates, the
+Covers the loadgen subsystem (schedule determinism, the open-loop pin),
+the SLO monitor (objective parsing, burn rates, the
 ``slo_*`` metric family, ``/debug/slo``), the previously-unexercised
 authz surface the macrobench drives (LookupSubjects, wildcard relations
 through the proxy filter path, Table filtering at >=1k rows), and the
@@ -22,8 +22,6 @@ from spicedb_kubeapi_proxy_tpu.loadgen import (
     OpenLoopDriver,
     ScheduleConfig,
     build_schedule,
-    knee_estimate,
-    run_sweep,
     trace_shaped_config,
 )
 from spicedb_kubeapi_proxy_tpu.loadgen.driver import (
@@ -38,7 +36,6 @@ from spicedb_kubeapi_proxy_tpu.loadgen.schedule import (
     OP_WATCH_OPEN,
     burst_windows,
 )
-from spicedb_kubeapi_proxy_tpu.loadgen.sweep import SweepPoint
 from spicedb_kubeapi_proxy_tpu.models import parse_schema
 from spicedb_kubeapi_proxy_tpu.obs.audit import AuditLog
 from spicedb_kubeapi_proxy_tpu.obs.slo import (
@@ -220,103 +217,6 @@ def test_driver_outcome_accounting_and_exec_split():
     assert rep.error_samples and "boom" in rep.error_samples[0]
     for r in rep.records:
         assert r.latency_s >= r.exec_s >= 0.0
-
-
-# -- sweep / knee -------------------------------------------------------------
-
-
-def _point(offered, good_frac):
-    rep = DriverReport(duration_s=1.0)
-    p = SweepPoint(multiplier=offered / 100.0, offered_rps=offered,
-                   fired_n=int(offered), completed_n=int(offered),
-                   good_n=int(offered * good_frac), shed_n=0, error_n=0,
-                   late_n=0, report=rep)
-    return p
-
-
-def test_knee_estimate_interpolates_crossing():
-    pts = [_point(100, 0.99), _point(200, 0.95), _point(400, 0.45)]
-    knee, saturated = knee_estimate(pts)
-    assert saturated
-    assert 200 < knee < 400
-    # the crossing of 0.85 between (200, .95) and (400, .45) is at 240
-    assert knee == pytest.approx(240.0, rel=0.01)
-
-
-def test_knee_estimate_never_reached_is_lower_bound():
-    pts = [_point(100, 0.99), _point(200, 0.97)]
-    knee, saturated = knee_estimate(pts)
-    assert not saturated
-    assert knee == 200.0
-
-
-def test_run_sweep_curve_and_burst_schema():
-    """A tiny two-point sweep over a fast vs saturating op mix yields
-    the full result schema: curve, knee, per-class p50/p99/p99.9,
-    burst windows with p99.9, SLO attainment."""
-    def fast(a):
-        pass
-
-    def watch(a):
-        time.sleep(0.001)
-
-    def make_config(m):
-        return trace_shaped_config(0.8, 120.0 * m, tenants=3, seed=11,
-                                   burst_multiplier=3.0)
-
-    slo_s = {OP_CHECK: 0.05, OP_WATCH_OPEN: 0.05, OP_LIST_PREFILTER: 0.05}
-    ops = {OP_CHECK: fast, OP_LIST_PREFILTER: fast, OP_WATCH_OPEN: watch}
-
-    # restrict the mix to the ops this harness implements
-    def cfg_for(m):
-        cfg = make_config(m)
-        cfg.mix = {OP_CHECK: 0.6, OP_LIST_PREFILTER: 0.3,
-                   OP_WATCH_OPEN: 0.1}
-        for b in cfg.bursts:
-            if b.mix is not None:
-                b.mix.clear()
-                b.mix.update(cfg.mix)
-        return cfg
-
-    res = run_sweep(cfg_for, ops, (0.5, 1.0), slo_s, max_workers=8,
-                    trace_ops=False, drain_timeout=5.0)
-    d = res.to_dict()
-    assert len(d["curve"]) == 2
-    for pt in d["curve"]:
-        assert {"multiplier", "offered_rps", "completed_rps",
-                "goodput_rps", "shed", "errors", "late",
-                "classes"} <= set(pt)
-    assert d["knee_rps"] is not None
-    # per-class quantiles carry the p99.9 key
-    top = d["curve"][-1]["classes"]
-    assert top and all("p999_ms" in q for q in top.values())
-    # burst windows from the top point, each class with exact p99.9
-    assert set(d["bursts"]) == {"watch-storm", "get-wave", "reconcile"}
-    for b in d["bursts"].values():
-        assert {"n", "shed", "errors", "window_epoch", "window_rel",
-                "classes"} <= set(b)
-        for st in b["classes"].values():
-            assert {"n", "p50_ms", "p99_ms", "p999_ms"} <= set(st)
-    assert set(d["slo_attainment"]) == set(ops)
-    for v in d["slo_attainment"].values():
-        assert v is None or 0.0 <= v <= 1.0
-
-
-def test_worst_burst_prefers_fully_shed_window():
-    from spicedb_kubeapi_proxy_tpu.loadgen.sweep import _worst_burst
-
-    bursts = {
-        "mild": {"n": 50, "shed": 0, "errors": 0,
-                 "classes": {"check": {"n": 50, "p50_ms": 1.0,
-                                       "p99_ms": 5.0, "p999_ms": 9.0}}},
-        "starved": {"n": 40, "shed": 40, "errors": 0, "classes": {}},
-    }
-    # a window the server shed ENTIRELY is the worst case even though
-    # it has no completed-op percentiles to rank by
-    assert _worst_burst(bursts) == "starved"
-    bursts["starved"]["shed"] = 0
-    bursts["starved"]["n"] = 0  # no arrivals at all: not starved
-    assert _worst_burst(bursts) == "mild"
 
 
 # -- metrics satellites -------------------------------------------------------

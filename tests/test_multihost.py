@@ -138,16 +138,34 @@ if role == "leader":
 else:
     argv += ["--mirror-leader", f"127.0.0.1:{port_tcp}",
              "--bind-port", "0"]
+    # the follower says what it has applied, so the test can wait for it
+    # (a follower serves no port of its own to ask)
+    from spicedb_kubeapi_proxy_tpu.parallel import multihost as _mh
+    _apply = _mh.apply_mirror_frame
+
+    def _reporting(engine, frame, blob=None):
+        try:
+            _apply(engine, frame, blob)
+        finally:
+            print(f"APPLIED method={frame['method']} "
+                  f"revision={engine.revision}", flush=True)
+
+    _mh.apply_mirror_frame = _reporting
     print("FOLLOWER STARTING", flush=True)
 sys.exit(main(argv))
 """
 
 
-def test_multihost_serving_leader_follower():
+def test_multihost_serving_leader_follower(tmp_path):
     """Full multi-host SERVING: the engine-host CLI as leader (process 0,
     serving TCP, MirroredEngine) + follower (process 1, replaying the
     mirror stream); a real client drives writes, bulk checks, and mask
-    lookups whose collectives span both processes."""
+    lookups whose collectives span both processes. Every query that
+    spans both is sent once the follower has applied the writes before
+    it (its own report, not a sleep): the leader then never sits in a
+    collective while a busy host is still replaying the follower's
+    writes."""
+    import threading
     import time
 
     from spicedb_kubeapi_proxy_tpu.engine import CheckItem, Engine, WriteOp
@@ -155,21 +173,49 @@ def test_multihost_serving_leader_follower():
     from spicedb_kubeapi_proxy_tpu.models.tuples import parse_relationship
 
     repo_root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    script = os.path.join(repo_root, ".pytest-mh-serve-worker.py")
-    with open(script, "w") as f:
-        f.write(SERVE_WORKER)
+    script = tmp_path / "mh_serve_worker.py"
+    script.write_text(SERVE_WORKER)
     port_coord, port_tcp = _free_port(), _free_port()
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)
-    procs = []
+    procs, readers, outs = [], [], [[], []]
+    cond = threading.Condition()
+    applied = {"writes": 0, "revision": 0}
+
+    def read(p, lines):
+        for line in p.stdout:
+            with cond:
+                lines.append(line)
+                if line.startswith("APPLIED "):
+                    f = dict(kv.split("=", 1) for kv in line.split()[1:])
+                    applied["revision"] = int(f["revision"])
+                    applied["writes"] += f["method"] == "write_relationships"
+                cond.notify_all()
+
+    def follower_applied(writes):
+        """Block until the follower has replayed ``writes`` write frames
+        and stands at the leader's revision."""
+        want = client.revision
+        with cond:
+            cond.wait_for(
+                lambda: (applied["writes"] >= writes
+                         and applied["revision"] >= want)
+                or procs[1].poll() is not None, timeout=300)
+            assert applied["writes"] >= writes \
+                and applied["revision"] == want, \
+                (applied, want, "".join(outs[1])[-2000:])
+
     client = None
     try:
-        for role in ("leader", "follower"):
+        for i, role in enumerate(("leader", "follower")):
             procs.append(subprocess.Popen(
-                [sys.executable, script, role, str(port_coord),
+                [sys.executable, str(script), role, str(port_coord),
                  str(port_tcp), repo_root],
                 stdout=subprocess.PIPE, stderr=subprocess.STDOUT,
                 text=True, env=env, cwd=repo_root))
+            readers.append(threading.Thread(
+                target=read, args=(procs[i], outs[i]), daemon=True))
+            readers[i].start()
         # wait for the leader's TCP port to accept
         deadline = time.monotonic() + 120
         while True:
@@ -179,9 +225,8 @@ def test_multihost_serving_leader_follower():
                 probe.close()
                 break
             except OSError:
-                for p in procs:
-                    assert p.poll() is None, \
-                        p.communicate()[0][-2000:]
+                for i, p in enumerate(procs):
+                    assert p.poll() is None, "".join(outs[i])[-2000:]
                 assert time.monotonic() < deadline, "leader never bound"
                 time.sleep(0.25)
         client = RemoteEngine("127.0.0.1", port_tcp, token="mh-tok")
@@ -194,6 +239,7 @@ def test_multihost_serving_leader_follower():
             [WriteOp("touch", parse_relationship(r)) for r in rels])
         items = [CheckItem("namespace", f"n{i}", "view", "user",
                            f"u{i % 5}") for i in range(25)]
+        follower_applied(writes=1)
         assert client.check_bulk(items) == ref.check_bulk(items)
         assert sorted(client.lookup_resources(
             "namespace", "view", "user", "u3")) == \
@@ -202,6 +248,7 @@ def test_multihost_serving_leader_follower():
         for eng in (client, ref):
             eng.write_relationships([WriteOp("touch", parse_relationship(
                 "namespace:n1#viewer@user:u6"))])
+        follower_applied(writes=2)
         assert client.check_bulk(
             [CheckItem("namespace", "n1", "view", "user", "u6")]) == [True]
         # a DETERMINISTICALLY-FAILING write (bad precondition) must fail
@@ -222,7 +269,9 @@ def test_multihost_serving_leader_follower():
             raise AssertionError("precondition should have failed")
         except PreconditionFailed:
             pass
-        # the set is still alive and consistent after the failure
+        # the follower replayed the failing frame too and moved no
+        # further than the leader: the set is still alive and consistent
+        follower_applied(writes=3)
         assert client.check_bulk(
             [CheckItem("namespace", "n1", "view", "user", "u6")]) == [True]
     finally:
@@ -231,15 +280,15 @@ def test_multihost_serving_leader_follower():
         for p in procs:
             p.terminate()
         deadline = time.monotonic() + 20
-        outs = []
         for p in procs:
             try:
                 p.wait(timeout=max(0.1, deadline - time.monotonic()))
             except subprocess.TimeoutExpired:
                 p.kill()
-            outs.append(p.communicate()[0])
-        os.unlink(script)
-    for role, out in zip(("leader", "follower"), outs):
+        for r in readers:
+            r.join(10)
+    for role, lines in zip(("leader", "follower"), outs):
+        out = "".join(lines)
         assert "STARTING" in out, (role, out[-1500:])
         assert "Traceback" not in out, (role, out[-2500:])
 

@@ -137,11 +137,7 @@ def test_crossover_from_occupancy_mapping():
     assert semiring.crossover_from_occupancy(1.0) == 0.05
 
 
-def test_force_mode_and_env(monkeypatch):
-    assert semiring.resolved_mode() == "auto"
-    monkeypatch.setenv("SDBKP_SEMIRING_MODE", "pull")
-    assert semiring.resolved_mode() == "pull"
-    monkeypatch.setenv("SDBKP_SEMIRING_MODE", "bogus")
+def test_force_mode():
     assert semiring.resolved_mode() == "auto"
     with semiring.force_mode("push"):
         assert semiring.resolved_mode() == "push"

@@ -585,6 +585,12 @@ class Engine:
         metrics.gauge("engine_core_ranges").set(cg.core_ranges())
         metrics.gauge("engine_feeder_edges").set(cg.feeder_edges())
         metrics.gauge("engine_feeder_ranges").set(cg.feeder_ranges())
+        # of the residual: the edges out of a subject's own range, which
+        # a dispatch finds from its seeds, the padded rest that every
+        # dispatch walks, and how many entries a seed looks up
+        metrics.gauge("engine_seed_edges").set(cg.seed_edges())
+        metrics.gauge("engine_walked_edges").set(cg.walked_edges())
+        metrics.gauge("engine_seed_fanout").set(cg.seed_fanout())
         metrics.gauge("engine_delta_occupancy").set(cg.n_delta)
         if cg.tier is not None:
             cg.tier.publish_gauges()

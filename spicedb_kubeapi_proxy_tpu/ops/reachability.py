@@ -473,6 +473,57 @@ def _stratify(offs: np.ndarray, src_rid: np.ndarray, dst_rid: np.ndarray,
 
 
 @dataclass(frozen=True)
+class SeedLayout:
+    """The *seeded* parts of the residual: within each phase's slice the
+    edges that start in a subject's own ``__self`` range lie behind the
+    walked part, sorted by (source, destination), and a row-pointer
+    table (``CompiledGraph.res_ptr``) finds a source's run there. A
+    ``__self`` range is written by nothing but the seeding, so a dispatch
+    reads the runs of its 2 * B seed slots (``semiring.propagate_seeded``)
+    where a walk of the slice would multiply every other edge by zero.
+    One entry per phase, in ``res_level_bounds``' order; all static."""
+
+    # where the phase's seeded part starts (its slice's end: it has none)
+    starts: tuple
+    # D_k, a power-of-two bucket of the longest run the table serves in
+    # the phase (0 = no seeded part). Chosen by cost from the phase's
+    # out-degrees (``_seed_fanout``): a source with a longer run (a
+    # ``user:*`` grant, an account bound everywhere) stays walked
+    fanout: tuple
+    # ((slot offset, size, row-pointer base), ...): the ``__self`` ranges
+    # with runs in the phase, each with a window of size + 1 pointers.
+    # Slot ``s`` of one owns the run ``ptr[i] .. ptr[i + 1]``, ``i = base
+    # + s - offset``, counted from the seeded part's start
+    ranges: tuple
+
+    def lengths(self, bounds: tuple) -> list:
+        """Padded length of each phase's seeded part, given
+        ``res_level_bounds``."""
+        return [hi - mid for mid, hi in zip(self.starts, bounds[1:])]
+
+
+def seed_looked(rows: int, fanout: int, length: int) -> bool:
+    """Whether a dispatch of ``rows`` rows reads a seeded part of
+    ``length`` padded edges through the table: only where its
+    ``2 * rows * fanout`` entries are fewer than the part itself. A bulk
+    check of thousands of subject rows walks the part as ordinary
+    edges."""
+    return 0 < 2 * rows * fanout < length
+
+
+def _seed_fanout(degrees: np.ndarray) -> int:
+    """D for one phase, from the out-degrees of its ``__self`` sources:
+    the power-of-two bucket that costs a one-row dispatch least, counting
+    the edges a cap D leaves walked (sources with longer runs) against
+    the 2 * D entries it looks up. One long run among short ones never
+    pays for itself (2 * D > its edges), so it stays walked and D is
+    what the rest need."""
+    top = _next_bucket(int(degrees.max()))
+    return min((top >> i for i in reversed(range(top.bit_length() - 3))),
+               key=lambda d: int(degrees[degrees > d].sum()) + 2 * d)
+
+
+@dataclass(frozen=True)
 class RunMeta:
     """What the traced fixpoint reads from the graph: slot count,
     permission programs, dense-block offsets, the schedule (see
@@ -510,11 +561,28 @@ class RunMeta:
     # the entry phase (last) merges into the core's ranges
     n_pre: int = 0
     pre_ranges: tuple = ()
+    # the seeded parts of the slices (None: every edge is walked)
+    seed: Optional[SeedLayout] = None
 
     def level_slice(self, k: int) -> tuple[int, int]:
-        """Bounds of phase k's residual slice."""
+        """Bounds of phase k's residual slice, both parts."""
         i = k + self.n_pre
         return self.res_level_bounds[i], self.res_level_bounds[i + 1]
+
+    def parts(self, k: int) -> tuple[int, int, int]:
+        """``(lo, mid, hi)`` of phase k's slice: the walked part
+        ``lo:mid`` (dst-sorted), the seeded part ``mid:hi`` (see
+        SeedLayout)."""
+        lo, hi = self.level_slice(k)
+        return (lo, hi if self.seed is None
+                else self.seed.starts[k + self.n_pre], hi)
+
+    def seed_looked(self, k: int, rows: int) -> bool:
+        """Whether a dispatch of ``rows`` rows reads phase k's seeded
+        part through the table (else it walks it)."""
+        _, mid, hi = self.parts(k)
+        return self.seed is not None and seed_looked(
+            rows, self.seed.fanout[k + self.n_pre], hi - mid)
 
     def scope(self, k: int) -> str:
         """Phase k's named scope in the device trace."""
@@ -600,8 +668,10 @@ class CompiledGraph:
     dead_buf: Optional[np.ndarray] = None  # int64 [cap, 2] append buffer
     host_lock: Optional[object] = None  # guards shared host-array reads
     block_codes: Optional[dict] = None  # id(_BlockMeta) -> sorted codes
-    # host residual views (padded; ordered by (level, dst) — see
-    # _stratify/res_level_bounds) for device upload + incremental search
+    # host residual views (padded; one slice per phase — see
+    # _stratify/res_level_bounds — each a walked part ordered by dst and
+    # a seeded part ordered by (src, dst), see SeedLayout) for device
+    # upload + incremental search
     res_src: Optional[np.ndarray] = None
     res_dst: Optional[np.ndarray] = None
     res_exp: Optional[np.ndarray] = None
@@ -620,6 +690,12 @@ class CompiledGraph:
     n_levels: int = 0
     n_pre: int = 0
     range_levels: Optional[np.ndarray] = None
+    # the seeded parts of the residual slices and their row pointers
+    # (int32, every window end to end; see SeedLayout). None on
+    # hand-built graphs: every edge is walked
+    seed: Optional[SeedLayout] = None
+    res_ptr: Optional[np.ndarray] = None
+    n_seed_edges: int = 0  # real (unpadded) edges the seeded parts hold
     # compile-time lookup tables reused by the incremental path
     range_offs: Optional[np.ndarray] = None  # ascending slot-range offsets
     block_index: dict = field(default_factory=dict)  # (dst_off,src_off)->i
@@ -747,6 +823,9 @@ class CompiledGraph:
             self.res_level_bounds if self.res_level_bounds is not None
             else ("unstratified", len(self.res_src)
                   if self.res_src is not None else len(self.src)),
+            # where each slice's seeded part starts, its fan-out and its
+            # row-pointer windows: all baked into the trace
+            self._seed_layout(),
             None if self.range_levels is None
             else tuple(self.range_levels.tolist()),
             # the per-level merge windows (RunMeta.level_ranges) derive
@@ -779,6 +858,11 @@ class CompiledGraph:
             return tuple(self.res_level_bounds)
         return (0, len(self.res_src) if self.res_src is not None
                 else len(self.src))
+
+    def _seed_layout(self) -> Optional[SeedLayout]:
+        """``seed``, which describes parts of ``res_level_bounds``'
+        slices: a graph stripped of those has none."""
+        return self.seed if self.res_level_bounds is not None else None
 
     def run_meta(self, active: Optional[tuple] = None) -> "RunMeta":
         """Slim static-metadata view for jit closures: everything the
@@ -829,6 +913,7 @@ class CompiledGraph:
             spmm_mode=semiring.resolved_mode(),
             n_pre=self.n_pre,
             pre_ranges=pre_ranges,
+            seed=self._seed_layout(),
         )
 
     def _dev(self):
@@ -875,6 +960,7 @@ class CompiledGraph:
         d["dst"] = jnp.asarray(res_dst)
         d["exp"] = jnp.asarray(res_exp)
         d["cav"] = jnp.asarray(res_cav)
+        d["ptr"] = jnp.asarray(self._res_ptr())
         d["dsrc"], d["ddst"], d["dexp"], d["dcav"] = (
             jnp.asarray(a) for a in self._delta_host())
         # caveat VM instance tables (tapes + per-tuple context columns);
@@ -944,6 +1030,13 @@ class CompiledGraph:
         m = ((t >= bm.dst_off) & (t < bm.dst_off + bm.n_dst)
              & (s >= bm.src_off) & (s < bm.src_off + bm.n_src))
         return t[m] - bm.dst_off, s[m] - bm.src_off
+
+    def _res_ptr(self) -> np.ndarray:
+        """The seeded parts' row pointers (one zero where the graph has
+        no seeded part: the trace then never reads it)."""
+        if self.res_ptr is None or self._seed_layout() is None:
+            return np.zeros(1, dtype=np.int32)
+        return self.res_ptr
 
     def _delta_host(self) -> tuple[np.ndarray, np.ndarray, np.ndarray,
                                    np.ndarray]:
@@ -1227,6 +1320,11 @@ class CompiledGraph:
             if run is None:
                 run = _jit_run_for(self, active)
                 d[rk] = run
+        seed_mode = self.seed_mode(B_pad)
+        if seed_mode == "lookup":
+            metrics.counter("engine_seed_lookups_total").inc()
+        elif seed_mode == "walk":
+            metrics.counter("engine_seed_walks_total").inc()
         # the enqueue alone: the call returns once the program is handed
         # to the device's queue, not when it has run
         with tracer.stage("engine_enqueue",
@@ -1237,7 +1335,8 @@ class CompiledGraph:
             # round trip
             out, converged, iters, n_push, cav_missing = run(
                 blocks_arg, bits_arg, d["src"], d["dst"], d["exp"],
-                d["cav"], d["dsrc"], d["ddst"], d["dexp"], d["dcav"],
+                d["cav"], d["ptr"],
+                d["dsrc"], d["ddst"], d["dexp"], d["dcav"],
                 d["cav_static"], cav_req,
                 seeds, qs_dev, qb_dev,
                 now_rel, np.float32(self.spmm_crossover),
@@ -1285,9 +1384,9 @@ class CompiledGraph:
                 else int(np.count_nonzero(self.range_levels == 0)))
 
     def feeder_edges(self) -> int:
-        """What is walked once before the loop: the padded residual
-        edges and dense-block cells of the feeder levels and of the
-        entry phase."""
+        """What the phases before the loop hold: the padded residual
+        edges (walked and seeded parts) and dense-block cells of the
+        feeder levels and of the entry phase."""
         bounds = self._level_bounds()
         return int(bounds[self.n_pre] - bounds[0] + sum(
             b.n_dst * b.n_src for b in self.blocks if b.level < 0))
@@ -1297,6 +1396,38 @@ class CompiledGraph:
         cycle and lies on none, roots included."""
         return (0 if self.range_levels is None
                 else int(np.count_nonzero(self.range_levels < 0)))
+
+    def seed_edges(self) -> int:
+        """Edges the seeded parts hold, unpadded: found from a
+        dispatch's seeds, walked only by a dispatch of too many rows."""
+        return self.n_seed_edges if self._seed_layout() is not None else 0
+
+    def walked_edges(self) -> int:
+        """Padded residual edges of the walked parts: what a dispatch
+        gathers and scatters whatever its seeds (the loop's part once a
+        trip)."""
+        bounds, seed = self._level_bounds(), self._seed_layout()
+        return int(bounds[-1] - bounds[0] - (
+            0 if seed is None else sum(seed.lengths(bounds))))
+
+    def seed_fanout(self) -> int:
+        """Sum of the phases' D_k: a row's seed looks up that many
+        entries a dispatch, twice (subject and wildcard)."""
+        seed = self._seed_layout()
+        return 0 if seed is None else int(sum(seed.fanout))
+
+    def seed_mode(self, rows: int) -> Optional[str]:
+        """How a dispatch of ``rows`` rows applies the seeded parts:
+        ``"lookup"`` where some phase reads its part through the table,
+        ``"walk"`` where every one is walked as ordinary edges, None
+        where the graph has no seeded part."""
+        seed = self._seed_layout()
+        if seed is None or not any(seed.fanout):
+            return None
+        return "lookup" if any(
+            seed_looked(rows, d, n) for d, n in zip(
+                seed.fanout, seed.lengths(self.res_level_bounds))
+        ) else "walk"
 
     def hop_bytes(self, batch: int = 1) -> dict:
         """Estimated HBM traffic (bytes) for roofline reporting, split by
@@ -1363,7 +1494,10 @@ class CompiledGraph:
             tail_res = 0
         else:
             n_core = bounds[self.n_pre + 1] - bounds[self.n_pre]
-            tail_res = bounds[-1] - bounds[0] - n_core
+            # the seeded parts are read from the seeds, 2 * batch runs
+            # of at most the phase's fan-out, not walked
+            tail_res = self.walked_edges() - n_core \
+                + 2 * batch * self.seed_fanout()
         delta = self._delta_pad() * (4 + 4 + 1 + batch)
         core_res = res_bytes(n_core) + delta
         core_blk = [b for b in self.blocks if b.level == 0]
@@ -1619,7 +1753,21 @@ def apply_level_once(meta: "RunMeta", prop_level, V, baseflat, k: int):
                               meta.programs_at(k)), is_push
 
 
-def _run(cg: "RunMeta", blocks, blocks_bits, src, dst, exp_rel, cav,
+def _seed_runs(cg: "RunMeta", k: int, seeds, ptr):
+    """``(start, length)`` [B, 2] of the runs that a dispatch's seed
+    slots own in phase k's seeded part (SeedLayout): length 0 for a slot
+    in no ``__self`` range with runs there (a userset subject, the trash
+    slot of an unknown one) or with no edge in the phase."""
+    at = jnp.zeros_like(seeds)
+    owns = jnp.zeros(seeds.shape, dtype=jnp.bool_)
+    for off, size, base in cg.seed.ranges[k + cg.n_pre]:
+        inside = (seeds >= off) & (seeds < off + size)
+        at = jnp.where(inside, seeds - off + base, at)
+        owns = owns | inside
+    return ptr[at], jnp.where(owns, ptr[at + 1] - ptr[at], 0)
+
+
+def _run(cg: "RunMeta", blocks, blocks_bits, src, dst, exp_rel, cav, ptr,
          dsrc, ddst, dexp, dcav, cav_static, cav_req,
          seeds, q_slots, q_batch, now_rel, crossover, *,
          max_iters: int, q_contig_len: int = 0, q_contig_rows: int = 1):
@@ -1649,7 +1797,13 @@ def _run(cg: "RunMeta", blocks, blocks_bits, src, dst, exp_rel, cav,
 
     Every hop is ONE call into the masked-semiring primitive
     (ops/semiring.propagate) — the same primitive the shard_map body
-    uses — with the ``(exp > now) ∧ cav_ok[row]`` edge-activation mask
+    uses — over the walked part of the phase's slice, and where the
+    slice has a seeded part (SeedLayout: edges out of a ``__self`` range)
+    one into ``semiring.propagate_seeded``, which reads the runs of this
+    dispatch's seed slots from ``ptr``; a dispatch of too many rows for
+    that (``seed_looked``, from the shapes the trace sees) walks the
+    seeded part with the overlay. Both read the ``(exp > now) ∧
+    cav_ok[row]`` edge-activation mask
     computed exactly once per dispatch (semiring.edge_activation) and
     fused into the multiply. The caveat VM evaluates every instance's
     tri-state once up front when the graph carries caveat instances
@@ -1679,12 +1833,27 @@ def _run(cg: "RunMeta", blocks, blocks_bits, src, dst, exp_rel, cav,
 
     def prop_level(V, k):
         Vflat = V.reshape(B, Mp)
-        lo, hi = cg.level_slice(k)
+        lo, mid, hi = cg.parts(k)
+        looked = cg.seed_looked(k, B)
+        unsorted = (dsrc, ddst, dact)
+        if mid < hi and not looked:
+            # too many rows for the table to be the shorter way: the
+            # seeded part (sorted by source) is walked with the overlay,
+            # the pass that expects no order
+            unsorted = tuple(
+                jnp.concatenate([a, b[mid:hi]])
+                for a, b in zip(unsorted, (src, dst, act)))
         occ = semiring.frontier_occupancy(Vflat)
-        return semiring.propagate(
-            cg.blocks, blocks, blocks_bits, src[lo:hi], dst[lo:hi],
-            act[lo:hi], dsrc, ddst, dact, Vflat, occ, crossover,
+        prop, is_push = semiring.propagate(
+            cg.blocks, blocks, blocks_bits, src[lo:mid], dst[lo:mid],
+            act[lo:mid], *unsorted, Vflat, occ, crossover,
             level=k, mode=cg.spmm_mode)
+        if looked:
+            start, length = _seed_runs(cg, k, seeds, ptr)
+            prop = semiring.propagate_seeded(
+                prop, start, length, dst[mid:hi], act[mid:hi],
+                cg.seed.fanout[k + cg.n_pre])
+        return prop, is_push
 
     # jax.named_scope names the phases for HLO dumps and xprof (op_name
     # metadata only: the computation is the same). No one-shot phase may
@@ -2152,39 +2321,66 @@ def compile_graph(schema: Schema, snapshot: Snapshot,
     res_idx = (np.sort(np.concatenate(res_parts)) if res_parts
                else np.empty(0, dtype=np.int64))
 
-    # padded host residual views ordered by (phase, dst) — the traced
-    # program slices the residual per phase, in order of execution
-    # (res_level_bounds: feeder levels, entry, core, levels), each slice
-    # dst-sorted for segment_max's indices_are_sorted and padded to its
-    # own power-of-two bucket so the bounds (part of the jit signature)
-    # stay stable as edge counts drift between recompiles
+    # padded host residual views, one slice per phase in order of
+    # execution (res_level_bounds: feeder levels, entry, core, levels).
+    # A slice is two parts, each padded to its own power-of-two bucket so
+    # the bounds (part of the jit signature) stay stable as edge counts
+    # drift between recompiles: the *walked* part, dst-sorted for
+    # segment_max's indices_are_sorted, then the *seeded* part
+    # (SeedLayout): the edges that start in a ``__self`` range, sorted by
+    # (src, dst) under a row pointer per source, but for the sources
+    # whose run is longer than the phase's fan-out, which stay walked
     n_res = len(res_idx)
     n_slices = n_pre + n_levels + 1
-    if n_res:
-        res_lvl = edge_level[res_idx] + n_pre
-        order = np.lexsort((dst[res_idx], res_lvl))
-        res_idx = res_idx[order]
-        res_lvl = res_lvl[order]
-        counts_per_level = np.bincount(res_lvl, minlength=n_slices)
-    else:
-        counts_per_level = np.zeros(n_slices, dtype=np.int64)
-    pads = [_next_bucket(max(int(c), 1)) for c in counts_per_level]
-    res_level_bounds = tuple(int(x) for x in np.concatenate(
-        [[0], np.cumsum(pads)]))
+    self_rid = np.asarray([r == SELF_REL for (_, r), _ in range_items])
+    walked: list = []  # per phase: indices into the edge arrays
+    seeded: list = []
+    fanout: list = []
+    res_phase = edge_level[res_idx] + n_pre if n_res else res_idx
+    for i in range(n_slices):
+        sel = res_idx[res_phase == i]
+        own = self_rid[src_rid[sel]]
+        d_k = 0
+        if own.any():
+            _, inv, runs = np.unique(src[sel[own]], return_inverse=True,
+                                     return_counts=True)
+            d_k = _seed_fanout(runs)
+            own[np.flatnonzero(own)[runs[inv] > d_k]] = False
+        w, t = sel[~own], sel[own]
+        walked.append(w[np.argsort(dst[w], kind="stable")])
+        seeded.append(t[np.lexsort((dst[t], src[t]))])
+        fanout.append(d_k if len(t) else 0)
+    bounds, seed_starts = [0], []
+    for w, t in zip(walked, seeded):
+        seed_starts.append(bounds[-1] + _next_bucket(max(len(w), 1)))
+        bounds.append(seed_starts[-1]
+                      + (_next_bucket(len(t)) if len(t) else 0))
+    res_level_bounds = tuple(bounds)
     res_src = np.full(res_level_bounds[-1], M, dtype=np.int32)
     res_dst = np.full(res_level_bounds[-1], M, dtype=np.int32)
     res_exp = np.full(res_level_bounds[-1], -np.inf, dtype=np.float32)
     res_cav = np.zeros(res_level_bounds[-1], dtype=np.int32)
-    pos = 0
-    for k in range(n_slices):
-        n_k = int(counts_per_level[k])
-        lo = res_level_bounds[k]
-        sel = res_idx[pos:pos + n_k]
-        res_src[lo:lo + n_k] = src_p[sel]
-        res_dst[lo:lo + n_k] = dst_p[sel]
-        res_exp[lo:lo + n_k] = exp_p[sel]
-        res_cav[lo:lo + n_k] = cav_p[sel]
-        pos += n_k
+    ptrs: list = []  # every window's row pointers, end to end
+    seed_ranges: list = []
+    n_ptr = 0
+    for lo, mid, w, t in zip(bounds, seed_starts, walked, seeded):
+        for at, sel in ((lo, w), (mid, t)):
+            res_src[at:at + len(sel)] = src_p[sel]
+            res_dst[at:at + len(sel)] = dst_p[sel]
+            res_exp[at:at + len(sel)] = exp_p[sel]
+            res_cav[at:at + len(sel)] = cav_p[sel]
+        # CSR by source, a window per ``__self`` range with a run here:
+        # slot ``off + j`` owns ptr[base + j] .. ptr[base + j + 1] of the
+        # part, where its sources first reach ``off + j`` and the next
+        wins = []
+        for rid in np.unique(src_rid[t]).tolist():
+            wins.append((int(offs[rid]), int(sizes[rid]), n_ptr))
+            ptrs.append(np.searchsorted(
+                src[t], offs[rid] + np.arange(sizes[rid] + 1)))
+            n_ptr += int(sizes[rid]) + 1
+        seed_ranges.append(tuple(wins))
+    res_ptr = (np.concatenate(ptrs) if ptrs
+               else np.zeros(1)).astype(np.int32)
 
     # fixed-capacity delta overlay: preallocated trash-padded segments the
     # incremental path appends into IN PLACE (watermarked by n_delta /
@@ -2231,6 +2427,10 @@ def compile_graph(schema: Schema, snapshot: Snapshot,
         n_levels=n_levels,
         n_pre=n_pre,
         range_levels=range_levels,
+        seed=SeedLayout(tuple(seed_starts), tuple(fanout),
+                        tuple(seed_ranges)),
+        res_ptr=res_ptr,
+        n_seed_edges=sum(len(t) for t in seeded),
         range_offs=offs,
         block_index={(b.dst_off, b.src_off): i
                      for i, b in enumerate(blocks)},
@@ -2323,22 +2523,24 @@ def _pair_block(cg: CompiledGraph, src: int, dst: int):
 
 
 def _res_positions(cg: CompiledGraph, src: int, dst: int) -> list[int]:
-    """Base-residual positions holding the (src, dst) edge. The residual
-    is ordered by (phase, dst), so each phase's slice is binary-searched
-    and its per-dst run scanned for the src match."""
+    """Base-residual positions holding the (src, dst) edge. A phase's
+    walked part is ordered by dst and its seeded part by (src, dst), so
+    each is binary-searched by its own key and the key's run scanned for
+    the other end."""
     bounds = cg.res_level_bounds or (0, len(cg.res_dst))
+    seed = cg._seed_layout()
     out: list[int] = []
     for k in range(len(bounds) - 1):
-        b0, b1 = bounds[k], bounds[k + 1]
-        if b0 == b1:
-            continue
-        lo = b0 + int(np.searchsorted(cg.res_dst[b0:b1], dst, side="left"))
-        hi = b0 + int(np.searchsorted(cg.res_dst[b0:b1], dst, side="right"))
-        if lo < hi:
-            out.extend(
-                (lo + np.flatnonzero(cg.res_src[lo:hi] == src)).tolist())
+        mid = bounds[k + 1] if seed is None else seed.starts[k]
+        for b0, b1, keys, key, ends, end in (
+                (bounds[k], mid, cg.res_dst, dst, cg.res_src, src),
+                (mid, bounds[k + 1], cg.res_src, src, cg.res_dst, dst)):
+            lo = b0 + int(np.searchsorted(keys[b0:b1], key, side="left"))
+            hi = b0 + int(np.searchsorted(keys[b0:b1], key, side="right"))
+            if lo < hi:
+                out.extend(
+                    (lo + np.flatnonzero(ends[lo:hi] == end)).tolist())
     return out
-
 
 
 

@@ -44,6 +44,10 @@ Residual (expiring / caveated / sparse) edges and the incremental delta
 overlay always ride the gather/segment-max path: their edge sets are
 small by construction (compile_graph routes everything big and static
 into dense blocks), so mode switching would only add latency there.
+Residual edges that start in a subject's own ``__self`` range are the
+exception: a dispatch sets at most two slots a row there, so
+:func:`propagate_seeded` reads those slots' runs instead of walking the
+slice (ops/reachability.compile_graph lays them out by source).
 """
 
 from __future__ import annotations
@@ -117,6 +121,26 @@ def frontier_occupancy(Vflat: jax.Array) -> jax.Array:
     mean of the uint8 0/1 state. Feeds the per-iteration push/pull
     ``lax.cond`` — a device-side scalar, never synced to the host."""
     return jnp.mean(Vflat.astype(jnp.float32))
+
+
+def propagate_seeded(prop, start, length, dst, act, fanout: int):
+    """The hop over edges that leave a dispatch's own seed slots, read
+    and not walked: ``prop[b, dst[i]] |= act[i]`` for every ``i`` of the
+    runs ``start[b, j] .. start[b, j] + length[b, j]`` (``[B, 2]``: row
+    b's subject and wildcard seed) of a slice sorted by source.
+
+    A ``__self`` range is written by nothing but the seeding, so of all
+    its slots a dispatch of B rows sets at most 2 * B, each to 1: the
+    product over the whole slice is these runs and nothing else.
+    ``fanout`` (static) bounds a run's length; ``act`` is the slice of
+    the one :func:`edge_activation` array the walk reads, so an expired,
+    caveated or killed edge is off here by the same rule."""
+    with jax.named_scope("seeded"):
+        j = jnp.arange(fanout, dtype=jnp.int32)
+        idx = jnp.minimum(start[..., None] + j, dst.shape[0] - 1)
+        on = jnp.where(j < length[..., None], act[idx], 0)  # [B, 2, D]
+        rows = jnp.arange(prop.shape[0], dtype=jnp.int32)[:, None, None]
+        return prop.at[rows, dst[idx]].max(on)
 
 
 def propagate(block_meta, blocks, blocks_bits, src, dst, act,

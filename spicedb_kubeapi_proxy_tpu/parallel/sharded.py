@@ -489,8 +489,11 @@ class ShardedGraph:
         """(level_arrays, kept_blocks): per phase of the schedule, in
         order of execution (feeder levels, entry, core, levels), the
         (src, dst, exp, cav) edge arrays this mesh gathers over (base
-        residual slice + folded-back blocks of that phase, dst-sorted,
-        padded to the graph axis) and the dense blocks that stay on the
+        residual slice, its walked and its seeded part merged back into
+        one dst order: every chip walks its chunk of all of it, and the
+        single chip's lookup from the seeds is not used here; + folded-
+        back blocks of that phase, padded to the graph axis) and the
+        dense blocks that stay on the
         MXU path (src axis divisible by the graph-axis size). Folded
         block edges are never caveated (caveated edges are excluded from
         dense blocks at compile, like expiring ones), so they carry
@@ -524,11 +527,12 @@ class ShardedGraph:
         res_cav = cg.res_cav
         if res_cav is None or len(res_cav) != len(cg.res_src):
             res_cav = np.zeros(len(cg.res_src), dtype=np.int32)
+        seed = cg._seed_layout()
         out = []
         for i, k in enumerate(range(-cg.n_pre, cg.n_levels + 1)):
-            # base residual slice for the phase: already dst-sorted and
-            # carrying incremental invalidations (res_exp -> -inf); its
-            # trailing bucket padding is harmless trash
+            # base residual slice for the phase, carrying incremental
+            # invalidations (res_exp -> -inf); its bucket padding is
+            # harmless trash that sorts last
             lo, hi = bounds[i], bounds[i + 1]
             parts = [(cg.res_src[lo:hi], cg.res_dst[lo:hi],
                       cg.res_exp[lo:hi], res_cav[lo:hi])]
@@ -547,7 +551,10 @@ class ShardedGraph:
             dst = np.concatenate([p[1] for p in parts])
             exp = np.concatenate([p[2] for p in parts])
             cav = np.concatenate([p[3] for p in parts])
-            if len(parts) > 1:  # merged folded edges: restore dst order
+            if len(parts) > 1 or (seed is not None
+                                  and seed.starts[i] < hi):
+                # folded edges, or a seeded part (ordered by source):
+                # restore dst order
                 order = np.argsort(dst, kind="stable")
                 src, dst, exp, cav = (src[order], dst[order], exp[order],
                                       cav[order])
@@ -789,6 +796,9 @@ class ShardedGraph:
         # process or many (a committed local array would need a reshard
         # from a non-global placement under multi-controller)
         crossover = np.float32(getattr(self.cg, "spmm_crossover", 1.0))
+        if self.cg.seed_edges():
+            # the mesh walks the seeded parts (_host_level_edges)
+            metrics.counter("engine_seed_walks_total").inc()
         with tracer.stage("engine_enqueue",
                           metrics.histogram("engine_enqueue_seconds"),
                           rows=len(seeds_pad)):

@@ -165,7 +165,8 @@ def _compile_fixpoint(cg, one_chip, q_contig_len: int) -> str:
                                        "q_contig_rows"))
         return run.lower(
             blocks, bits,
-            *like((cg.res_src, cg.res_dst, cg.res_exp, cg.res_cav)),
+            *like((cg.res_src, cg.res_dst, cg.res_exp, cg.res_cav,
+                   cg._res_ptr())),
             *like(cg._delta_host()),
             like(cav_static), like(cav_req),
             S((1, 2), jnp.int32), S((), jnp.int32), S((), jnp.int32),

@@ -176,6 +176,49 @@ def test_overlay_filter_delete_and_idempotent_redelete():
         == compiles0
 
 
+def test_seeded_edge_killed_added_killed_and_folded_without_recompile():
+    """A membership out of ``user.__self`` lives in a seeded part (sorted
+    by source under a row pointer): a delete kills it where it lies
+    (found by its source), a re-add rides the overlay, a second delete
+    kills that, and a fold lays the base out again: no graph compile
+    before the fold, no fresh trace at any point, parity after each."""
+    e = build(n_users=40)
+    base = e.compiled()
+    src, _ = base.encode_subject("user", "u7", None, e._objects_by_name())
+    dst = base.encode_target("group", "member", "g2",
+                             e._objects_by_name())
+    (pos,) = R._res_positions(base, src, dst)
+    phase = int(np.searchsorted(base.res_level_bounds, pos, "right")) - 1
+    assert base.seed.starts[phase] <= pos  # in the seeded part
+    assert base.seed_mode(1) == "lookup"
+    item = [CheckItem("group", "g2", "member", "user", "u7")]
+    assert e.check_bulk(item) == [True]
+    compiles0 = metrics.counter("engine_graph_compiles_total").value
+    lookups0 = metrics.counter("engine_seed_lookups_total").value
+    traces0 = R._TRACE_BUILDS
+    for op, want, n_delta in (("delete", False, 0), ("touch", True, 1),
+                              ("delete", False, 1)):
+        e.write_relationships([WriteOp(op, rel("group:g2#member@user:u7"))])
+        cg = e.compiled()
+        assert cg.res_src is base.res_src and cg.n_delta == n_delta
+        assert cg.res_exp[pos] == -np.inf
+        assert e.check_bulk(item) == [want]
+        assert_oracle_parity(e, n_users=40)
+    assert metrics.counter("engine_graph_compiles_total").value == compiles0
+    c = e.enable_compaction(1.0)
+    assert c.compact() is True
+    e.close_compaction()
+    folded = e.compiled()
+    assert folded.res_src is not base.res_src and folded.n_delta == 0
+    assert R._res_positions(folded, src, dst) == []
+    assert folded.seed_edges() == base.seed_edges() - 1
+    assert e.check_bulk(item) == [False]
+    assert_oracle_parity(e, n_users=40)
+    assert folded.signature() == base.signature()
+    assert R._TRACE_BUILDS == traces0
+    assert metrics.counter("engine_seed_lookups_total").value > lookups0
+
+
 def test_closured_block_delete_recloses_and_expiry_attach_falls_back(
         monkeypatch):
     """The two fallback edge cases of the closured dense block: deleting

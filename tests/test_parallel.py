@@ -231,6 +231,49 @@ def test_sharded_sees_incremental_updates():
         assert got_ids == want, f"subject {u}: {got_ids} != {want}"
 
 
+def test_sharded_agrees_slot_for_slot_on_a_graph_with_a_seeded_part():
+    """The single chip reads the edges out of ``user.__self`` from a
+    dispatch's seeds (their part of a slice is sorted by source); the
+    mesh merges that part back into dst order and walks it. Every slot
+    of the state agrees, before and after a kill of one such edge
+    through ``updated()``."""
+    from spicedb_kubeapi_proxy_tpu.engine.store import RelationshipFilter
+
+    e, users = build_engine(seed=31, n_users=40, n_groups=12, n_docs=30)
+    cg = e.compiled()
+    assert cg.seed_edges() > 40 and cg.seed_mode(1) == "lookup"
+    sg = ShardedGraph(cg, make_mesh(8, data=2, graph=4))
+    for _, h_dst, _, _ in sg._h_levels:
+        assert np.all(np.diff(h_dst) >= 0)
+    subjects = [("user", u) for u in users] + [("user", "nobody")]
+
+    def agree(cg, sg):
+        objs = e._objects_by_name()
+        seeds = np.asarray([cg.encode_subject(t, i, None, objs)
+                            for t, i in subjects], dtype=np.int32)
+        every = np.arange(cg.M, dtype=np.int32)
+        got = sg.query_grid(seeds, np.tile(every, (len(seeds), 1)))
+        for b in range(len(seeds)):  # one row a dispatch: the lookup
+            want = cg.query(seeds[b:b + 1], every, np.zeros_like(every))
+            assert np.array_equal(got[b], want), (
+                subjects[b], np.flatnonzero(got[b] != want)[:5])
+        assert got.sum() > 2 * len(seeds)
+
+    agree(cg, sg)
+    gone = sorted(e.read_relationships(RelationshipFilter(
+        resource_type="group", relation="member", subject_type="user")),
+        key=str)[0]
+    e.write_relationships([WriteOp("delete", gone)])
+    cg2 = e.compiled()
+    assert cg2.res_src is cg.res_src and len(cg2.dead_pairs) == 1
+    sg2 = sg.updated(cg2)
+    assert sg2._run is sg._run  # no rebuild: the kill found its edge
+    agree(cg2, sg2)
+    assert e.check_bulk([CheckItem(
+        "group", gone.resource_id, "member", "user", gone.subject_id)]) \
+        == [False]
+
+
 def test_engine_mesh_routes_queries_through_sharded():
     """Engine(mesh=...) answers checks and lookups through the sharded
     backend — parity with a single-device engine over the same store,

@@ -104,7 +104,10 @@ def test_nested_org_loop_holds_group_to_group_edges_only(deployments):
     assert cg.core_ranges() == 1 and cg.feeder_ranges() == 1
     lo, hi = cg.run_meta().level_slice(0)
     assert cg.core_edges() == hi - lo == reachability._next_bucket(nested)
-    assert cg.feeder_edges() == reachability._next_bucket(direct)
+    # every entry edge starts at a user: the walked part is its floor
+    assert cg.feeder_edges() == 8 + reachability._next_bucket(direct)
+    assert cg.run_meta().parts(-1) == (0, 8, cg.feeder_edges())
+    assert cg.seed_edges() == direct
 
 
 def test_ns_tree_loop_holds_the_arrow_edges_only(deployments):
@@ -129,10 +132,15 @@ def test_ns_tree_loop_holds_the_arrow_edges_only(deployments):
 
 def test_kube_rbac_has_no_cycle_and_keeps_the_schedule_it_had(deployments):
     """Read from the parent commit (PR 28) on the same seed: a graph the
-    sink-end peel takes whole gets no feeder, no entry, the same bounds."""
+    sink-end peel takes whole gets no feeder, no entry, the same levels.
+    Since PR 31 the two slices whose edges all start in a ``__self``
+    range (users into groups, pods' namespaces) hold them as a seeded
+    part behind an empty walked part of 8: (.., 272, .., 1336) before."""
     _, cg = deployments("kube-rbac-10m")
     assert cg.n_pre == 0 and cg.n_levels == 6
-    assert cg.res_level_bounds == (0, 8, 16, 272, 304, 312, 824, 1336)
+    assert cg.res_level_bounds == (0, 8, 16, 280, 312, 320, 832, 1352)
+    assert cg.seed.starts == (8, 16, 24, 312, 320, 832, 840)
+    assert cg.seed.fanout == (0, 0, 8, 0, 0, 0, 64)
     assert levels_by_name(cg) == {
         "user#__self": 1, "group#member": 2, "namespace#creator": 3,
         "namespace#viewer": 3, "namespace#view": 4, "activity#__self": 5,
@@ -530,3 +538,224 @@ def test_the_mesh_program_equals_the_single_chip_program(case, dense,
         got, mesh_trips = whole_state(e, sg, NOW, context)
         assert np.array_equal(got, want), np.argwhere(got != want)[:5]
         assert mesh_trips == trips
+
+
+# ---------------------------------------------------------------------------
+# (e) edges out of a ``__self`` range are looked up from the seeds
+# ---------------------------------------------------------------------------
+
+SEEDED = """
+use expiration
+caveat ip_allowlist(ip ipaddress, allowed list<ipaddress>) { ip in allowed }
+definition user {}
+definition group {
+  relation member: user | user:* | user with expiration
+    | user with ip_allowlist | group#member
+}
+definition namespace {
+  relation parent: namespace
+  relation viewer: user | user:* | group#member
+  relation banned: user
+  permission view = (viewer - banned) + parent->view
+}
+definition pod {
+  relation namespace: namespace
+  relation creator: user
+  permission view = creator + namespace->view
+}
+"""
+WIDE_USERS, WIDE_GROUPS, WIDE_NS = 96, 40, 24
+
+
+def seeded_graph(rng):
+    """Wide enough for the table to be the shorter way at 1 and 8 rows:
+    every user in 1-3 groups (a third of the memberships expiring,
+    expired or caveated), ``user:*`` a member of 24 groups (one run
+    longer than any user's), grants, bans and creators out of ``user``,
+    parents and pods out of ``namespace``."""
+    def stamp(t):
+        return time.strftime("%Y-%m-%dT%H:%M:%SZ", time.gmtime(t))
+
+    traits = ("", "", "", "", f"[expiration:{stamp(NOW + 3600)}]",
+              f"[expiration:{stamp(NOW - 3600)}]",
+              '[ip_allowlist:{"allowed":["10.0.0.0/8"]}]')
+    member = {}
+    for u in range(WIDE_USERS):
+        for g in rng.choice(WIDE_GROUPS, size=rng.integers(1, 4),
+                            replace=False):
+            member[(int(g), u)] = traits[rng.integers(len(traits))]
+    ops = {f"group:g{g}#member@user:u{u}{t}" for (g, u), t in member.items()}
+    ops |= {f"group:g{g}#member@user:*" for g in range(24)}
+    ops |= {f"group:g{p}#member@group:g{c}#member"
+            for c, p in _tree(rng, WIDE_GROUPS)}
+    for n in range(WIDE_NS):
+        ops.add(f"namespace:n{n}#viewer@group:g{rng.integers(WIDE_GROUPS)}"
+                "#member")
+        ops.add(f"namespace:n{n}#viewer@user:u{rng.integers(WIDE_USERS)}")
+        ops.add(f"namespace:n{n}#banned@user:u{rng.integers(WIDE_USERS)}")
+    ops.add("namespace:n3#viewer@user:*")
+    ops |= {f"namespace:n{c}#parent@namespace:n{p}"
+            for c, p in _tree(rng, WIDE_NS)}
+    for p in range(60):
+        ops.add(f"pod:p{p}#namespace@namespace:n{rng.integers(WIDE_NS)}")
+        ops.add(f"pod:p{p}#creator@user:u{rng.integers(WIDE_USERS)}")
+    return ops
+
+
+@pytest.fixture(scope="module")
+def seeded_engines(deployments):
+    """name -> (engine, subjects, contexts): the wide random graph on
+    three seeds, and the two device-bound deployments at rehearsal
+    size."""
+    cache: dict = {}
+
+    def load(name):
+        if name in cache:
+            return cache[name]
+        if name.startswith("wide"):
+            e = Engine(schema=parse_schema(SEEDED))
+            e.write_relationships(touch(*sorted(seeded_graph(
+                np.random.default_rng(int(name[4:]))))))
+            subjects = [("user", f"u{u}", None) for u in (0, 1, 17, 95)] + [
+                ("group", "g0", "member"),    # a userset subject
+                ("user", "nobody", None),     # unknown: the trash slot
+                ("namespace", "n0", None)]    # seeds another __self range
+            contexts = ({"ip": "10.0.0.5"}, {"ip": "8.8.8.8"})
+        else:
+            dep, _ = deployments(name)
+            e = Engine(dep.text("bootstrap.yaml"))
+            e.bulk_load(dep.columns())
+            subjects = [("user", f"u{u}", None) for u in (0, 3, 50)] + [
+                ("group", "g0", "member"), ("user", "nobody", None),
+                ("namespace", "ns0", None)]
+            contexts = (None,)
+        e.compiled()
+        cache[name] = e, subjects, contexts
+        return cache[name]
+
+    return load
+
+
+def state_by_rows(e, backend, subjects, rows, now, context):
+    """Every slot of ``V`` for every subject, ``rows`` subjects (padded
+    with the last) a dispatch."""
+    cg = e.compiled()
+    objs = e._objects_by_name()
+    seeds = np.asarray([cg.encode_subject(t, i, r, objs)
+                        for t, i, r in subjects], dtype=np.int32)
+    q = np.tile(np.arange(cg.M, dtype=np.int32), rows)
+    qb = np.repeat(np.arange(rows, dtype=np.int32), cg.M)
+    out = []
+    for at in range(0, len(seeds), rows):
+        chunk = seeds[at:at + rows]
+        chunk = np.concatenate(
+            [chunk, np.repeat(chunk[-1:], rows - len(chunk), axis=0)])
+        got = backend.query_async(chunk, q, qb, now=now,
+                                  context=context).result()
+        out.append(got.reshape(rows, cg.M)[:len(seeds) - at])
+    return np.concatenate(out)
+
+
+def seed_counters() -> tuple:
+    return (metrics.counter("engine_seed_lookups_total").value,
+            metrics.counter("engine_seed_walks_total").value)
+
+
+@pytest.mark.parametrize("rows,mode", [(1, "lookup"), (8, "lookup"),
+                                       (64, "walk")],
+                         ids=["B1", "B8", "B64-walks"])
+@pytest.mark.parametrize("name", ["wide1", "wide2", "wide3",
+                                  "nested-org-1m", "ns-tree-10hop"])
+def test_seeded_program_equals_the_plain_loop_and_the_oracle(
+        name, rows, mode, seeded_engines):
+    """A user, a userset subject, an unknown subject and a subject of
+    another type, at 1 and 8 rows a dispatch (the table) and at 64 (the
+    same edges walked): every slot equals one plain loop over every
+    edge; then the engine's own answers against the oracle."""
+    e, subjects, contexts = seeded_engines(name)
+    cg = e.compiled()
+    assert cg.seed_edges() > 0 and cg.seed_mode(rows) == mode
+    dispatches = -(-len(subjects) // rows)
+    for context in contexts:
+        before = seed_counters()
+        got = state_by_rows(e, cg, subjects, rows, NOW, context)
+        after = seed_counters()
+        assert (after[0] - before[0], after[1] - before[1]) == (
+            (dispatches, 0) if mode == "lookup" else (0, dispatches))
+        want = state_by_rows(e, flat(cg), subjects, rows, NOW, context)
+        assert np.array_equal(got, want), np.argwhere(got != want)[:5]
+        assert got[0].sum() > 2  # more than its own two seeds
+    if rows == 1:
+        o = e.oracle(now=NOW, context=contexts[0])
+        snap = e.store.snapshot()
+
+        def first(tname, n):
+            it = snap.objects.get(snap.types.lookup(tname), ())
+            return [it.string(i) for i in range(2, min(len(it), 2 + n))]
+
+        items = [CheckItem(rt, rid, "view", t, i, r)
+                 for t, i, r in subjects
+                 for rt, n in (("namespace", 60), ("pod", 20))
+                 for rid in first(rt, n)]
+        got = e.check_bulk(items, now=NOW, context=contexts[0])
+        want = [o.check(i.resource_type, i.resource_id, i.permission,
+                        i.subject_type, i.subject_id, i.subject_relation)
+                for i in items]
+        assert got == want and True in want and False in want
+
+
+def test_a_long_run_stays_walked_and_leaves_the_fanout_alone(
+        seeded_engines):
+    """``user:*`` is a member of 24 groups and a viewer of one namespace
+    (all entry edges: both ranges iterate), no user holds more than a
+    handful: the entry phase's fan-out is what the users need, the
+    wildcard's run lies in the walked part, and every user still gets
+    it."""
+    e, _, _ = seeded_engines("wide1")
+    cg = e.compiled()
+    meta = cg.run_meta()
+    lo, mid, hi = meta.parts(-1)
+    assert cg.seed.fanout[cg.n_pre - 1] == 8
+    star = cg.slot_offset[("user", reachability.SELF_REL)] \
+        + reachability.WILDCARD_IDX
+    assert np.count_nonzero(cg.res_src[lo:mid] == star) == 25
+    assert not np.any(cg.res_src[mid:hi] == star)
+    runs = np.unique(cg.res_src[mid:hi][cg.res_dst[mid:hi] != cg.M],
+                     return_counts=True)[1]
+    assert 0 < runs.max() <= 8 and len(runs) > 64
+    assert reachability._seed_fanout(np.append(runs, 24)) \
+        == reachability._seed_fanout(runs) == 8
+    # several long runs pay for themselves; one never does
+    assert reachability._seed_fanout(np.asarray([3] * 50 + [24] * 5)) == 32
+    assert reachability._seed_fanout(np.asarray([3] * 50 + [3000])) == 8
+    # the wildcard's grant reaches any user, even one the store never saw
+    assert e.check_bulk([CheckItem("group", "g5", "member", "user", "x")],
+                        now=NOW) == [True]
+    assert e.check_bulk([CheckItem("group", "g30", "member", "user", "x")],
+                        now=NOW) == [False]
+
+
+def test_expiring_and_caveated_edges_out_of_self_follow_the_one_rule(
+        seeded_engines):
+    """The table reads the activation array the walk reads: an expired
+    membership is off, a caveated one follows the request's context,
+    both through the lookup (one row) and through the walk (64)."""
+    e, _, _ = seeded_engines("wide2")
+    cg = e.compiled()
+    lo, mid, hi = cg.run_meta().parts(-1)
+    exp, cav = cg.res_exp[mid:hi], cg.res_cav[mid:hi]
+    real = cg.res_dst[mid:hi] != cg.M
+    assert np.any(real & np.isfinite(exp)) and np.any(real & (cav != 0))
+    users = [("user", f"u{u}", None) for u in range(WIDE_USERS)]
+    seen = []
+    for rows in (1, 64):
+        per_ctx = [state_by_rows(e, cg, users, rows, NOW, c)
+                   for c in ({"ip": "10.0.0.5"}, {"ip": "8.8.8.8"})]
+        late = state_by_rows(e, cg, users, rows, NOW + 7200,
+                             {"ip": "10.0.0.5"})
+        seen.append(per_ctx + [late])
+        # the allowlisted address sees more, the later clock fewer
+        assert per_ctx[0].sum() > per_ctx[1].sum()
+        assert per_ctx[0].sum() > late.sum()
+    for a, b in zip(*seen):
+        assert np.array_equal(a, b)

@@ -112,7 +112,7 @@ def single_prefilter(rules: list[RunnableRule]) -> Optional[tuple[RunnableRule, 
 
 def run_prefilter_sync(engine: Engine, pf: PreFilter,
                        input: ResolveInput,
-                       strict: bool = True, lookup=None,
+                       strict: bool = True,
                        context: Optional[dict] = None) -> AllowedSet:
     """``strict=False`` skips ids whose name/namespace mapping expression
     fails instead of raising — for MID-STREAM recomputes, where one
@@ -120,27 +120,22 @@ def run_prefilter_sync(engine: Engine, pf: PreFilter,
     OPEN for revocations). The initial, pre-headers run stays strict so
     misconfigured mappings surface as a 500.
 
-    ``lookup`` overrides the engine call with ``lookup(rel) -> [ids]`` —
-    the watch hub routes group recomputes through a shared
-    :class:`~..engine.batcher.LookupBatcher` this way, so N groups
-    triggered by one write batch fuse into ~N/8 device fixpoints
-    instead of N (authz/watchhub.py). Results are unconditional by
-    construction (caveated tuples never enter the store —
-    models/bootstrap.py / engine._validate — so there are no
-    CONDITIONAL results to skip; the reference's lookups.go:83-90 skip
-    happens here at ingest instead)."""
+    The watch hub's group recomputes (authz/watchhub.py) come here from
+    N worker threads at once and carry no request context: the engine's
+    batcher (engine/batcher.py) fuses their lookups, conditional grants
+    resolve from tuple context alone, and missing request-only
+    parameters fail closed — the safe direction for a mid-stream
+    allowed-set refresh. Results are unconditional by construction
+    (caveated tuples never enter the store — models/bootstrap.py /
+    engine._validate — so there are no CONDITIONAL results to skip; the
+    reference's lookups.go:83-90 skip happens here at ingest
+    instead)."""
     rel = pf.rel.generate(input)[0]
     if rel.resource_id != MATCHING_ID_FIELD_VALUE:
         raise PreFilterError(
             f"prefilter resource ID must be {MATCHING_ID_FIELD_VALUE!r}, "
             f"got {rel.resource_id!r} (reference lookups.go:49-56)")
-    if lookup is not None:
-        # shared-batcher recomputes (watch hub) carry no request context:
-        # conditional grants resolve from tuple context alone, missing
-        # request-only parameters fail closed — the safe direction for a
-        # mid-stream allowed-set refresh
-        ids = lookup(rel)
-    elif context:
+    if context:
         ids = engine.lookup_resources(
             rel.resource_type, rel.resource_relation,
             rel.subject_type, rel.subject_id, rel.subject_relation or None,
@@ -213,10 +208,10 @@ def _map_ids(pf: PreFilter, input: ResolveInput, ids, strict: bool
 
 async def run_prefilter(engine: Engine, pf: PreFilter,
                         input: ResolveInput,
-                        strict: bool = True, lookup=None,
+                        strict: bool = True,
                         context: Optional[dict] = None) -> AllowedSet:
     """Async wrapper so the device query overlaps the upstream kube request
     (the reference overlaps via goroutine+channel,
     responsefilterer.go:165-183)."""
     return await tracer.to_thread(run_prefilter_sync, engine, pf, input,
-                                  strict, lookup, context)
+                                  strict, context)

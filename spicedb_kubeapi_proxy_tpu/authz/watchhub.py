@@ -57,16 +57,6 @@ EXPIRY_RECOMPUTE_INTERVAL = 1.0
 # stream (old remote hosts)
 LEGACY_POLL_INTERVAL = 0.05
 
-# fusing window for group recomputes (engine/batcher.py): one write batch
-# kicks every always-relevant group within milliseconds of each other, so
-# a short hold fuses N group fixpoints into ~N/8 device dispatches — the
-# frames/s collapse at 50 groups was N dispatches per write batch.
-# Wider than the request-path default: a recompute is background work
-# whose result was already ordered by the ("pending", seq) marker, so a
-# few ms of extra hold buys fusing even under to_thread scheduling jitter
-RECOMPUTE_BATCH_WINDOW = 0.005
-
-
 class WatcherHandle:
     """One registered watcher: the hub feeds ``queue``; the watch loop
     additionally feeds its own upstream frames into the same queue so it
@@ -121,11 +111,6 @@ class WatchHub:
         self._push_stream = None
         self._q: Optional[asyncio.Queue] = None
         self._last_rev: Optional[int] = None
-        # hub-owned LookupBatcher fusing concurrent group recomputes into
-        # shared device fixpoints (in-process engines only; a tcp:// host
-        # fuses server-side via --lookup-batch-window). Created lazily,
-        # closed with the pump.
-        self._recompute_batcher = None
         # register/unregister await (engine.revision, watch_gate) between
         # their check-then-set steps; without mutual exclusion two
         # concurrent registrations would duplicate pumps or overwrite each
@@ -218,11 +203,6 @@ class WatchHub:
             # release any worker thread parked in wait_since so loop
             # shutdown never waits out the wait timeout
             store.wake_waiters()
-        if self._recompute_batcher is not None:
-            # flush + mark dead: a recompute racing the teardown falls
-            # through to the direct engine path (batcher.close contract)
-            self._recompute_batcher.close()
-            self._recompute_batcher = None
         self._q = None
 
     async def _teardown_pump(self, dead_pump: asyncio.Task) -> None:
@@ -403,39 +383,6 @@ class WatchHub:
             group.task = asyncio.get_running_loop().create_task(
                 self._recompute(group))
 
-    def _recompute_lookup(self):
-        """``lookup(rel) -> [ids]`` override for run_prefilter, routing
-        group recomputes through a hub-owned LookupBatcher so the N
-        groups one write batch triggers fuse into ~N/8 device fixpoints
-        instead of N independent dispatches. None when the engine cannot
-        batch locally (remote client — the engine HOST fuses across all
-        proxies with --lookup-batch-window) or already batches every
-        lookup itself (engine._batcher set: the request-path batcher
-        would fuse our recomputes with live list prefilters, strictly
-        better)."""
-        eng = self.engine
-        if not hasattr(eng, "_lookup_direct") \
-                or getattr(eng, "_batcher", None) is not None:
-            return None
-        if self._recompute_batcher is None:
-            from ..engine.batcher import LookupBatcher
-
-            self._recompute_batcher = LookupBatcher(
-                eng, window=RECOMPUTE_BATCH_WINDOW, max_rows=8)
-        batcher = self._recompute_batcher
-
-        def lookup(rel):
-            from ..engine.engine import mask_to_ids
-
-            fut = batcher.submit(
-                rel.resource_type, rel.resource_relation,
-                rel.subject_type, rel.subject_id,
-                rel.subject_relation or None)
-            mask, interner = fut.result()
-            return mask_to_ids(mask, interner)
-
-        return lookup
-
     async def _recompute(self, group: _Group) -> None:
         import time as _time
 
@@ -446,9 +393,12 @@ class WatchHub:
                 start_seq = group.seq
                 t0 = _time.perf_counter()
                 try:
+                    # the N groups one write batch kicks recompute on
+                    # worker threads at once: the engine's batcher (on
+                    # the engine host for a tcp:// engine) fuses their
+                    # lookups into shared dispatches
                     fresh = await run_prefilter(
-                        self.engine, group.pf, group.input, strict=False,
-                        lookup=self._recompute_lookup())
+                        self.engine, group.pf, group.input, strict=False)
                 except asyncio.CancelledError:
                     raise
                 except Exception as e:

@@ -33,6 +33,7 @@ from ..ops.reachability import (
     incremental_update,
 )
 from ..utils.metrics import metrics
+from .batcher import LookupBatcher
 from .decision_cache import DecisionCache, MISS, check_key, lookup_key
 from .evaluator import OracleEvaluator
 from .store import (
@@ -284,6 +285,7 @@ class Engine:
         self._lock = threading.RLock()
         self._compiled: Optional[CompiledGraph] = None
         self._batcher = None
+        self.enable_lookup_batching()
         self._decision_cache: Optional[DecisionCache] = None
         self._persistence = None  # persistence/manager.py, opt-in
         # delta-overlay sizing for every graph this engine compiles, and
@@ -333,15 +335,13 @@ class Engine:
         if seed:
             self.write_relationships([WriteOp("touch", r) for r in seed])
 
-    def enable_lookup_batching(self, window: float = 0.002,
-                               max_rows: int = 8) -> None:
-        """Coalesce concurrent lookup_resources_mask calls into fused
-        device dispatches (engine/batcher.py) — trades up to ``window``
-        seconds of added latency for one dispatch per ``max_rows``
-        concurrent list prefilters."""
-        from .batcher import LookupBatcher
-
-        self._batcher = LookupBatcher(self, window=window, max_rows=max_rows)
+    def enable_lookup_batching(self) -> None:
+        """Fuse concurrent lookup_resources_mask calls into device
+        dispatches of several subject rows (engine/batcher.py). Every
+        engine starts with it on; this turns it back on after
+        :meth:`disable_lookup_batching`."""
+        self.disable_lookup_batching()
+        self._batcher = LookupBatcher(self)
 
     def disable_lookup_batching(self) -> None:
         """Revert to one device dispatch per lookup. The retired batcher
@@ -1173,8 +1173,9 @@ class Engine:
                        subject_relation: Optional[str],
                        now: Optional[float],
                        context: Optional[dict] = None):
-        """Route one true-miss lookup: fused through the batcher when
-        enabled, direct otherwise."""
+        """Route one true-miss lookup: through the batcher, which fuses
+        it with the lookups waiting beside it or sends it alone; direct
+        for what cannot share a dispatch."""
         cg = self._compiled
         # a request context only matters when the graph actually holds
         # caveat instances: a fused batch evaluates ONE caveat mask per
@@ -1185,11 +1186,16 @@ class Engine:
         ctx_matters = bool(context) and not (
             cg is not None and cg.revision == self.store.revision
             and (cg.caveats is None or not cg.caveats.metas))
-        if self._batcher is not None and now is None and not ctx_matters:
+        batcher = self._batcher
+        if batcher is not None and now is None and not ctx_matters \
+                and self.mesh is None:
             # explicit-now callers bypass the batcher: a fused batch runs
             # at one dispatch-time clock, which is only equivalent to the
-            # unbatched path for now-less queries
-            return self._batcher.submit(
+            # unbatched path for now-less queries. A mesh engine's
+            # lookups go one a dispatch, as they always have; so do those
+            # of a graph whose fused program does not pay, which the
+            # batcher hands straight back (``fused_rows``).
+            return batcher.submit(
                 resource_type, permission, subject_type, subject_id,
                 subject_relation)
         return self._lookup_direct(resource_type, permission, subject_type,
@@ -1241,17 +1247,19 @@ class Engine:
                        subject_type: str, subject_id: str,
                        subject_relation: Optional[str],
                        now: Optional[float],
-                       context: Optional[dict] = None):
+                       context: Optional[dict] = None,
+                       enqueued: Optional[list] = None):
+        """One lookup, one dispatch of one row. ``enqueued``: where the
+        batcher collects the dispatches it has on the device."""
         cg = self.compiled()
         objs = self._objects_by_name()
         off = cg.offset_of(resource_type, permission)
         n = cg.type_sizes.get(resource_type)
         interner = objs.get(resource_type)
         if off is None or interner is None:
-            # trivial lookups (unknown type/permission) count too — the
-            # batched path already counts them in LookupBatcher._dispatch,
-            # and tests read engine_lookups_total as "lookups the engine
-            # answered", cache hits excluded
+            # trivial lookups (unknown type/permission) count too: tests
+            # read engine_lookups_total as "lookups the engine answered",
+            # cache hits excluded
             metrics.counter("engine_lookups_total").inc()
             return EngineFuture(None, lambda _: (None, None))
         with tracer.stage("engine_encode",
@@ -1287,6 +1295,8 @@ class Engine:
             seeds, q_slots, q_batch, now=now,
             q_cache_key=("lookup", off, n), q_contiguous=True,
             context=context)
+        if enqueued is not None:
+            enqueued.append(fut)
         metrics.counter("engine_lookups_total").inc()
         _count_dispatch_rows(len(seeds))
 

@@ -397,8 +397,8 @@ class EngineServer:
 
     The workers come from a DEDICATED executor, not the loop's default
     pool: push-watch streams park a thread per subscriber waiting for
-    events, and batched lookups (enable_lookup_batching) park up to
-    max_rows threads per fill window — on a small host the default
+    events, and lookups waiting to be fused (engine/batcher.py) park a
+    thread each until a dispatch carries them — on a small host the default
     pool's min(32, cpus+4) workers would starve request handling (and an
     embedding application's own to_thread users would compete with the
     engine)."""
@@ -2295,13 +2295,6 @@ def main(argv=None) -> int:
     ap.add_argument("--failover-boot-grace", type=float, default=20.0,
                     help="(--peers) boot-time wait for the rest of the "
                          "set before electing from partial visibility")
-    ap.add_argument("--lookup-batch-window", type=float, default=0.0,
-                    help="fuse concurrent lookup_mask requests (across "
-                         "ALL connected proxies) into shared device "
-                         "dispatches, holding each for at most this many "
-                         "seconds (0 = off). No effect on --distributed "
-                         "hosts: mirrored lookups pin their evaluation "
-                         "time for SPMD lockstep, which bypasses fusion")
     from ..proxy.options import parse_bool_flag
 
     ap.add_argument("--authz-cache", type=parse_bool_flag, nargs="?",
@@ -2532,8 +2525,6 @@ def main(argv=None) -> int:
         if mig is not None:
             log.info("schema migration record recovered: %s (phase %s)",
                      mig.get("action"), mig.get("phase"))
-    if args.lookup_batch_window > 0:
-        engine.enable_lookup_batching(args.lookup_batch_window)
     if args.authz_cache:
         engine.enable_decision_cache(
             max_entries=args.authz_cache_size,

@@ -925,6 +925,18 @@ class CompiledGraph:
                 return self._dev_locked()
         return d
 
+    def memo(self, key, make):
+        """``make()`` once, kept beside this graph's device arrays and
+        compiled programs: it lives as long as they do, incremental
+        updates included (engine/batcher.py keeps a window's fused lookup
+        program here)."""
+        d = self._dev()
+        if key not in d:
+            with _DEV_INIT_LOCK:
+                if key not in d:
+                    d[key] = make()
+        return d[key]
+
     def _dev_locked(self):
         d = self._device
         if not d:
@@ -1189,8 +1201,8 @@ class CompiledGraph:
     def query_async(
         self,
         seed_slots: np.ndarray,  # int32 [B, 2] (subject slot, wildcard slot)
-        q_slots: np.ndarray,  # int32 [Q]
-        q_batch: np.ndarray,  # int32 [Q] batch row per query
+        q_slots: Optional[np.ndarray],  # int32 [Q]; None with a grid
+        q_batch: Optional[np.ndarray],  # int32 [Q] batch row per query
         now: Optional[float] = None,
         max_iters: int = DEFAULT_MAX_ITERS,
         q_cache_key: Optional[tuple] = None,
@@ -1218,7 +1230,9 @@ class CompiledGraph:
         """
         d = self._dev()
         B = seed_slots.shape[0]
-        Q = len(q_slots)
+        # a grid names its own Q: its caller builds no [Q] arrays
+        Q = len(q_slots) if q_slots is not None \
+            else q_contig_grid[1] * q_contig_grid[2]
         B_pad = _next_bucket(B, 1)
         Q_pad = _next_bucket(Q, 8)
         seeds = np.full((B_pad, 2), self.M, dtype=np.int32)

@@ -139,9 +139,6 @@ class Options:
     checkpoint_wal_bytes: int = 64 << 20
     checkpoint_wal_records: int = 50_000
     checkpoint_keep: int = 2
-    # >0 coalesces concurrent list prefilters into fused device dispatches
-    # (seconds of added latency traded for per-dispatch amortization)
-    lookup_batch_window: float = 0.0
     # revision-keyed decision cache + singleflight on the authorization
     # hot path (engine/decision_cache.py): repeats at an unchanged store
     # revision serve host-side with zero device dispatches. In-process
@@ -353,7 +350,6 @@ class Options:
                      "bootstrap"),
                     (self.snapshot_path, "snapshot-path"),
                     (self.data_dir, "data-dir"),
-                    (self.lookup_batch_window > 0, "lookup-batch-window"),
                     (self.engine_mesh, "engine-mesh")):
                 if bad:
                     raise OptionsError(
@@ -464,10 +460,6 @@ class Options:
                     "checkpoint-wal-bytes/records must be >= 1")
             if self.checkpoint_keep < 1:
                 raise OptionsError("checkpoint-keep must be >= 1")
-        if remote and self.lookup_batch_window > 0:
-            raise OptionsError(
-                "lookup-batch-window applies to in-process engines; batch "
-                "on the tcp:// engine host instead")
         if remote and self.engine_mesh:
             raise OptionsError(
                 "engine-mesh applies to in-process engines; configure the "
@@ -823,8 +815,6 @@ class Options:
                 engine.recover_schema_migration()
             else:
                 engine.load_snapshot_if_exists(self.snapshot_path)
-            if self.lookup_batch_window > 0:
-                engine.enable_lookup_batching(self.lookup_batch_window)
             if self.authz_cache:
                 engine.enable_decision_cache(
                     max_entries=self.authz_cache_size,
@@ -1233,10 +1223,6 @@ def add_flags(parser: argparse.ArgumentParser) -> None:
     parser.add_argument("--checkpoint-keep", type=int, default=2,
                         help="snapshot generations to retain (the WAL is "
                              "pruned only up to the oldest kept one)")
-    parser.add_argument("--lookup-batch-window", type=float, default=0.0,
-                        help="seconds to hold a list prefilter for fusing "
-                             "concurrent lookups into one device dispatch "
-                             "(0 disables)")
     parser.add_argument("--authz-cache", type=parse_bool_flag,
                         nargs="?", const=True, default=True,
                         metavar="BOOL",
@@ -1565,7 +1551,6 @@ def options_from_args(args: argparse.Namespace) -> Options:
         checkpoint_wal_bytes=args.checkpoint_wal_bytes,
         checkpoint_wal_records=args.checkpoint_wal_records,
         checkpoint_keep=args.checkpoint_keep,
-        lookup_batch_window=args.lookup_batch_window,
         authz_cache=args.authz_cache,
         authz_cache_size=args.authz_cache_size,
         authz_cache_mask_bytes=args.authz_cache_mask_bytes,

@@ -321,11 +321,26 @@ def test_limiter_grows_under_heavy_weight_saturation():
                           warmup=5, cooldown=2)
     c = AdmissionController(tenant_rate=0.0, tenant_burst=1e9,
                             queue_timeout=5.0, limiter=lim)
+    # what is under test is the in-flight cost a release reports, a
+    # count; the latency beside it is this host's clock between two
+    # statements, which six busy workers stretch past the limiter's
+    # tolerance: every release is observed at one steady latency
+    reported = []
+    observe = lim.observe
+
+    def steady(latency, inflight_cost):
+        reported.append(inflight_cost)
+        observe(0.010, inflight_cost)
+
+    lim.observe = steady
     for _ in range(30):
         t1 = c.acquire("a", LOOKUP_PREFILTER)
         t2 = c.acquire("b", LOOKUP_PREFILTER)  # 8 units: saturated
         t1.release()
         t2.release()
+    # the first of the two releases still counts its own 4 units
+    assert reported == [8.0, 4.0] * 30
+    # 30 saturated releases past warm-up, one probe each cooldown of 2
     assert lim.limit > 8
 
 
@@ -586,13 +601,12 @@ def test_watchhub_groups_fuse_into_batched_dispatches(monkeypatch):
     from spicedb_kubeapi_proxy_tpu.rules.input import ResolveInput
     from spicedb_kubeapi_proxy_tpu.rules.matcher import RequestMeta
 
-    # the counts below are of the hub's mechanism (one write batch kicks
-    # every group; their recomputes go through ONE batcher), not of how
-    # a busy host spaces six worker threads against a 5 ms window, and
-    # not of the expiry tick (a second source of lookups once a slow
-    # host takes over a second): hold submits long enough that they
-    # meet, and keep the tick out of the window
-    monkeypatch.setattr(watchhub, "RECOMPUTE_BATCH_WINDOW", 0.25)
+    # the counts below are of the mechanism (one write batch kicks every
+    # group; their recomputes meet in the engine's batcher), not of how
+    # a busy host spaces six worker threads, and not of the expiry tick
+    # (a second source of lookups once a slow host takes over a second):
+    # the batcher is held until all six wait, and the tick kept out
+    from fusing import hold, release, warm
     monkeypatch.setattr(watchhub, "EXPIRY_RECOMPUTE_INTERVAL", 3600.0)
 
     e = Engine()
@@ -613,12 +627,15 @@ def test_watchhub_groups_fuse_into_batched_dispatches(monkeypatch):
             input = ResolveInput.create(info, UserInfo(name=f"u{i}"))
             handles.append(await hub.register(pf, input))
         assert len({id(h.group) for h in handles}) == 6
+        await asyncio.to_thread(warm, e, "namespace")
+        hold(e._batcher)
         b0 = metrics.counter("engine_lookup_batches_total").value
         n0 = metrics.counter("engine_lookups_total").value
         # ONE write batch triggers all 6 (rule, subject) groups
         await asyncio.to_thread(e.write_relationships, [WriteOp(
             "touch",
             parse_relationship("namespace:dev#viewer@user:u1"))])
+        await asyncio.to_thread(release, e._batcher, 6)
 
         async def drain(h):
             # the group's answer to THIS write: an allowed set computed
@@ -636,11 +653,11 @@ def test_watchhub_groups_fuse_into_batched_dispatches(monkeypatch):
         # every group answered, and with the write applied: u0 and u1
         # see dev, nobody else sees anything
         assert [len(a) for a in answers] == [1, 1, 0, 0, 0, 0], answers
-        # 6 group recomputes fused into shared dispatches (VERDICT Weak
-        # #3: pre-fusing this was 6 independent fixpoints): one lookup a
-        # group and no more, in at most half as many dispatches
+        # 6 group recomputes fused (VERDICT Weak #3: pre-fusing this was
+        # 6 independent fixpoints): one lookup a group and no more, and
+        # those that waited together in one dispatch
         assert lookups == 6
-        assert 1 <= batches <= 3
+        assert batches == 1
         for h in handles:
             await hub.unregister(h)
     asyncio.run(go())

@@ -392,16 +392,24 @@ def test_batched_lookup_counts_missing_context():
     """Context-free lookups FUSE through the batcher (the watch-hub
     recompute path): their fail-closed conditional denials must tick
     the missing-context counter like every other path."""
+    from fusing import hold, release, warm
+    from spicedb_kubeapi_proxy_tpu.engine.engine import mask_to_ids
+
     e = Engine(bootstrap=IP_BOOT)
-    e.enable_lookup_batching(window=0.005)
-    try:
-        c0 = metrics.counter(
-            "engine_caveat_denied_missing_context_total").value
-        assert e.lookup_resources("doc", "view", "user", "bob") == []
-        assert metrics.counter(
-            "engine_caveat_denied_missing_context_total").value > c0
-    finally:
-        e.disable_lookup_batching()
+    b = e._batcher
+    warm(e, "doc")
+    c0 = metrics.counter(
+        "engine_caveat_denied_missing_context_total").value
+    f0 = metrics.counter("engine_lookup_batches_total").value
+    hold(b)
+    futs = [e.lookup_resources_mask_async("doc", "view", "user", u)
+            for u in ("bob", "alice", "carol")]  # three wait: they fuse
+    release(b, 3)
+    bob, alice, carol = (mask_to_ids(*f.result()) for f in futs)
+    assert metrics.counter("engine_lookup_batches_total").value == f0 + 1
+    assert bob == [] and alice == ["readme"] and carol == []
+    assert metrics.counter(
+        "engine_caveat_denied_missing_context_total").value > c0
 
 
 # -- write validation ---------------------------------------------------------

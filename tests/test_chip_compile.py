@@ -139,9 +139,11 @@ def graph():
     return cg
 
 
-def _compile_fixpoint(cg, one_chip, q_contig_len: int) -> str:
+def _compile_fixpoint(cg, one_chip, q_contig_len: int, rows: int = 1) -> str:
     """One whole jitted fixpoint (``_jit_run_for``'s program) over ``cg``,
-    in ``auto``, compiled for the described chip: its HLO text."""
+    in ``auto``, compiled for the described chip: its HLO text. ``rows``
+    subject rows, read back as the grid of that many rows over one
+    window (a lookup alone, or the batcher's fused dispatch)."""
 
     def S(shape, dtype):
         return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
@@ -169,10 +171,10 @@ def _compile_fixpoint(cg, one_chip, q_contig_len: int) -> str:
                    cg._res_ptr())),
             *like(cg._delta_host()),
             like(cav_static), like(cav_req),
-            S((1, 2), jnp.int32), S((), jnp.int32), S((), jnp.int32),
+            S((rows, 2), jnp.int32), S((), jnp.int32), S((), jnp.int32),
             S((), jnp.float32), S((), jnp.float32),
             max_iters=reachability.DEFAULT_MAX_ITERS,
-            q_contig_len=q_contig_len,
+            q_contig_len=q_contig_len, q_contig_rows=rows,
         ).compile().as_text()
 
 
@@ -250,6 +252,39 @@ definition pod {
     for scope in ("feed1", "entry", "core", "level1", "readout"):
         assert f"/{scope}/" in text, scope
     assert text.count("tpu_custom_call") >= 2  # both blocks' kernels
+
+
+@pytest.mark.parametrize("rows", [8, 32])
+def test_fused_lookup_program_of_the_tenant_schema_compiles(
+        one_chip, compiled_kernels, rows):
+    """A dispatch of several subject rows read as a grid, the shape of
+    the batcher's fused dispatch (engine/batcher.py), on the
+    ``multi-tenant-100k`` schema at its rehearsal size, dense blocks
+    and seeded edges included: 8 rows ride the bit kernel, 32 the MXU
+    kernel, and the grid of ``rows`` rows is one ``dynamic_slice`` of
+    the state."""
+    import importlib.util
+    import os
+
+    from spicedb_kubeapi_proxy_tpu.engine import Engine
+
+    spec = importlib.util.spec_from_file_location(
+        "bench_deployment", os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmark", "deployment.py"))
+    deployment = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(deployment)
+    dep = deployment.Deployment("multi-tenant-100k", 3200000029,
+                                rehearse=True)
+    e = Engine(dep.text("bootstrap.yaml"))
+    e.bulk_load(dep.columns())
+    cg = e.compiled()
+    assert cg.blocks and cg.seed_edges()
+    text = _compile_fixpoint(cg, one_chip, cg.type_sizes["namespace"], rows)
+    assert text.startswith("HloModule jit_sdbkp_fixpoint")
+    # past the bit kernel's rows the push branch is the pull branch
+    assert "sdbkp_dense_hop" in text
+    assert ("sdbkp_bit_hop" in text) == (rows <= bitprop.BIT_B_MAX)
+    assert f"pred[{rows * cg.type_sizes['namespace']}]" in text
 
 
 def test_mesh_fixpoint_compiles_and_joins_as_int32(topo, one_chip,

@@ -15,6 +15,7 @@ import time
 import numpy as np
 import pytest
 
+from fusing import warm
 from spicedb_kubeapi_proxy_tpu.engine import (
     CheckItem,
     Engine,
@@ -91,7 +92,7 @@ def test_repeat_lookup_zero_dispatches():
 
 def test_repeat_lookup_zero_dispatches_with_batcher():
     e = build()
-    e.enable_lookup_batching(window=0.005)
+    warm(e, "ns")
     e.lookup_resources_mask("ns", "view", "user", "u1")
     before = lookups_total()
     batches = metrics.counter("engine_lookup_batches_total").value

@@ -585,10 +585,11 @@ def test_remote_watch_pump_restarts_after_host_restart():
 
 
 def test_remote_lookups_fuse_across_connections():
-    """An engine host with lookup batching on (--lookup-batch-window):
-    concurrent lookup_mask requests from SEPARATE proxy connections fuse
-    into shared device dispatches, and per-subject results stay
+    """An engine host fuses, with its default flags: lookup_mask
+    requests from SEPARATE proxy connections that wait beside each other
+    leave in one device dispatch, and per-subject results stay
     correct."""
+    from fusing import hold, release, warm
     from spicedb_kubeapi_proxy_tpu.utils.metrics import metrics
 
     e = Engine()
@@ -597,8 +598,7 @@ def test_remote_lookups_fuse_across_connections():
             for i, u in enumerate(users)]
     e.write_relationships(
         [WriteOp("touch", parse_relationship(r)) for r in rels])
-    e.lookup_resources_mask("namespace", "view", "user", users[0])  # warm
-    e.enable_lookup_batching(window=0.02)
+    warm(e, "namespace")
 
     async def go():
         server = EngineServer(e)
@@ -613,21 +613,18 @@ def test_remote_lookups_fuse_across_connections():
                     "namespace", "view", "user", u)
                 return set(ids)
 
-            for _ in range(5):  # burst can straggle under load: retry
-                results = await asyncio.gather(*(
-                    asyncio.to_thread(one, r, u)
-                    for r, u in zip(remotes, users)))
-                fused = metrics.counter(
-                    "engine_lookup_batches_total").value - b0
-                issued = metrics.counter(
-                    "engine_lookups_total").value - l0
-                if 0 < fused < issued:
-                    break
-                b0, l0 = (metrics.counter(
-                    "engine_lookup_batches_total").value,
-                    metrics.counter("engine_lookups_total").value)
-            else:
-                raise AssertionError("no cross-connection fusion observed")
+            # the host's batcher held as by a dispatch being enqueued,
+            # until the six connections' lookups wait beside each other
+            hold(e._batcher)
+            burst = asyncio.gather(*(
+                asyncio.to_thread(one, r, u)
+                for r, u in zip(remotes, users)))
+            await asyncio.to_thread(release, e._batcher, len(users))
+            results = await burst
+            assert metrics.counter(
+                "engine_lookup_batches_total").value - b0 == 1
+            assert metrics.counter(
+                "engine_lookups_total").value - l0 == len(users)
             for i, (u, got) in enumerate(zip(users, results)):
                 assert got == {f"ns{i}"}, (u, got)
         finally:

@@ -10,10 +10,14 @@ read back from the persistent cache in ``jax_compile_cache_hits_total``,
 so a scrape can attribute a p99 spike to compilation instead of
 guessing, and tell a cold checkout from a warm one.
 
-The interpreter's garbage collector stops every thread of the serving
-process while it runs: ``install_gc_hook`` times each collection as
-stage ``gc`` (``process_gc_seconds{generation=...}`` and the profiler
-annotation ``sdbkp:gc``, obs/trace.py).
+The interpreter's garbage collector runs under the interpreter lock,
+which it gives up only where it frees an object that does (a device
+array): ``install_gc_hook`` times each collection as stage ``gc``
+(``process_gc_seconds{generation=...}`` and the profiler annotation
+``sdbkp:gc``, obs/trace.py), on the collecting thread's wall clock, its
+waits to get the lock back included. ``settle_collector`` keeps it off
+the heap that start-up built, makes it come rarely, and gives it a
+thread of its own, so that no request's thread sits in a collection.
 
 The other profiling hooks live where the numbers are produced:
 CSR nnz / slot-space gauges at graph compile (engine/engine.py
@@ -26,6 +30,7 @@ from __future__ import annotations
 
 import gc
 import threading
+import time
 
 from ..utils.metrics import metrics
 from .trace import Stage
@@ -33,6 +38,21 @@ from .trace import Stage
 _install_lock = threading.Lock()
 _installed = False
 _gc_installed = False
+_collector_settled = False
+
+# The collector's thread takes the young generation once it holds this
+# many net container allocations (the interpreter's own threshold is
+# 700, which under load means dozens of collections a second, each over
+# the requests in flight), every tenth time the middle one with it,
+# every hundredth everything that is not frozen. What bounds it from
+# above is what a request's garbage holds until then: device arrays, so
+# peak device memory, and the length of that one collection. Constants
+# from one sweep on the chip (PERF.md section 6, PR 34), not options.
+GC_YOUNG_AFTER = 10_000
+GC_POLL_S = 0.05
+# The interpreter's own thresholds while that thread collects: a
+# backstop, reached only if the thread falls ten collections behind.
+GC_BACKSTOP = (10 * GC_YOUNG_AFTER, 10, 10)
 
 
 def _on_event_duration(event: str, duration: float, **kw) -> None:
@@ -100,3 +120,46 @@ def install_gc_hook() -> None:
             open_stage.pop().finish()
 
     gc.callbacks.append(on_gc)
+
+
+def settle_collector() -> None:
+    """Where the process begins to serve (proxy/server.py ``start``),
+    once per process: collect, freeze what is left — the store, the
+    compiled graph, the rules, jax and every imported module — into the
+    permanent generation, which no later collection walks, and hand the
+    collecting to a thread of its own (``_collect``). An embedding
+    application's live objects are frozen with the proxy's; the
+    collection before the freeze is what finds the garbage among them.
+    Whatever is made later (the programs the first requests compile, a
+    graph recompiled while serving) stays ordinary, and a second
+    ``start`` in the process does nothing: what is alive then are
+    requests in flight."""
+    global _collector_settled
+    with _install_lock:
+        if _collector_settled:
+            return
+        _collector_settled = True
+    gc.collect()
+    gc.freeze()
+    metrics.gauge("process_gc_frozen_objects").set(gc.get_freeze_count())
+    gc.set_threshold(*GC_BACKSTOP)
+    threading.Thread(target=_collect, daemon=True,
+                     name="sdbkp-collector").start()
+
+
+def _collect() -> None:
+    """The collector's thread. A collection lasts several times its own
+    CPU time under load: each device array it frees gives the
+    interpreter lock up, and the collecting thread queues for it again
+    behind every busy worker. On the thread whose allocation happened
+    to cross the threshold that is a request (or, on the event loop,
+    every request) standing still for up to seconds; here it is nobody.
+    It collects for as long as the thresholds are the ones it was
+    started under: whoever sets others takes the collector back."""
+    young = 0
+    while gc.get_threshold() == GC_BACKSTOP:
+        time.sleep(GC_POLL_S)
+        if gc.get_count()[0] < GC_YOUNG_AFTER:
+            continue
+        young += 1
+        gc.collect(2 if young % 100 == 0 else 1 if young % 10 == 0 else 0)

@@ -33,6 +33,22 @@ if _SANITIZE:
 import pytest  # noqa: E402
 
 
+@pytest.fixture(autouse=True)
+def _collector_as_found():
+    """A process that begins to serve freezes its heap and hands the
+    collecting to a thread of its own, once (obs/profile.py). A test
+    process is no serving process: what a test's server did to the
+    collector is undone after the test, so no other test inherits it
+    (the thread ends by itself once the thresholds are not its own)."""
+    import gc
+
+    thresholds = gc.get_threshold()
+    yield
+    if gc.get_freeze_count():
+        gc.unfreeze()
+    gc.set_threshold(*thresholds)
+
+
 @pytest.fixture(scope="session", autouse=True)
 def _proxy_sanitize_gate():
     """With PROXY_SANITIZE=1: after the whole session, report advisory

@@ -11,10 +11,12 @@ the client.
 """
 
 import asyncio
+import gc
 import json
 import os
 import re
 import time
+import weakref
 
 import pytest
 
@@ -927,8 +929,6 @@ def test_served_reads_feed_stage_histograms_with_tracing_off(tmp_path):
 
 
 def test_gc_hook_times_collections_by_generation():
-    import gc
-
     from spicedb_kubeapi_proxy_tpu.obs.profile import install_gc_hook
 
     install_gc_hook()
@@ -936,6 +936,115 @@ def test_gc_hook_times_collections_by_generation():
     n0 = _hist_count("process_gc_seconds", generation=2)
     gc.collect()
     assert _hist_count("process_gc_seconds", generation=2) == n0 + 1
+
+
+def _collector_threads():
+    import threading
+
+    return [t for t in threading.enumerate() if t.name == "sdbkp-collector"]
+
+
+@pytest.fixture
+def unserved_process(monkeypatch):
+    """The collector's policy is applied once a process, where it begins
+    to serve: a test of it needs a process that has not served yet.
+    tests/conftest.py puts heap and thresholds back after every test,
+    which also ends the collector's thread."""
+    from spicedb_kubeapi_proxy_tpu.obs import profile
+
+    monkeypatch.setattr(profile, "_collector_settled", False)
+    for thread in _collector_threads():  # an earlier test's, on its way out
+        thread.join(10)
+    return profile
+
+
+async def _start_and_stop():
+    from spicedb_kubeapi_proxy_tpu.proxy.server import Server
+
+    server = Server(None)
+    await server.start()
+    await server.stop()
+
+
+def test_start_freezes_the_heap_that_start_up_built(unserved_process):
+    assert gc.get_freeze_count() == 0
+    asyncio.run(_start_and_stop())
+    # the gauge is the count at the freeze; a frozen object whose last
+    # reference goes is freed like any other and leaves the count
+    assert 0 < gc.get_freeze_count() \
+        <= metrics.gauge("process_gc_frozen_objects").value
+
+
+def test_a_second_start_freezes_nothing_more(unserved_process):
+    """What is alive at a later ``start`` are requests in flight: they
+    stay ordinary objects, with their garbage."""
+    asyncio.run(_start_and_stop())
+    frozen = metrics.gauge("process_gc_frozen_objects").value
+    in_flight = [[] for _ in range(1000)]
+    asyncio.run(_start_and_stop())
+    assert gc.get_freeze_count() <= frozen
+    assert metrics.gauge("process_gc_frozen_objects").value == frozen
+    ordinary = {id(o) for o in gc.get_objects()}  # lists no frozen object
+    assert all(id(x) in ordinary for x in in_flight)
+
+
+def test_start_hands_the_collecting_to_one_thread(unserved_process):
+    """The interpreter's own thresholds become the backstop and one
+    thread collects, however often ``start`` is asked; it ends when the
+    thresholds are set back (as tests/conftest.py does after this)."""
+    before = gc.get_threshold()
+    assert before != unserved_process.GC_BACKSTOP
+    assert _collector_threads() == []
+    asyncio.run(_start_and_stop())
+    asyncio.run(_start_and_stop())
+    assert gc.get_threshold() == unserved_process.GC_BACKSTOP
+    (thread,) = _collector_threads()
+    gc.set_threshold(*before)
+    thread.join(10)
+    assert not thread.is_alive()
+
+
+def test_garbage_is_collected_on_the_collectors_thread(unserved_process):
+    import threading
+
+    class Node:
+        pass
+
+    collected_on = set()
+
+    def note(phase, info):
+        if phase == "stop":
+            collected_on.add(threading.current_thread().name)
+
+    asyncio.run(_start_and_stop())
+    gc.callbacks.append(note)
+    try:
+        gone = None
+        for _ in range(unserved_process.GC_YOUNG_AFTER + 1):
+            a, b = Node(), Node()
+            a.other, b.other = b, a
+            gone = gone or weakref.ref(a)
+        del a, b
+        deadline = time.monotonic() + 10
+        while gone() is not None and time.monotonic() < deadline:
+            time.sleep(0.01)
+    finally:
+        gc.callbacks.remove(note)
+    assert gone() is None
+    assert collected_on == {"sdbkp-collector"}
+
+
+def test_a_cycle_made_after_the_freeze_is_collected(unserved_process):
+    class Node:
+        pass
+
+    asyncio.run(_start_and_stop())
+    a, b = Node(), Node()
+    a.other, b.other = b, a
+    gone = weakref.ref(a)
+    del a, b
+    assert gc.collect() >= 2
+    assert gone() is None
 
 
 def test_dispatch_batch_rows_counts_subject_rows_not_slots():

@@ -540,10 +540,11 @@ async def _authorized(req: ProxyRequest, deps: AuthzDeps, info, user,
             resp = filter_response(resp, allowed, input)
     if run_postfilter:
         try:
-            with tracer.span("postfilter"):
-                resp = await tracer.to_thread(
-                    filter_list_response, deps.engine, post_filters,
-                    input, resp, caveat_ctx)
+            # stage ``postfilter`` opens in the worker, around the work:
+            # the two hand-overs are ``executor_wait`` / ``loop_wait``
+            resp = await tracer.to_thread(
+                filter_list_response, deps.engine, post_filters,
+                input, resp, caveat_ctx)
         except ExprError as e:
             return kube_status(401, f"postfilter: {e}")
 

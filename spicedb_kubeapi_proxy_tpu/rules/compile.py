@@ -9,7 +9,7 @@ relationship strings, and `if` conditions compile to boolean programs.
 from __future__ import annotations
 
 from dataclasses import dataclass, field
-from typing import Optional
+from typing import Callable, Optional
 
 from ..models.tuples import TupleError, parse_rel_fields
 from .expr import CompiledExpr, ExprError, compile_expr, compile_template
@@ -45,11 +45,39 @@ class ResolvedRel:
         return s
 
 
+# the template-data roots that differ from one object of a listed answer to
+# the next (authz/postfilter.py rewrites them per object); every other root
+# (headers, request, user, body) is the request's own. ``this`` is the whole
+# map, so a read of it counts as a read of ``object``.
+ITEM_ROOTS = frozenset({"name", "namespace", "namespacedName", "resourceId",
+                        "object", "metadata", "this"})
+
+
+_REL_FIELDS = ("resource_type", "resource_id", "resource_relation",
+               "subject_type", "subject_id", "subject_relation")
+# a relationship's six fields, in ResolvedRel's order
+RelFields = tuple[str, str, str, str, str, str]
+
+
 class RelationshipExpr:
     """A compiled expression producing relationships from a ResolveInput
     (reference RelationshipExpr interface, rules.go:148-152)."""
 
+    @property
+    def refs(self) -> frozenset:
+        """Root data-map identifiers the expression may read: the union
+        of its compiled expressions' (``CompiledExpr.refs``)."""
+        raise NotImplementedError
+
     def generate(self, input: ResolveInput) -> list[ResolvedRel]:
+        raise NotImplementedError
+
+    def per_list(self, data: dict) -> Callable[[], list[RelFields]]:
+        """A resolver over ONE template-data map whose ``ITEM_ROOTS``
+        entries the caller rewrites between calls (the objects of one
+        list): what reads none of them is evaluated here, once; a call
+        evaluates the rest against ``data`` as it then stands and
+        returns the fields of what ``generate`` would for that input."""
         raise NotImplementedError
 
 
@@ -65,25 +93,39 @@ class RelExpr(RelationshipExpr):
     subject_id: CompiledExpr
     subject_relation: Optional[CompiledExpr] = None
 
+    @property
+    def refs(self) -> frozenset:
+        exprs = [getattr(self, f_) for f_ in _REL_FIELDS]
+        return frozenset().union(*(e.refs for e in exprs if e is not None))
+
     def generate(self, input: ResolveInput) -> list[ResolvedRel]:
-        data = input.template_data()
-        try:
-            rel = ResolvedRel(
-                self.resource_type.evaluate_str(data),
-                self.resource_id.evaluate_str(data),
-                self.resource_relation.evaluate_str(data),
-                self.subject_type.evaluate_str(data),
-                self.subject_id.evaluate_str(data),
-                (self.subject_relation.evaluate_str(data)
-                 if self.subject_relation else ""),
-            )
-        except ExprError as e:
-            raise ExprError(f"resolving relationship: {e}") from None
-        for f_ in ("resource_type", "resource_id", "resource_relation",
-                   "subject_type", "subject_id"):
-            if not getattr(rel, f_):
-                raise ExprError(f"relationship field {f_} resolved empty")
-        return [rel]
+        return [ResolvedRel(*f)
+                for f in self.per_list(input.template_data())()]
+
+    def per_list(self, data: dict) -> Callable[[], list[RelFields]]:
+        def evaluate(name: str, expr: Optional[CompiledExpr]) -> str:
+            if expr is None:  # no subject relation
+                return ""
+            try:
+                v = expr.evaluate_str(data)
+            except ExprError as e:
+                raise ExprError(f"resolving relationship: {e}") from None
+            if not v and name != "subject_relation":
+                raise ExprError(f"relationship field {name} resolved empty")
+            return v
+
+        fields = [(f_, getattr(self, f_)) for f_ in _REL_FIELDS]
+        per_item = [i for i, (_, e) in enumerate(fields)
+                    if e is not None and e.refs & ITEM_ROOTS]
+        values = [None if i in per_item else evaluate(*f)
+                  for i, f in enumerate(fields)]
+
+        def resolve() -> list[RelFields]:
+            for i in per_item:
+                values[i] = evaluate(*fields[i])
+            return [tuple(values)]
+
+        return resolve
 
 
 @dataclass
@@ -93,14 +135,26 @@ class TupleSetExpr(RelationshipExpr):
 
     expr: CompiledExpr
 
+    @property
+    def refs(self) -> frozenset:
+        return self.expr.refs
+
     def generate(self, input: ResolveInput) -> list[ResolvedRel]:
-        data = input.template_data()
+        return [ResolvedRel(*f) for f in self._resolve(input.template_data())]
+
+    def per_list(self, data: dict) -> Callable[[], list[RelFields]]:
+        if self.refs & ITEM_ROOTS:
+            return lambda: self._resolve(data)
+        rels = self._resolve(data)
+        return lambda: rels
+
+    def _resolve(self, data: dict) -> list[RelFields]:
         v = self.expr.evaluate(data)
         if not isinstance(v, list):
             raise ExprError(
                 f"tupleSet expression must evaluate to a list of relationship "
                 f"strings, got {type(v).__name__}")
-        out: list[ResolvedRel] = []
+        out: list[RelFields] = []
         for i, item in enumerate(v):
             if not isinstance(item, str):
                 raise ExprError(f"tupleSet item {i} is not a string")
@@ -108,7 +162,7 @@ class TupleSetExpr(RelationshipExpr):
                 f_ = parse_rel_fields(item)
             except TupleError as e:
                 raise ExprError(f"tupleSet item {i}: {e}") from None
-            out.append(ResolvedRel(
+            out.append((
                 f_["resource_type"], f_["resource_id"], f_["relation"],
                 f_["subject_type"], f_["subject_id"],
                 f_["subject_relation"] or "",

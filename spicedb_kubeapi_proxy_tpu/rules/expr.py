@@ -490,6 +490,9 @@ class _Parser:
                 return self.parse_if()
             if t.value == "this":
                 self.advance()
+                # outside a lambda ``this`` is the whole data map: a read
+                # of every root, recorded as such
+                self.refs.add("this")
                 return lambda env: env.this
             name = self.advance().value
             if self.cur.kind == "op" and self.cur.value == "(":

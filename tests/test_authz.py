@@ -1200,6 +1200,154 @@ def test_watch_recomputes_shared_across_watchers():
     run(go())
 
 
+def _postfilter_rule(resource: str, *checks: str) -> str:
+    return ("apiVersion: authzed.com/v1alpha1\nkind: ProxyRule\n"
+            "metadata:\n  name: listed\nmatch:\n- apiVersion: v1\n"
+            f"  resource: {resource}\n  verbs: [list]\npostfilter:\n"
+            + "".join(f"- checkPermissionTemplate:\n    {c}\n"
+                      for c in checks))
+
+
+BY_NAMESPACE = 'tpl: "namespace:{{namespace}}#view@user:{{user.name}}"'
+BY_NSNAME = 'tpl: "pod:{{namespacedName}}#view@user:{{user.name}}"'
+LISTED_PODS = [("ns1", "a", "x"), ("ns1", "b", "y"), ("ns2", "c", "x"),
+               ("ns2", "d", "y"), ("ns3", "e", "x"), ("ns1", "f", "y")]
+GRANTS = ["namespace:ns1#viewer@user:alice", "namespace:ns3#viewer@user:alice",
+          "namespace:x#viewer@user:alice", "pod:ns1/a#viewer@user:alice",
+          "pod:ns2/c#viewer@user:alice", "pod:ns1/f#viewer@user:alice",
+          "pod:b#viewer@user:alice", "pod:e#viewer@user:alice"]
+
+
+def _listed(kind: str = "PodList", objs=None) -> dict:
+    objs = objs if objs is not None else [
+        {"kind": "Pod", "metadata": {"name": n, "namespace": ns,
+                                     "labels": {"team": team}}}
+        for ns, n, team in LISTED_PODS]
+    if kind == "Table":
+        return {"kind": "Table", "rows": [
+            {"cells": [o["metadata"]["name"]], "object": o} for o in objs]}
+    return {"kind": kind, "items": objs}
+
+
+def _plain_postfilter(engine, post_filters, input, doc):
+    """The plain per-object resolver: every object its own ResolveInput,
+    every template of every rule resolved from it, nothing shared between
+    objects. -> (status, names kept, the checks it asks)."""
+    import dataclasses
+    from spicedb_kubeapi_proxy_tpu.rules.expr import ExprError
+
+    table = doc["kind"] == "Table"
+    objs = [r["object"] for r in doc["rows"]] if table else doc["items"]
+    per_object = []
+    for obj in objs:
+        meta = obj.get("metadata") or {}
+        name, ns = meta.get("name") or "", meta.get("namespace") or ""
+        if input.request.resource == "namespaces":
+            ns = ""
+        one = dataclasses.replace(
+            input, name=name, namespace=ns, object=obj,
+            namespaced_name=f"{ns}/{name}" if ns else name)
+        try:
+            per_object.append([
+                CheckItem(r.resource_type, r.resource_id,
+                          r.resource_relation, r.subject_type, r.subject_id,
+                          r.subject_relation or None)
+                for pf in post_filters for r in pf.rel.generate(one)])
+        except ExprError:
+            return 401, [], set()
+    flat = [c for cs in per_object for c in cs]
+    verdict = dict(zip(flat, engine.check_bulk(flat)))
+    return 200, [o["metadata"]["name"] for o, cs in zip(objs, per_object)
+                 if all(verdict[c] for c in cs)], set(flat)
+
+
+@pytest.mark.parametrize("resource,checks,doc,resolved,n_checks,kept", [
+    ("pods", [BY_NAMESPACE], _listed(), 3, 3, ["a", "b", "e", "f"]),
+    ("pods", ['tpl: "pod:{{name}}#view@user:{{user.name}}"'], _listed(),
+     6, 6, ["b", "e"]),
+    ("pods", [BY_NSNAME], _listed(), 6, 6, ["a", "c", "f"]),
+    ("pods", ['tpl: "namespace:{{object.metadata.labels.team}}#view'
+              '@user:{{user.name}}"'], _listed(), 6, 2, ["a", "c", "e"]),
+    ("pods", ['tpl: "namespace:{{this.namespace}}#view@user:{{user.name}}"'],
+     _listed(), 6, 3, ["a", "b", "e", "f"]),
+    ("pods", ["tupleSet: '[\"namespace:\" + namespace + \"#view@user:\" + "
+              "user.name, \"namespace:ns1#view@user:\" + user.name]'"],
+     _listed(), 3, 3, ["a", "b", "e", "f"]),
+    ("pods", ["tupleSet: '[\"namespace:ns1#view@user:\" + user.name]'"],
+     _listed(), 1, 1, ["a", "b", "c", "d", "e", "f"]),
+    ("pods", [BY_NAMESPACE, BY_NSNAME], _listed(), 9, 9, ["a", "f"]),
+    ("pods", [BY_NAMESPACE], _listed("Table"), 3, 3, ["a", "b", "e", "f"]),
+    ("namespaces",
+     ['tpl: "namespace:{{namespacedName}}#view@user:{{user.name}}"'],
+     _listed("NamespaceList", [
+         {"kind": "Namespace", "metadata": {"name": n, "namespace": n}}
+         for n in ("ns1", "ns2", "ns3")]), 3, 3, ["ns1", "ns3"]),
+    ("pods", [BY_NAMESPACE], _listed(objs=[
+        {"metadata": {"name": "a", "namespace": "ns1"}},
+        {"metadata": {"name": "g"}}]), None, 0, None),
+    ("pods", [BY_NAMESPACE], _listed(objs=[]), 0, 0, []),
+], ids=["namespace", "name", "namespacedName", "object-labels", "this",
+        "tupleset", "tupleset-constant", "two-rules", "table",
+        "namespaces-list", "empty-namespace", "no-objects"])
+def test_postfilter_asks_what_a_per_object_resolver_asks(
+        resource, checks, doc, resolved, n_checks, kept):
+    """A postfiltered list keeps the objects, and asks the set of checks,
+    that resolving every object on its own does, whatever its rule reads;
+    it resolves once per distinct value of what the rule reads (per
+    object for a rule that reads the object itself) and asks each
+    distinct check once."""
+    from spicedb_kubeapi_proxy_tpu.engine import WriteOp
+    from spicedb_kubeapi_proxy_tpu.models.tuples import parse_relationship
+    from spicedb_kubeapi_proxy_tpu.proxy.types import json_response
+    from spicedb_kubeapi_proxy_tpu.rules import RequestMeta
+    from spicedb_kubeapi_proxy_tpu.rules.input import ResolveInput
+    from spicedb_kubeapi_proxy_tpu.utils.metrics import metrics
+
+    async def go():
+        env = Env(rules_yaml=_postfilter_rule(resource, *checks))
+        env.engine.write_relationships(
+            [WriteOp("touch", parse_relationship(g)) for g in GRANTS])
+
+        async def upstream(req):
+            return json_response(200, doc)
+        env.deps.upstream = upstream
+        asked = []
+        check_bulk = env.engine.check_bulk
+
+        def recorded(items, **kw):
+            asked.append(list(items))
+            return check_bulk(items, **kw)
+
+        info = parse_request_info("GET", f"/api/v1/{resource}", {})
+        rules = env.deps.matcher.match(RequestMeta.from_request(info))
+        status, plain_kept, plain_checks = _plain_postfilter(
+            env.engine, [p for r in rules for p in r.post_filters],
+            ResolveInput.create(info, UserInfo(name="alice"), headers={}),
+            doc)
+        counters = [metrics.counter("proxy_postfilter_items_total"),
+                    metrics.counter("proxy_postfilter_resolved_total")]
+        before = [c.value for c in counters]
+        env.engine.check_bulk = recorded
+        resp = await env.request("GET", f"/api/v1/{resource}")
+        assert resp.status == status, resp.body
+        if kept is None:  # refused whole: nothing dispatched, nothing kept
+            assert status == 401 and asked == []
+            assert b"resolved empty" in resp.body
+            assert [c.value for c in counters] == before
+            return
+        out = json.loads(resp.body)
+        objs = ([r["object"] for r in out["rows"]] if doc["kind"] == "Table"
+                else out["items"])
+        assert [o["metadata"]["name"] for o in objs] == plain_kept == kept
+        (bulk,) = asked  # one bulk check a list
+        assert len(bulk) == len(set(bulk)) == n_checks
+        assert set(bulk) == plain_checks
+        n = len(doc["rows" if doc["kind"] == "Table" else "items"])
+        assert [c.value - b for c, b in zip(counters, before)] == [
+            n, resolved]
+    run(go())
+
+
 def test_postfilter_proto_response_clean_401_not_500():
     """A hand-crafted proto Accept on a postfilter route is rewritten to
     JSON upstream; an upstream that returns protobuf ANYWAY must produce

@@ -35,6 +35,7 @@ STAGES = {"postfilter": "proxy_postfilter_seconds",
           "postfilter_resolve": "proxy_postfilter_resolve_seconds",
           "postfilter_write": "proxy_postfilter_write_seconds"}
 COUNTERS = ("proxy_postfilter_items_total", "proxy_postfilter_kept_total",
+            "proxy_postfilter_resolved_total",
             "engine_checks_total", "engine_checks_distinct_total")
 PROTOBUF = "application/vnd.kubernetes.protobuf,application/json"
 TABLE = "application/json;as=Table;v=v1;g=meta.k8s.io,application/json"
@@ -63,6 +64,9 @@ class Scoped:
                                self.dep.config["control"]["stale_share"])
         self.expect = _bench_module("ops/list_scoped").expect
         self.users = self.dep.names("user")
+        # namespaces that hold a service: a list's distinct checks
+        self.held = len({n.split("/")[0]
+                         for n in self.dep.names("service").tolist()})
 
     def request(self, user: int, typ: str = "service") -> dict:
         return {"key": "namespace#view", "user_idx": user, "type": typ,
@@ -187,7 +191,7 @@ def test_served_lists_name_what_the_reference_and_the_oracle_name(
     reference and by the oracle, whatever shape the upstream's answer
     has; a protobuf ``Accept`` reaches the upstream as JSON. A list is
     one observation of each stage of the path and one bulk check of as
-    many items as the upstream has objects."""
+    many checks as namespaces hold one of the upstream's objects."""
     dep = scoped.dep
     n_objects = dep.count("service")
     users = list(range(0, dep.count("user"), dep.count("user") // LISTS))
@@ -209,12 +213,15 @@ def test_served_lists_name_what_the_reference_and_the_oracle_name(
         assert delta[hist] == LISTS, hist
     assert delta["proxy_postfilter_items_total"] == LISTS * n_objects
     assert delta["proxy_postfilter_kept_total"] == kept
-    # every user is new to the cache: every item is dispatched
-    assert delta["engine_checks_total"] == LISTS * n_objects
-    distinct = delta["engine_checks_distinct_total"]
-    # one question a namespace that holds a service, a list
-    held = len({n.split("/")[0] for n in dep.names("service").tolist()})
-    assert distinct == LISTS * held and distinct < LISTS * n_objects
+    # the rule reads the object's namespace and the user: it is resolved
+    # once a namespace that holds a service, and that is one check. Every
+    # user is new to the cache, so every check is dispatched, and each is
+    # a question of its own
+    held = scoped.held
+    assert held < n_objects
+    assert delta["proxy_postfilter_resolved_total"] == LISTS * held
+    assert delta["engine_checks_total"] == LISTS * held
+    assert delta["engine_checks_distinct_total"] == LISTS * held
     spans = [s["name"] for t in tracer.recent() for s in t["spans"]]
     for stage in STAGES:
         assert spans.count(stage) == LISTS, stage
@@ -233,8 +240,10 @@ def test_a_list_asked_again_is_answered_from_the_cache(scoped, tmp_path):
     assert names_of(got[0][1]) == names_of(got[1][1]) == scoped.seen(7)
     delta = moved(before)
     assert delta["engine_bulk_cache_seconds"] == 2
-    assert delta["engine_checks_total"] == dep.count("service")
+    assert delta["engine_checks_total"] == scoped.held
     assert delta["proxy_postfilter_items_total"] == 2 * dep.count("service")
+    # the templates are resolved again: only verdicts are kept between lists
+    assert delta["proxy_postfilter_resolved_total"] == 2 * scoped.held
 
 
 def test_an_object_with_no_namespace_fails_the_whole_list(scoped, tmp_path):
@@ -259,6 +268,7 @@ def test_an_object_with_no_namespace_fails_the_whole_list(scoped, tmp_path):
     delta = moved(before)
     assert delta["proxy_postfilter_seconds"] == 1
     assert delta["proxy_postfilter_kept_total"] == 0
+    assert delta["proxy_postfilter_resolved_total"] == 0
     assert delta["engine_checks_total"] == 0
 
 

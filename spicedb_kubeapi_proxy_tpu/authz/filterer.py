@@ -264,5 +264,6 @@ def filter_response(resp: ProxyResponse, allowed: AllowedSet,
     the caller chose by :func:`runs_off_loop`: the stage begins and ends
     around the filter alone, so a hop to a worker is not in it."""
     with tracer.stage("body_filter",
-                      metrics.histogram("proxy_body_filter_seconds")):
+                      metrics.histogram("proxy_body_filter_seconds"),
+                      metrics.counter("proxy_body_filter_cpu_seconds_total")):
         return apply_filter(resp, allowed, input)

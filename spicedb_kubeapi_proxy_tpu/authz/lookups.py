@@ -148,6 +148,7 @@ def run_prefilter_sync(engine: Engine, pf: PreFilter,
         )
     with tracer.stage("prefilter_map",
                       metrics.histogram("proxy_prefilter_map_seconds"),
+                      metrics.counter("proxy_prefilter_map_cpu_seconds_total"),
                       ids=len(ids)):
         return _map_ids(pf, input, ids, strict)
 

@@ -105,7 +105,9 @@ def _filter(engine: Engine, post_filters: list[PostFilter],
             input: ResolveInput, resp: ProxyResponse,
             context) -> ProxyResponse:
     with tracer.stage("postfilter_parse",
-                      metrics.histogram("proxy_postfilter_parse_seconds")):
+                      metrics.histogram("proxy_postfilter_parse_seconds"),
+                      metrics.counter(
+                          "proxy_postfilter_parse_cpu_seconds_total")):
         try:
             doc = json.loads(resp.body)
         except ValueError:
@@ -126,7 +128,9 @@ def _filter(engine: Engine, post_filters: list[PostFilter],
     checks: dict[RelFields, int] = {}
     rules = []
     with tracer.stage("postfilter_resolve",
-                      metrics.histogram("proxy_postfilter_resolve_seconds")):
+                      metrics.histogram("proxy_postfilter_resolve_seconds"),
+                      metrics.counter(
+                          "proxy_postfilter_resolve_cpu_seconds_total")):
         if objs:  # no object, no template resolved: nothing to refuse
             metas = [obj.get("metadata") or {} for obj in objs]
             names = [meta.get("name") or "" for meta in metas]
@@ -141,7 +145,9 @@ def _filter(engine: Engine, post_filters: list[PostFilter],
     results = (engine.check_bulk(items, context=context) if context
                else engine.check_bulk(items))
     with tracer.stage("postfilter_write",
-                      metrics.histogram("proxy_postfilter_write_seconds")):
+                      metrics.histogram("proxy_postfilter_write_seconds"),
+                      metrics.counter(
+                          "proxy_postfilter_write_cpu_seconds_total")):
         keep = [True] * len(objs)
         for asked, resolution in rules:
             ok = [all(map(results.__getitem__, cs)) for cs in asked]

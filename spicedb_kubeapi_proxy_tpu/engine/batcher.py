@@ -322,7 +322,8 @@ class LookupBatcher:
         off, n = cg.offset_of(rt, perm), cg.type_sizes[rt]
         interner = objs[rt]
         with tracer.stage("engine_encode",
-                          metrics.histogram("engine_encode_seconds")):
+                          metrics.histogram("engine_encode_seconds"),
+                          metrics.counter("engine_encode_cpu_seconds_total")):
             seeds = np.full((FUSED_ROWS, 2), cg.M, dtype=np.int32)
             for i, item in enumerate(chunk):
                 _rt, _perm, st, sid, srl = item.args

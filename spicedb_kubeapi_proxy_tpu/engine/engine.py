@@ -922,9 +922,14 @@ class Engine:
                   else None)
         # stage ``bulk_cache``: the cache's two passes over the items (a
         # key and a probe each before the dispatch, a put each after it),
-        # a span each and ONE observation a call, of their sum
+        # a span each and ONE observation a call, of their sum; each
+        # pass runs on one thread, whose CPU seconds go to the counter
+        # (for the one call in CPU_EVERY that reads them: obs/trace.py)
         spent = metrics.histogram("engine_bulk_cache_seconds")
+        cpu = metrics.counter("engine_bulk_cache_cpu_seconds_total")
+        cpu_weight = tracer.cpu_weight()
         t0 = time.perf_counter()
+        c0 = time.thread_time() if cpu_weight else 0.0
         with tracer.span("bulk_cache"):
             keys = [check_key(cg.revision, it, digest) for it in items]
             out: list = [None] * len(items)
@@ -935,6 +940,8 @@ class Engine:
                     miss_idx.append(i)
                 else:
                     out[i] = v
+        if cpu_weight:
+            cpu.inc((time.thread_time() - c0) * cpu_weight)
         probe_s = time.perf_counter() - t0
         if not miss_idx:
             spent.observe(probe_s)
@@ -945,12 +952,15 @@ class Engine:
         def fin(_):
             got = inner.result()
             t1 = time.perf_counter()
+            c1 = time.thread_time() if cpu_weight else 0.0
             with tracer.span("bulk_cache"):
                 deadline = self._cache_deadline(cg, now0, context)
                 for j, i in enumerate(miss_idx):
                     v = bool(got[j])
                     cache.put(keys[i], v, deadline, 0, now0)
                     out[i] = v
+            if cpu_weight:
+                cpu.inc((time.thread_time() - c1) * cpu_weight)
             spent.observe(probe_s + time.perf_counter() - t1)
             return list(out)
 
@@ -993,7 +1003,9 @@ class Engine:
         distinct = 0
         for s in range(0, n, chunk):
             with tracer.stage("engine_encode",
-                              metrics.histogram("engine_encode_seconds")):
+                              metrics.histogram("engine_encode_seconds"),
+                              metrics.counter(
+                                  "engine_encode_cpu_seconds_total")):
                 seeds, q_slots, q_batch = self._encode_checks(
                     cg, objs, items[s:s + chunk])
             futs.append(backend.query_async(seeds, q_slots, q_batch,
@@ -1054,7 +1066,9 @@ class Engine:
             resource_type, permission, subject_type, subject_id,
             subject_relation, now=now, context=context)
         with tracer.stage("mask_to_ids",
-                          metrics.histogram("engine_mask_to_ids_seconds")):
+                          metrics.histogram("engine_mask_to_ids_seconds"),
+                          metrics.counter(
+                              "engine_mask_to_ids_cpu_seconds_total")):
             return mask_to_ids(mask, interner)
 
     def lookup_subjects(self, resource_type: str, resource_id: str,
@@ -1280,7 +1294,8 @@ class Engine:
             metrics.counter("engine_lookups_total").inc()
             return EngineFuture(None, lambda _: (None, None))
         with tracer.stage("engine_encode",
-                          metrics.histogram("engine_encode_seconds")):
+                          metrics.histogram("engine_encode_seconds"),
+                          metrics.counter("engine_encode_cpu_seconds_total")):
             seeds = np.asarray(
                 [cg.encode_subject(subject_type, subject_id,
                                    subject_relation, objs)],

@@ -32,6 +32,18 @@ read); and a
 ``jax.profiler.TraceAnnotation("sdbkp:<name>")``, so that while a
 profiler session runs the stage lies on the device trace's clock.
 Every span carries the annotation; ``stage`` adds the histogram.
+
+A wall clock cannot tell work from waiting for the interpreter lock. A
+stage that starts and ends on one thread with no ``await`` between
+takes ``cpu=metrics.counter("<stage>_cpu_seconds_total")`` too: the
+thread's own CPU clock is read beside the wall clock at both ends and
+the difference added to the counter (the span carries it as
+``cpu_us``). Wall minus CPU of such a stage is the time its thread did
+not run: it waited for the lock or, in native code that holds none,
+for a core. A stage that crosses an ``await`` on the event loop gets
+no ``cpu``: the loop's thread runs other requests meanwhile. The CPU
+clock is a system call where the wall clock is not, so one such stage
+in ``CPU_EVERY``, drawn at random, reads it and counts for them all.
 """
 
 from __future__ import annotations
@@ -49,6 +61,16 @@ from typing import Optional
 from ..utils.metrics import metrics
 
 ANNOTATION_PREFIX = "sdbkp:"
+
+# One ``cpu=`` stage in this many reads its thread's CPU clock, and adds
+# this many times what it read. ``time.thread_time()`` is a system call
+# held under the interpreter lock: 0.3 us on a plain kernel, 6 us and
+# more under a sandboxed one (gVisor, where the benchmark's chips are),
+# and ten of them a list, every list, cost the two cells that the lock
+# binds 1-5% of their requests/s (PERF.md section 6, PR 37). Read one
+# stage in sixteen they cost a sixteenth, and a window's thousands of
+# stages still sum to their CPU. Measured, not an option.
+CPU_EVERY = 16
 
 _FLAG_SAMPLED = 0x01
 
@@ -210,16 +232,27 @@ class Stage:
     registers it by its literal name: the metrics contract). As a
     context manager the span nests what runs inside it; a bare
     ``finish()`` — from any thread, once — leaves it a leaf, for a stage
-    that ends elsewhere than it began. Built by :meth:`Tracer.stage`."""
+    that ends elsewhere than it began. With a ``cpu`` counter (only for
+    a stage that ends on the thread it began on, with no ``await``
+    between) the thread's CPU seconds between the two ends, read inside
+    the wall clock's two readings and so never more than the wall time,
+    are added to it ``cpu_weight`` times over: the stage stands for that
+    many that went unread (:meth:`Tracer.stage` draws which).
+    Built by :meth:`Tracer.stage`."""
 
-    __slots__ = ("_span", "_ann", "_hist", "_t0", "_token")
+    __slots__ = ("_span", "_ann", "_hist", "_t0", "_token", "_cpu", "_c0",
+                 "_cpu_weight")
 
-    def __init__(self, span: Optional[Span], name: str, histogram=None):
+    def __init__(self, span: Optional[Span], name: str, histogram=None,
+                 cpu=None, cpu_weight: int = 1):
         self._span = span
         self._hist = histogram
+        self._cpu = cpu
+        self._cpu_weight = cpu_weight
         self._token = None
         self._ann = _annotation(name)
         self._t0 = time.perf_counter()
+        self._c0 = time.thread_time() if cpu is not None else 0.0
 
     # the span's surface, so a call site reads the same with tracing off
     def set(self, key: str, value) -> None:
@@ -232,6 +265,11 @@ class Stage:
     def finish(self) -> None:
         if self._t0 is None:
             return
+        if self._cpu is not None:
+            used = time.thread_time() - self._c0
+            self._cpu.inc(used * self._cpu_weight)
+            if self._span is not None:
+                self._span.set("cpu_us", int(used * 1e6))
         dt, self._t0 = time.perf_counter() - self._t0, None
         _end_annotation(self._ann)
         if self._hist is not None:
@@ -502,12 +540,16 @@ class Tracer:
                     del self._live[trace.trace_id]
             self._tail_decide(trace, root)
 
-    def stage(self, name: str, histogram=None, **attrs) -> Stage:
+    def stage(self, name: str, histogram=None, cpu=None, **attrs) -> Stage:
         """Start a :class:`Stage`: a child span of whatever is active
         (none when nothing is, or tracing is off), the profiler
         annotation ``sdbkp:<name>`` and, given a ``histogram``
         (``metrics.histogram("<stage>_seconds")`` at the call site), an
-        observation of the stage's seconds at its finish. ``with``
+        observation of the stage's seconds at its finish; given a
+        ``cpu`` counter (``metrics.counter("<stage>_cpu_seconds_total")``,
+        where the stage stays on one thread and crosses no ``await``),
+        the thread's CPU seconds too, for one such stage in
+        ``CPU_EVERY`` (:meth:`cpu_weight`). ``with``
         it where it nests other spans; ``finish()`` it by hand where it
         ends on another thread. Exceptions mark the span AND flag the
         trace as error before propagating."""
@@ -515,7 +557,18 @@ class Tracer:
         span = None
         if cur is not None and self.enabled:
             span = Span(cur[0], cur[1], name, attrs)
-        return Stage(span, name, histogram)
+        weight = self.cpu_weight() if cpu is not None else 0
+        return Stage(span, name, histogram, cpu if weight else None, weight)
+
+    def cpu_weight(self) -> int:
+        """Whether this stage reads its thread's CPU clock: 0 for the
+        ones that go unread, ``CPU_EVERY`` for the one in ``CPU_EVERY``
+        that is read, which adds that many times its reading to its
+        counter. Drawn, not counted off: the stages of a request come in
+        one order, and a fixed stride would always read the same ones.
+        For a stage timed by hand (``bulk_cache``); :meth:`stage` asks
+        by itself."""
+        return CPU_EVERY if self._rand() * CPU_EVERY < 1.0 else 0
 
     def span(self, name: str, **attrs) -> Stage:
         """A stage with no histogram: span + annotation. ``with`` it to

@@ -1343,6 +1343,7 @@ class CompiledGraph:
         # to the device's queue, not when it has run
         with tracer.stage("engine_enqueue",
                           metrics.histogram("engine_enqueue_seconds"),
+                          metrics.counter("engine_enqueue_cpu_seconds_total"),
                           rows=B):
             # seeds ride the jit call as a host array: jax folds the
             # transfer into the dispatch instead of a separate device_put

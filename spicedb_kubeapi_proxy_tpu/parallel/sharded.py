@@ -801,6 +801,7 @@ class ShardedGraph:
             metrics.counter("engine_seed_walks_total").inc()
         with tracer.stage("engine_enqueue",
                           metrics.histogram("engine_enqueue_seconds"),
+                          metrics.counter("engine_enqueue_cpu_seconds_total"),
                           rows=len(seeds_pad)):
             out, converged, iters, checks, n_push, cav_missing = self._run(
                 self._level_edges, self._blocks,

@@ -22,7 +22,7 @@ from typing import Optional
 from urllib.parse import parse_qs, unquote, urlsplit
 
 from ..authz import AuthzDeps, authorize
-from ..obs.profile import install_gc_hook, settle_collector
+from ..obs.profile import cpu_ledger, install_gc_hook, settle_collector
 from ..obs.trace import tracer
 from ..proxy.authn import (
     AuthenticationError,
@@ -382,6 +382,7 @@ class Server:
     async def start(self) -> int:
         install_gc_hook()
         settle_collector()
+        cpu_ledger.serve_from(asyncio.get_running_loop())
         self._server = await asyncio.start_server(
             self._serve_connection, self.host, self.port,
             ssl=self.ssl_context)

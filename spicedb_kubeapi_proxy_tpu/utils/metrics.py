@@ -24,6 +24,14 @@ class Counter:
         with self._lock:
             self._value += amount
 
+    def advance_to(self, total: float) -> None:
+        """For a counter that mirrors a clock kept elsewhere (the
+        kernel's CPU clocks, obs/profile.py): take its reading, never
+        backwards."""
+        with self._lock:
+            if total > self._value:
+                self._value = total
+
     @property
     def value(self) -> float:
         return self._value
@@ -115,6 +123,16 @@ class Registry:
         self._counters: dict[tuple, Counter] = {}
         self._gauges: dict[tuple, Gauge] = {}
         self._hists: dict[tuple, Histogram] = {}
+        self._refreshers: list = []
+
+    def add_refresher(self, refresh) -> None:
+        """``refresh()`` runs at the start of every :meth:`render`, on
+        the rendering thread and before the registry's lock is taken:
+        for series that mirror a reading kept elsewhere and cost nothing
+        between scrapes. It outlives :meth:`reset`."""
+        with self._lock:
+            if refresh not in self._refreshers:
+                self._refreshers.append(refresh)
 
     def counter(self, name: str, **labels) -> Counter:
         key = (name,) + tuple(sorted(labels.items()))
@@ -174,6 +192,10 @@ class Registry:
         ``_bucket{le="..."}`` series ending at ``+Inf`` — so a real
         scraper can compute quantiles; the historical ``_count``/``_sum``
         lines are unchanged."""
+        with self._lock:
+            refreshers = list(self._refreshers)
+        for refresh in refreshers:
+            refresh()
         out = []
         typed: set = set()
 

@@ -229,6 +229,45 @@ def test_served_lists_name_what_the_reference_and_the_oracle_name(
     assert spans.count("engine_encode") == LISTS  # one chunk a list
 
 
+# each synchronous stage of the postfilter path adds its thread's CPU
+# seconds to a counter beside its histogram (ISSUE 37)
+CPU_BESIDE = {
+    "proxy_postfilter_parse_cpu_seconds_total":
+        "proxy_postfilter_parse_seconds",
+    "proxy_postfilter_resolve_cpu_seconds_total":
+        "proxy_postfilter_resolve_seconds",
+    "proxy_postfilter_write_cpu_seconds_total":
+        "proxy_postfilter_write_seconds",
+    "engine_bulk_cache_cpu_seconds_total": "engine_bulk_cache_seconds",
+}
+_LIST_CLOCKS = {}
+
+
+@pytest.mark.parametrize("counter", sorted(CPU_BESIDE))
+def test_a_postfiltered_list_adds_each_stages_cpu_beside_its_wall(
+        scoped, tmp_path, counter, monkeypatch):
+    """One worker thread's Python from the parse to the write: every
+    part's CPU counter moves, and never by more than its wall clock
+    (with every stage's CPU read, not one in ``CPU_EVERY``)."""
+    from spicedb_kubeapi_proxy_tpu.obs import trace
+
+    monkeypatch.setattr(trace, "CPU_EVERY", 1)
+    if not _LIST_CLOCKS:  # one served list a process
+        def read():
+            return {c: (metrics.counter(c).value, metrics.histogram(h).total)
+                    for c, h in CPU_BESIDE.items()}
+
+        before = read()
+        got, _ = asyncio.run(_served(
+            scoped.dep, tmp_path, stand_in(scoped.dep, []),
+            [(str(scoped.users[0]), "application/json")]))
+        assert got[0][0] == 200
+        for c, (cpu, wall) in read().items():
+            _LIST_CLOCKS[c] = (cpu - before[c][0], wall - before[c][1])
+    cpu, wall = _LIST_CLOCKS[counter]
+    assert 0.0 < cpu <= wall, (counter, cpu, wall)
+
+
 def test_a_list_asked_again_is_answered_from_the_cache(scoped, tmp_path):
     """The same user's second list dispatches nothing: every verdict is
     a hit, and ``bulk_cache`` is still one observation a call."""

@@ -14,6 +14,7 @@ import asyncio
 import gc
 import json
 import os
+import random
 import re
 import time
 import weakref
@@ -45,7 +46,8 @@ def _tracing_on():
     tracer.configure(sample=1.0, slow_ms=250.0, ring=256)
     tracer.reset()
     yield
-    tracer.configure(sample=0.1, slow_ms=250.0, ring=256)
+    tracer.configure(sample=0.1, slow_ms=250.0, ring=256,
+                     _rand=random.random)  # a test may have loaded the dice
     tracer.reset()
 
 
@@ -619,17 +621,31 @@ def test_debug_traces_flag_gated_and_infra_paths_untraced(tmp_path):
     asyncio.run(go())
 
 
-def test_trace_overhead_disabled_is_negligible():
+@pytest.fixture
+def every_stage_clocked(monkeypatch):
+    """One ``cpu=`` stage in ``CPU_EVERY`` reads the CPU clock; a test
+    that looks at one stage has every one read."""
+    from spicedb_kubeapi_proxy_tpu.obs import trace
+
+    monkeypatch.setattr(trace, "CPU_EVERY", 1)
+
+
+@pytest.mark.parametrize("with_cpu", [False, True], ids=["wall", "cpu"])
+def test_trace_overhead_disabled_is_negligible(with_cpu,
+                                               every_stage_clocked):
     """The no-regression guard in unit form: with sample=0 the span hooks
     must cost nanoseconds, not microseconds (the bench-level pin is the
-    check-throughput phase staying within noise)."""
+    check-throughput phase staying within noise); a stage that reads the
+    thread's CPU clock too, every time, stays under the same bound."""
     tracer.configure(sample=0.0)
+    cpu = (metrics.counter("test_overhead_cpu_seconds_total")
+           if with_cpu else None)
     t0 = time.perf_counter()
     n = 20_000
     for _ in range(n):
-        with tracer.span("x"):
+        with tracer.stage("x", None, cpu):
             pass
-        tracer.span("y").finish()
+        tracer.stage("y", None, cpu).finish()
     per_call = (time.perf_counter() - t0) / (2 * n)
     # generous bound: even a slow CI box does a no-op contextvar check in
     # well under 20us
@@ -731,6 +747,267 @@ def test_stage_with_tracing_off_still_feeds_its_histogram():
             st.set("ignored", 1)
     assert _hist_count("test_stage_off_seconds") == n0 + 1
     assert tracer.recent() == []
+
+
+# -- the interpreter lock: CPU beside the wall clock (ISSUE 37) ---------------
+
+
+def _burn(cpu_seconds):
+    """Compute until this thread's own CPU clock has advanced."""
+    c0 = time.thread_time()
+    while time.thread_time() - c0 < cpu_seconds:
+        sum(range(1000))
+
+
+def _staged(body):
+    """Run ``body`` inside one ``cpu=`` stage -> (wall, cpu) seconds it
+    added to its histogram and its counter."""
+    hist = metrics.histogram("test_stage_cpu_seconds")
+    cpu = metrics.counter("test_stage_cpu_seconds_total")
+    wall0, cpu0 = hist.total, cpu.value
+    with tracer.stage("clocked", hist, cpu):
+        body()
+    return hist.total - wall0, cpu.value - cpu0
+
+
+def test_a_cpu_stage_around_a_busy_loop_adds_what_the_loop_burned(
+        every_stage_clocked):
+    """At least what the loop read off the same clock, and never more
+    than the wall time (the CPU clock is read inside the wall clock's two
+    readings). How far under the wall time it stays is the machine's and
+    the other threads' business — what the counter is there to show —
+    and no test's."""
+    wall, cpu = _staged(lambda: _burn(0.02))
+    assert 0.02 <= cpu <= wall
+
+
+def test_a_cpu_stage_around_a_sleep_adds_next_to_nothing(
+        every_stage_clocked):
+    wall, cpu = _staged(lambda: time.sleep(0.05))
+    assert wall >= 0.05 and 0.0 <= cpu < 0.005
+
+
+def test_a_stage_without_cpu_reads_no_thread_clock(monkeypatch,
+                                                   every_stage_clocked):
+    """``cpu`` is per call site: a stage that crosses an ``await`` or
+    ends on another thread gets none, touches no counter and pays for
+    no clock but the wall's."""
+    reads = []
+    thread_time = time.thread_time
+    monkeypatch.setattr(time, "thread_time",
+                        lambda: reads.append(1) or thread_time())
+    hist = metrics.histogram("test_stage_nocpu_seconds")
+    counters = set(metrics._counters)
+    with tracer.start("request"):
+        with tracer.stage("unclocked", hist):
+            pass
+        tracer.stage("unclocked_leaf", hist).finish()
+    assert reads == [] and set(metrics._counters) == counters
+    with tracer.stage("clocked", hist, metrics.counter(
+            "test_stage_cpu_seconds_total")):
+        pass
+    assert len(reads) == 2  # where the wall clock is read: both ends
+
+
+def test_the_span_of_a_cpu_stage_carries_cpu_us(every_stage_clocked):
+    cpu = metrics.counter("test_stage_cpu_seconds_total")
+    with tracer.start("request"):
+        with tracer.stage("clocked", None, cpu):
+            _burn(0.002)
+        with tracer.stage("unclocked"):
+            pass
+    (t,) = tracer.recent()
+    spans = {s["name"]: s for s in t["spans"]}
+    assert 2000 <= spans["clocked"]["attrs"]["cpu_us"] \
+        <= spans["clocked"]["duration_us"]
+    assert "cpu_us" not in spans["unclocked"]["attrs"]
+
+
+def test_one_cpu_stage_in_cpu_every_is_read_and_counts_for_them_all(
+        monkeypatch):
+    """The CPU clock is a system call: of ``CPU_EVERY`` stages one, drawn
+    by the tracer's own dice, reads it at both ends and adds
+    ``CPU_EVERY`` times what it read; the others read no clock, add
+    nothing and carry no ``cpu_us``. Equal stages sum to their CPU."""
+    from spicedb_kubeapi_proxy_tpu.obs import trace
+
+    monkeypatch.setattr(trace, "CPU_EVERY", 4)
+    # the first of every four; the last throw is the tail sampler's
+    dice = iter(([0.1, 0.3, 0.6, 0.9] * 3 + [0.0]) * 2)
+    tracer.configure(_rand=lambda: next(dice))
+    reads = []
+    thread_time = time.thread_time
+    monkeypatch.setattr(time, "thread_time",
+                        lambda: reads.append(1) or thread_time())
+    cpu = metrics.counter("test_stage_sampled_cpu_seconds_total")
+    c0 = cpu.value
+    with tracer.start("request"):
+        for _ in range(12):
+            with tracer.stage("clocked", None, cpu):
+                time.sleep(0.001)  # reads no clock of its own
+    assert len(reads) == 2 * 3
+    with tracer.start("request"):
+        for _ in range(12):
+            with tracer.stage("clocked", None, cpu):
+                _burn(0.002)
+    (_, t) = sorted(tracer.recent(), key=lambda t: t["start"])
+    clocked = [s for s in t["spans"] if s["name"] == "clocked"]
+    assert len(clocked) == 12
+    assert [("cpu_us" in s["attrs"]) for s in clocked] \
+        == [True, False, False, False] * 3
+    assert all(s["attrs"]["cpu_us"] >= 2000 for s in clocked[::4])
+    # three read, each standing for four: what twelve such stages burned
+    assert 12 * 0.002 <= cpu.value - c0 <= 12 * 0.002 * 3
+
+
+def test_a_stage_timed_by_hand_asks_for_its_weight():
+    from spicedb_kubeapi_proxy_tpu.obs import trace
+
+    tracer.configure(_rand=lambda: 0.99)
+    assert tracer.cpu_weight() == 0
+    tracer.configure(_rand=lambda: 0.0)
+    assert tracer.cpu_weight() == trace.CPU_EVERY == 16
+
+
+def _rendered(registry) -> dict:
+    return {line.split(" ")[0]: float(line.split(" ")[1])
+            for line in registry.render().splitlines()
+            if line.startswith("process_")}
+
+
+LEDGER = ("process_cpu_seconds_total", "process_loop_cpu_seconds_total",
+          "process_worker_cpu_seconds_total")
+
+
+def test_a_render_refreshes_the_cpu_ledger_and_it_never_runs_backwards():
+    """Three counters set from the kernel's clocks when the registry is
+    rendered, and only then: what the loop's thread and the pool's
+    threads burned shows at the next render, the parts never exceed the
+    whole, and a worker that has ended keeps its last reading."""
+    from concurrent.futures import ThreadPoolExecutor
+
+    from spicedb_kubeapi_proxy_tpu.obs.profile import CpuLedger
+
+    reg = Registry()
+    ledger = CpuLedger(reg)
+    seen = []
+
+    async def serve():
+        loop = asyncio.get_running_loop()
+        loop.set_default_executor(ThreadPoolExecutor(max_workers=2))
+        ledger.serve_from(loop)
+        ledger.serve_from(loop)  # a second start registers nothing more
+        seen.append(_rendered(reg))
+        _burn(0.02)  # on the loop's thread
+        await asyncio.gather(asyncio.to_thread(_burn, 0.03),
+                             asyncio.to_thread(_burn, 0.03))
+        unrendered = {n: reg.counter(n).value for n in LEDGER}
+        assert unrendered == seen[0]  # nothing moves between renders
+        seen.append(_rendered(reg))
+
+    assert reg.render() == "\n"  # nothing registered, nothing rendered
+    asyncio.run(serve())
+    assert len(reg._refreshers) == 1
+    seen.append(_rendered(reg))  # the loop closed, its pool's threads gone
+    _burn(0.01)
+    seen.append(_rendered(reg))
+    first, burned, ended, last = seen
+    assert set(first) == set(LEDGER)
+    assert burned["process_loop_cpu_seconds_total"] \
+        >= first["process_loop_cpu_seconds_total"] + 0.02
+    assert burned["process_worker_cpu_seconds_total"] \
+        >= first["process_worker_cpu_seconds_total"] + 0.06
+    for earlier, later in zip(seen, seen[1:]):
+        assert all(later[n] >= earlier[n] for n in LEDGER)
+    for r in seen:
+        assert r["process_loop_cpu_seconds_total"] \
+            + r["process_worker_cpu_seconds_total"] \
+            <= r["process_cpu_seconds_total"]
+    assert ended["process_worker_cpu_seconds_total"] \
+        == burned["process_worker_cpu_seconds_total"]
+    assert not ledger._worker_clock._last  # its readings folded, not kept
+    assert last["process_cpu_seconds_total"] \
+        >= ended["process_cpu_seconds_total"] + 0.01
+
+
+def test_a_threads_cpu_clock_is_asked_by_its_kernel_id():
+    """The id the ledger builds from ``native_id`` is the one
+    ``pthread_getcpuclockid`` computes from the ``pthread_t``; for a
+    thread that has gone the kernel refuses it, where a stale
+    ``pthread_t`` would be undefined behaviour."""
+    import threading
+
+    me = threading.current_thread()
+    assert (~me.native_id << 3) | 6 == time.pthread_getcpuclockid(me.ident)
+    t = threading.Thread(target=lambda: None)
+    t.start()
+    t.join(10)
+    assert not t.is_alive()
+    deadline = time.monotonic() + 10  # the kernel's thread ends a little
+    with pytest.raises(OSError):      # after the interpreter's
+        while time.monotonic() < deadline:
+            time.clock_gettime((~t.native_id << 3) | 6)
+
+
+def test_a_serving_process_renders_the_cpu_ledger():
+    asyncio.run(_start_and_stop())
+    r = _rendered(metrics)
+    assert set(LEDGER) <= set(r)
+    assert 0 < r["process_loop_cpu_seconds_total"] \
+        <= r["process_cpu_seconds_total"]
+
+
+@pytest.mark.parametrize("sleeps", [True, False],
+                         ids=["late", "early"])
+def test_a_collectors_poll_is_one_observation_of_the_lock_wait(
+        monkeypatch, sleeps):
+    """How late the collector's sleep returns, once a poll; a sleep that
+    came back early (a clock's step) observes 0, never less."""
+    from spicedb_kubeapi_proxy_tpu.obs import profile
+
+    monkeypatch.setattr(profile, "GC_POLL_S", 0.002)
+    if not sleeps:
+        monkeypatch.setattr(time, "sleep", lambda s: None)
+    profile._sleep_a_poll()  # the histogram exists
+    before = metrics.hist_snapshot("process_lock_wait_seconds")
+    profile._sleep_a_poll()
+    after = metrics.hist_snapshot("process_lock_wait_seconds")
+    assert after["n"] == before["n"] + 1
+    late = after["total"] - before["total"]
+    assert 0.0 <= late < 10.0
+    if not sleeps:
+        assert late == 0.0
+
+
+def test_the_collectors_thread_probes_the_lock_and_writes_no_annotation(
+        unserved_process, tmp_path):
+    """Twenty observations a second from the thread that sleeps anyway,
+    straight into the histogram: no ``sdbkp:lock_wait`` enters the
+    profiler's trace, where it would count as a working stage."""
+    n0 = _hist_count("process_lock_wait_seconds")
+
+    def body():
+        asyncio.run(_start_and_stop())
+        deadline = time.monotonic() + 10
+        while _hist_count("process_lock_wait_seconds") < n0 + 3 \
+                and time.monotonic() < deadline:
+            time.sleep(0.01)
+
+    events = _profiled(tmp_path, body)
+    assert _hist_count("process_lock_wait_seconds") >= n0 + 3
+    assert not [name for name in events if "lock" in name]
+
+
+def test_gc_hook_adds_the_collections_own_cpu():
+    from spicedb_kubeapi_proxy_tpu.obs.profile import install_gc_hook
+
+    install_gc_hook()
+    cpu = metrics.counter("process_gc_cpu_seconds_total")
+    wall = metrics.hist_snapshot("process_gc_seconds", generation=2)
+    c0, w0 = cpu.value, wall["total"] if wall else 0.0
+    gc.collect()
+    wall = metrics.hist_snapshot("process_gc_seconds", generation=2)
+    assert 0.0 < cpu.value - c0 <= wall["total"] - w0
 
 
 def test_executor_wait_grows_when_the_pool_is_saturated():
@@ -926,6 +1203,46 @@ def test_served_reads_feed_stage_histograms_with_tracing_off(tmp_path):
     # one lookup and one check, a subject row each
     assert metrics.counter("engine_dispatch_rows_total").value - rows0 >= 2
     assert metrics.gauge("engine_residual_edges").value >= 1
+
+
+# a synchronous stage's CPU counter beside its histogram (ISSUE 37)
+CPU_BESIDE = {
+    "engine_encode_cpu_seconds_total": "engine_encode_seconds",
+    "engine_enqueue_cpu_seconds_total": "engine_enqueue_seconds",
+    "engine_mask_to_ids_cpu_seconds_total": "engine_mask_to_ids_seconds",
+    "engine_bulk_cache_cpu_seconds_total": "engine_bulk_cache_seconds",
+    "proxy_prefilter_map_cpu_seconds_total": "proxy_prefilter_map_seconds",
+    "proxy_body_filter_cpu_seconds_total": "proxy_body_filter_seconds",
+}
+_SERVED_CLOCKS = {}
+
+
+def _served_clocks(tmp_path) -> dict:
+    """{counter: (cpu, wall) seconds a served list and a served get
+    added}, read once a process."""
+    if not _SERVED_CLOCKS:
+        def read():
+            return {c: (metrics.counter(c).value,
+                        (metrics.hist_snapshot(h) or {"total": 0.0})["total"])
+                    for c, h in CPU_BESIDE.items()}
+
+        before = read()
+        assert asyncio.run(_serve_and_get(
+            tmp_path, 0.0, ["/api/v1/namespaces",
+                            "/api/v1/namespaces/team-a"])) == [200, 200]
+        for c, (cpu, wall) in read().items():
+            _SERVED_CLOCKS[c] = (cpu - before[c][0], wall - before[c][1])
+    return _SERVED_CLOCKS
+
+
+@pytest.mark.parametrize("counter", sorted(CPU_BESIDE))
+def test_served_reads_add_each_stages_cpu_beside_its_wall(
+        tmp_path, counter, every_stage_clocked):
+    """A prefiltered list and a get pass every synchronous stage of the
+    two read paths: each adds its thread's CPU seconds to a counter of
+    its own, and never more than its histogram took."""
+    cpu, wall = _served_clocks(tmp_path)[counter]
+    assert 0.0 < cpu <= wall, (counter, cpu, wall)
 
 
 def test_gc_hook_times_collections_by_generation():

@@ -26,6 +26,15 @@ device dispatches:
   :class:`~.batcher.LookupBatcher` batch, which this layer sits in front
   of (the batcher only ever sees true misses).
 
+- **Bulk entries**: a bulk check's verdicts pass the cache a shard at a
+  time, not a verdict at a time — :meth:`DecisionCache.get_many` probes
+  and :meth:`DecisionCache.put_many` fills every key of a bulk with one
+  visit a shard under its lock, each shard seeing its keys in the
+  bulk's order, and the counters and gauges moved once a pass by the
+  totals. A shard's LRU is its own, so the cache is left exactly as
+  key-by-key ``get`` / ``put`` leave it;
+  ``engine_bulk_cache_lock_takes_total`` counts the visits.
+
 Values are stored raw; the ENGINE copies masks on read so callers can
 never mutate a cached array (copy-on-read). Metrics:
 ``engine_decision_cache_hits_total`` / ``_misses_total`` (labeled by
@@ -36,7 +45,8 @@ kind), ``_evictions_total``, ``_piggybacks_total``, and gauges
 from __future__ import annotations
 
 import threading
-from collections import OrderedDict
+from collections import Counter, OrderedDict
+from operator import itemgetter
 from typing import Optional
 
 from ..utils.metrics import metrics
@@ -44,6 +54,9 @@ from ..utils.metrics import metrics
 #: sentinel distinguishing "no entry" from any cached value (False/None
 #: are legitimate verdicts — negative checks are cached too)
 MISS = object()
+
+# a key's kind ("check" / "lookup"): the label of the hit and miss counters
+_KIND = itemgetter(0)
 
 
 class Flight:
@@ -210,6 +223,128 @@ class DecisionCache:
                 evicted)
         metrics.gauge("engine_decision_cache_entries").inc(added - evicted)
         metrics.gauge("engine_decision_cache_mask_bytes").dec(freed)
+
+    # -- a bulk check's verdicts, a shard at a time --------------------------
+
+    def _by_shard(self, keys: list) -> list:
+        """``(shard index, positions in keys)`` for every shard that has
+        a key of the bulk, positions in the keys' order: one hash a key."""
+        n = len(self._shards)
+        groups: list = [[] for _ in range(n)]
+        for i, s in enumerate([hash(k) % n for k in keys]):
+            groups[s].append(i)
+        return [(s, positions) for s, positions in enumerate(groups)
+                if positions]
+
+    def get_many(self, keys: list, now: float) -> tuple[list, list]:
+        """:meth:`get` for every key of a bulk, a shard at a time.
+
+        Returns ``(values, missed)``: ``values[i]`` is the cached value
+        of ``keys[i]`` or :data:`MISS`; ``missed`` pairs each shard in
+        which a key missed with those keys' positions in ``keys``, in
+        the order they came, and is what :meth:`put_many` takes to fill
+        them. Every shard that has a key is visited once, under its
+        lock, and sees its keys in the bulk's order; a shard's LRU is its
+        own, so the cache is left exactly as ``get`` called key by key
+        leaves it (recency, expired entries dropped on the spot), and
+        hits, misses and the two gauges move by the same totals, once a
+        pass."""
+        shards = self._shards
+        groups = self._by_shard(keys)
+        values = [MISS] * len(keys)
+        missed = []
+        hits: dict = {}
+        dropped = freed = 0
+        for s, positions in groups:
+            sh = shards[s]
+            miss = []
+            with sh.lock:
+                entries = sh.entries
+                probe = entries.get
+                for i in positions:
+                    k = keys[i]
+                    ent = probe(k)
+                    if ent is None:
+                        miss.append(i)
+                    elif now < ent[1]:
+                        entries.move_to_end(k)
+                        values[i] = ent[0]
+                        hits[k[0]] = hits.get(k[0], 0) + 1
+                    else:
+                        del entries[k]
+                        sh.mask_bytes -= ent[2]
+                        dropped += 1
+                        freed += ent[2]
+                        miss.append(i)
+            if miss:
+                missed.append((s, miss))
+        for kind, asked in Counter(map(_KIND, keys)).items():
+            hit = hits.get(kind, 0)
+            if hit:
+                metrics.counter("engine_decision_cache_hits_total",
+                                kind=kind).inc(hit)
+            if asked > hit:
+                metrics.counter("engine_decision_cache_misses_total",
+                                kind=kind).inc(asked - hit)
+        if dropped:
+            metrics.gauge("engine_decision_cache_entries").dec(dropped)
+            metrics.gauge("engine_decision_cache_mask_bytes").dec(freed)
+        metrics.counter("engine_bulk_cache_lock_takes_total").inc(
+            len(groups))
+        return values, missed
+
+    def put_many(self, keys: list, values: list, deadline: float,
+                 now: float, groups: Optional[list] = None) -> None:
+        """:meth:`put` of the verdict ``values[i]`` (an entry of 0
+        bytes) under ``keys[i]``, a shard at a time. ``groups`` is
+        :meth:`get_many`'s ``missed``: only those positions are put,
+        and its grouping is reused; without it every key is. As
+        :meth:`put`: a born-dead deadline stores nothing, ``_closed`` is
+        read again under each shard's lock, a key is popped and inserted
+        at the warm end, and the shard evicts from its cold end after
+        every insert, so entries, recency, evictions and gauges come out
+        as key-by-key puts leave them. One ``(value, deadline, 0)``
+        entry serves every key of a verdict."""
+        if deadline <= now:
+            return
+        shards = self._shards
+        if groups is None:
+            groups = self._by_shard(keys)
+        granted = (True, deadline, 0)
+        denied = (False, deadline, 0)
+        entry_budget = self._entry_budget
+        byte_budget = self._byte_budget
+        evicted = freed = added = 0
+        for s, positions in groups:
+            sh = shards[s]
+            with sh.lock:
+                if self._closed:
+                    continue
+                entries = sh.entries
+                pop = entries.pop
+                for i in positions:
+                    k = keys[i]
+                    old = pop(k, None)
+                    if old is not None:
+                        sh.mask_bytes -= old[2]
+                        freed += old[2]
+                        added -= 1
+                    entries[k] = granted if values[i] else denied
+                    added += 1
+                    while len(entries) > 1 and (
+                            len(entries) > entry_budget
+                            or sh.mask_bytes > byte_budget):
+                        _, (_, _, nb) = entries.popitem(last=False)
+                        sh.mask_bytes -= nb
+                        freed += nb
+                        evicted += 1
+        if evicted:
+            metrics.counter("engine_decision_cache_evictions_total").inc(
+                evicted)
+        metrics.gauge("engine_decision_cache_entries").inc(added - evicted)
+        metrics.gauge("engine_decision_cache_mask_bytes").dec(freed)
+        metrics.counter("engine_bulk_cache_lock_takes_total").inc(
+            len(groups))
 
     def clear(self) -> None:
         """Drop every entry (and fix the gauges) and refuse future fills:

@@ -920,34 +920,39 @@ class Engine:
         digest = (context_digest(cg.caveats.relevant_context(context))
                   if cg.caveats is not None and cg.caveats.metas
                   else None)
-        # stage ``bulk_cache``: the cache's two passes over the items (a
-        # key and a probe each before the dispatch, a put each after it),
-        # a span each and ONE observation a call, of their sum; each
-        # pass runs on one thread, whose CPU seconds go to the counter
-        # (for the one call in CPU_EVERY that reads them: obs/trace.py)
+        # stage ``bulk_cache``: the cache's two passes over the bulk (the
+        # keys and one probe pass before the dispatch, one put pass after
+        # it; each visits a shard once: decision_cache.get_many), a span
+        # each and ONE observation a call, of their sum; each pass runs
+        # on one thread, whose CPU seconds go to the counter (for the
+        # one call in CPU_EVERY that reads them: obs/trace.py)
         spent = metrics.histogram("engine_bulk_cache_seconds")
         cpu = metrics.counter("engine_bulk_cache_cpu_seconds_total")
         cpu_weight = tracer.cpu_weight()
         t0 = time.perf_counter()
         c0 = time.thread_time() if cpu_weight else 0.0
         with tracer.span("bulk_cache"):
-            keys = [check_key(cg.revision, it, digest) for it in items]
-            out: list = [None] * len(items)
-            miss_idx: list[int] = []
-            for i, k in enumerate(keys):
-                v = cache.get(k, now0)
-                if v is MISS:
-                    miss_idx.append(i)
-                else:
-                    out[i] = v
+            # the tuple decision_cache.check_key builds, inline
+            rev = cg.revision
+            tail = () if digest is None else (digest,)
+            keys = [("check", rev, it.resource_type, it.resource_id,
+                     it.permission, it.subject_type, it.subject_id,
+                     it.subject_relation) + tail for it in items]
+            out, missed = cache.get_many(keys, now0)
         if cpu_weight:
             cpu.inc((time.thread_time() - c0) * cpu_weight)
         probe_s = time.perf_counter() - t0
-        if not miss_idx:
+        if not missed:
             spent.observe(probe_s)
             return EngineFuture(None, lambda _: list(out))
-        inner = self._check_bulk_dispatch(
-            [items[i] for i in miss_idx], now0, cg=cg, context=context)
+        if sum(len(positions) for _, positions in missed) == len(items):
+            miss_idx = None  # every item missed: the bulk goes on as it is
+            inner = self._check_bulk_dispatch(items, now0, cg=cg,
+                                              context=context)
+        else:
+            miss_idx = [i for i, v in enumerate(out) if v is MISS]
+            inner = self._check_bulk_dispatch(
+                [items[i] for i in miss_idx], now0, cg=cg, context=context)
 
         def fin(_):
             got = inner.result()
@@ -955,14 +960,17 @@ class Engine:
             c1 = time.thread_time() if cpu_weight else 0.0
             with tracer.span("bulk_cache"):
                 deadline = self._cache_deadline(cg, now0, context)
-                for j, i in enumerate(miss_idx):
-                    v = bool(got[j])
-                    cache.put(keys[i], v, deadline, 0, now0)
-                    out[i] = v
+                if miss_idx is None:
+                    verdicts = got
+                else:
+                    for i, v in zip(miss_idx, got):
+                        out[i] = v
+                    verdicts = list(out)
+                cache.put_many(keys, verdicts, deadline, now0, missed)
             if cpu_weight:
                 cpu.inc((time.thread_time() - c1) * cpu_weight)
             spent.observe(probe_s + time.perf_counter() - t1)
-            return list(out)
+            return verdicts
 
         return EngineFuture(None, fin, iters=inner.iterations)
 

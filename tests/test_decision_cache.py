@@ -431,3 +431,283 @@ def test_disable_clears_gauges():
     before_l = lookups_total()
     e.lookup_resources_mask("ns", "view", "user", "u0")
     assert lookups_total() - before_l == 1
+
+
+# ---------------------------------------------------------------------------
+# The bulk entries (get_many / put_many): a bulk check's verdicts pass the
+# cache a shard at a time, and leave it as key-by-key get / put leave it
+# ---------------------------------------------------------------------------
+
+T0 = 1_000_000.0
+FOREVER = float("inf")
+
+
+def _k(i, rev=1):
+    return ("check", rev, "ns", f"n{i}", "view", "user", "u0", None)
+
+
+def _bulk(ids, deadline=FOREVER, now=T0, rev=1):
+    """One bulk check as the cache sees it: keys, the verdict each would
+    get if it missed, the fill's deadline, the clock."""
+    return ([_k(i, rev) for i in ids], [i % 3 == 0 for i in ids],
+            deadline, now)
+
+
+def _seeded_bulks(seed):
+    rng = np.random.default_rng(seed)
+    bulks = []
+    for step in range(40):
+        ids = rng.integers(0, 400, size=int(rng.integers(1, 120))).tolist()
+        now = T0 + step
+        # one fill in five dies ten ticks on, one in ten is born dead
+        deadline = (now + 10 if step % 5 == 0
+                    else now - 1 if step % 10 == 7 else FOREVER)
+        bulks.append(_bulk(ids, deadline, now, rev=1 + step // 25))
+    return bulks
+
+
+BULK_CASES = {
+    "fresh_keys": [_bulk(range(40)), _bulk(range(40, 80))],
+    "repeats_hit_and_refresh_recency": [
+        _bulk(range(40)), _bulk(range(20)), _bulk(range(30, 70))],
+    "duplicates_inside_one_bulk": [
+        _bulk([1, 2, 1, 3, 2, 1]), _bulk([3, 3, 1, 9, 9])],
+    "bulk_of_one": [_bulk([5]), _bulk([5]), _bulk([6])],
+    "expired_entries_drop_on_the_spot": [
+        _bulk(range(30), deadline=T0 + 5),
+        _bulk(range(10, 50), now=T0 + 6)],
+    "born_dead_deadline_stores_nothing": [
+        _bulk(range(30)), _bulk(range(20, 60), deadline=T0 - 1),
+        _bulk(range(60))],
+    "bulk_larger_than_the_budget": [
+        _bulk(range(50)), _bulk(range(300)), _bulk(range(250, 320))],
+    "another_revision_is_another_key": [
+        _bulk(range(40)), _bulk(range(40), rev=2), _bulk(range(40))],
+    "seeded_mix_7": _seeded_bulks(7),
+    "seeded_mix_8": _seeded_bulks(8),
+}
+
+_CACHE_SERIES = (
+    ("counter", "engine_decision_cache_hits_total", {"kind": "check"}),
+    ("counter", "engine_decision_cache_hits_total", {"kind": "lookup"}),
+    ("counter", "engine_decision_cache_misses_total", {"kind": "check"}),
+    ("counter", "engine_decision_cache_misses_total", {"kind": "lookup"}),
+    ("counter", "engine_decision_cache_evictions_total", {}),
+    ("gauge", "engine_decision_cache_entries", {}),
+    ("gauge", "engine_decision_cache_mask_bytes", {}),
+)
+
+
+def _cache_series():
+    return [getattr(metrics, kind)(name, **labels).value
+            for kind, name, labels in _CACHE_SERIES]
+
+
+def _moved(drive):
+    before = _cache_series()
+    got = drive()
+    return got, [a - b for a, b in zip(_cache_series(), before)]
+
+
+def _key_by_key(cache, bulks):
+    """The passes check_bulk_async made before the bulk entries: a get a
+    key, then a put a key that missed."""
+    answers = []
+    for keys, verdicts, deadline, now in bulks:
+        values = [cache.get(k, now) for k in keys]
+        for k, v, verdict in zip(keys, values, verdicts):
+            if v is MISS:
+                cache.put(k, verdict, deadline, 0, now)
+        answers.append(values)
+    return answers
+
+
+def _shard_at_a_time(cache, bulks):
+    answers = []
+    for keys, verdicts, deadline, now in bulks:
+        values, missed = cache.get_many(keys, now)
+        assert (sorted(i for _, positions in missed for i in positions)
+                == [i for i, v in enumerate(values) if v is MISS])
+        cache.put_many(keys, verdicts, deadline, now, missed)
+        answers.append(values)
+    return answers
+
+
+def _shards(cache):
+    return [list(sh.entries.items()) for sh in cache._shards]
+
+
+def _small_cache():
+    # 16 entries a shard; one lookup mask already over its shard's byte
+    # budget (a lone entry is never evicted), for the puts to push out
+    c = DecisionCache(max_entries=64, max_mask_bytes=400, shards=4)
+    c.put(("lookup", 1, "ns", "view", "user", "u0", None), ("mask", None),
+          FOREVER, 150, T0)
+    return c
+
+
+@pytest.mark.parametrize("case", sorted(BULK_CASES))
+def test_bulk_entries_leave_the_cache_as_key_by_key_passes_do(case):
+    bulks = BULK_CASES[case]
+    one, many = _small_cache(), _small_cache()
+    want, want_moved = _moved(lambda: _key_by_key(one, bulks))
+    got, got_moved = _moved(lambda: _shard_at_a_time(many, bulks))
+    assert got == want
+    # the same keys in the same recency order under the same entries
+    assert _shards(many) == _shards(one)
+    assert many.stats() == one.stats()
+    assert all(len(sh.entries) <= 16 for sh in many._shards)
+    names = [f"{name}{labels or ''}" for _, name, labels in _CACHE_SERIES]
+    assert dict(zip(names, got_moved)) == dict(zip(names, want_moved))
+
+
+def test_get_many_counts_hits_and_misses_by_each_keys_kind():
+    one, many = (DecisionCache(max_entries=64, shards=4) for _ in "ab")
+    keys = [_k(1), ("lookup", 1, "ns", "view", "user", "u0", None),
+            ("lookup", 1, "ns", "view", "user", "u9", None), _k(2)]
+    for c in (one, many):
+        c.put(keys[1], ("mask", None), FOREVER, 10, T0)
+        c.put(_k(2), False, FOREVER, 0, T0)
+    want, want_moved = _moved(lambda: [one.get(k, T0) for k in keys])
+    (got, missed), got_moved = _moved(lambda: many.get_many(keys, T0))
+    assert got == want == [MISS, ("mask", None), MISS, False]
+    assert sorted(i for _, positions in missed for i in positions) == [0, 2]
+    assert got_moved == want_moved
+    assert _shards(many) == _shards(one)
+
+
+def test_put_many_without_a_grouping_puts_every_key():
+    one, many = _small_cache(), _small_cache()
+    keys, verdicts, _, _ = _bulk(range(100))
+    _, want_moved = _moved(lambda: [
+        one.put(k, v, FOREVER, 0, T0) for k, v in zip(keys, verdicts)])
+    _, got_moved = _moved(
+        lambda: many.put_many(keys, verdicts, FOREVER, T0))
+    assert _shards(many) == _shards(one)
+    assert got_moved == want_moved
+
+
+def test_put_many_after_clear_stores_nothing_and_moves_no_gauge():
+    c = DecisionCache(max_entries=64, shards=4)
+    keys, verdicts, _, _ = _bulk(range(40))
+    _, missed = c.get_many(keys, T0)
+    c.clear()  # the cache is detached while the bulk is on the device
+    _, moved = _moved(
+        lambda: c.put_many(keys, verdicts, FOREVER, T0, missed))
+    assert c.stats() == {"entries": 0, "mask_bytes": 0}
+    assert moved == [0] * len(_CACHE_SERIES)
+    assert c.get_many(keys, T0)[0] == [MISS] * 40
+
+
+def test_bulk_entries_share_one_entry_a_verdict():
+    c = DecisionCache(shards=1)
+    keys, verdicts, _, _ = _bulk(range(30))
+    c.put_many(keys, verdicts, FOREVER, T0)
+    assert len({id(ent) for ent in c._shards[0].entries.values()}) == 2
+    assert c.get_many(keys, T0)[0] == verdicts
+
+
+def test_check_bulk_takes_a_lock_a_shard_a_pass_and_observes_once():
+    e = build()
+    items = [CheckItem("ns", f"n{i}", "view", "user", "u0")
+             for i in range(1000)]
+    takes = metrics.counter("engine_bulk_cache_lock_takes_total")
+    spent = metrics.histogram("engine_bulk_cache_seconds")
+    misses = metrics.counter("engine_decision_cache_misses_total",
+                             kind="check")
+    hits = metrics.counter("engine_decision_cache_hits_total", kind="check")
+    t0, n0, m0, h0 = takes.value, spent.n, misses.value, hits.value
+    want = [True, True] + [False] * 998
+    assert e.check_bulk(items) == want
+    # a probe pass and a put pass, each at most one visit a shard
+    assert 2 <= takes.value - t0 <= 2 * 16
+    assert spent.n - n0 == 1
+    assert (misses.value - m0, hits.value - h0) == (1000, 0)
+    assert e._decision_cache.stats()["entries"] == 1000
+    before = checks_total()
+    t1 = takes.value
+    assert e.check_bulk(items) == want  # served whole, one probe pass
+    assert checks_total() == before
+    assert 1 <= takes.value - t1 <= 16
+    assert spent.n - n0 == 2
+    assert (misses.value - m0, hits.value - h0) == (1000, 1000)
+    # a bulk of one passes the same two entries
+    t2 = takes.value
+    assert e.check(CheckItem("ns", "n1", "view", "user", "u1")) is True
+    assert takes.value - t2 == 2 and spent.n - n0 == 3
+
+
+def test_check_bulk_stores_under_the_key_check_key_builds():
+    from spicedb_kubeapi_proxy_tpu.engine.decision_cache import check_key
+
+    e = build()
+    items = [CheckItem("ns", "n0", "view", "user", "u0"),
+             CheckItem("ns", "n2", "view", "group", "g0", "member")]
+    e.check_bulk(items)
+    rev = e.compiled().revision
+    stored = {k for sh in e._decision_cache._shards for k in sh.entries}
+    assert stored == {check_key(rev, it) for it in items}
+
+
+def _run_threads(target, offsets):
+    """Threads that change hands every few bytecodes (so they do meet
+    inside the passes), joined with a time limit."""
+    import sys
+
+    threads = [threading.Thread(target=target, args=(o,)) for o in offsets]
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(t.is_alive() for t in threads)
+
+
+def test_two_threads_pass_overlapping_bulks_through_one_cache():
+    """Load-sensitive: counts only. Two threads put and probe bulks whose
+    keys overlap; every shard stays within its budget, the gauge agrees
+    with the cache, and a key probed after its put (same revision, no
+    eviction possible: the budget holds every key) hits."""
+    c = DecisionCache(max_entries=4096, shards=4)
+    gauge = metrics.gauge("engine_decision_cache_entries")
+    g0 = gauge.value
+    errors = []
+
+    def run(offset):
+        try:
+            for round_ in range(30):
+                ids = range(offset + 20 * round_, offset + 20 * round_ + 400)
+                keys, verdicts, _, _ = _bulk(ids)
+                _, missed = c.get_many(keys, T0)
+                c.put_many(keys, verdicts, FOREVER, T0, missed)
+                values, again = c.get_many(keys, T0)
+                assert values == verdicts and not again
+        except BaseException as ex:  # noqa: BLE001 - reported below
+            errors.append(ex)
+            raise
+
+    _run_threads(run, (0, 200))
+    assert not errors, errors
+    # ids 0..1179 between them, each stored once whoever put it
+    assert c.stats()["entries"] == 1180
+    assert all(len(sh.entries) <= 1024 for sh in c._shards)
+    assert gauge.value - g0 == c.stats()["entries"]
+    # over budget, two threads evicting in the same shards: never more
+    # than a shard's share, and the gauge still agrees
+    small = DecisionCache(max_entries=64, shards=4)
+    g1 = gauge.value
+
+    def churn(offset):
+        for round_ in range(30):
+            keys, verdicts, _, _ = _bulk(
+                range(offset + 50 * round_, offset + 50 * round_ + 300))
+            _, missed = small.get_many(keys, T0)
+            small.put_many(keys, verdicts, FOREVER, T0, missed)
+
+    _run_threads(churn, (0, 120))
+    assert all(len(sh.entries) <= 16 for sh in small._shards)
+    assert gauge.value - g1 == small.stats()["entries"] <= 64

@@ -95,7 +95,7 @@ def _load() -> Optional[ctypes.CDLL]:
 # exported-signature change; _bind refuses a mismatching cached .so (the
 # rebuild path then fires) — binding by symbol NAME alone would let a
 # stale library misread argument slots silently
-_ABI_VERSION = 5
+_ABI_VERSION = 6
 
 
 def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
@@ -131,6 +131,12 @@ def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
         ctypes.c_char_p, ctypes.c_int64,
         ctypes.c_char_p, p64, ctypes.c_int64,
         p64, p64, ctypes.c_int64, p64, ctypes.c_int64, p64,
+    ]
+    p32 = ctypes.POINTER(ctypes.c_int32)
+    lib.json_list_keys.restype = ctypes.c_int64
+    lib.json_list_keys.argtypes = [
+        ctypes.c_char_p, ctypes.c_int64, ctypes.c_int64,
+        p64, p64, p32, ctypes.c_int64, ctypes.c_char_p, p32, p64,
     ]
     lib.proto_list_spans.restype = ctypes.c_int64
     lib.proto_list_spans.argtypes = [
@@ -238,6 +244,52 @@ def json_list_filter(body: bytes, records: bytes, offsets: np.ndarray):
         return None
     return (arr_span.tolist(), runs[:counts[1]], esc[:counts[2]],
             int(counts[0]))
+
+
+def json_list_keys(body: bytes, read_namespace: bool, read_name: bool):
+    """A kube *List or Table response body read in ONE native call that
+    holds no interpreter lock (graphcore.cpp json_list_keys), by the same
+    scan as :func:`json_list_filter`, for a caller that decides items by
+    their keys only after the scan. An item's key is its namespace if
+    ``read_namespace``, its name if ``read_name`` (what is unread or
+    missing reads empty). Returns ``(arr_span, spans, ids, keys, esc)``:
+    ``arr_span`` as :func:`json_list_filter` gives it; ``spans`` an int64
+    ``[n, 2]`` array, every item's byte span in ``body``; ``ids`` int32
+    ``[n]``, each item's key, dense in the order keys first occur;
+    ``keys`` the distinct keys' raw string content, ``k`` namespaces then
+    ``k`` names, each ended by ``b"\\x1e"``; ``esc`` int32, the ids of the
+    keys whose bytes hold an escape, for the caller to decode (two escapes
+    of one string are two keys). None where :func:`json_list_filter`
+    gives None."""
+    lib = _load()
+    if lib is None or not isinstance(body, bytes) or not body:
+        return None
+    p64 = ctypes.POINTER(ctypes.c_int64)
+    p32 = ctypes.POINTER(ctypes.c_int32)
+    arr_span = np.empty(2, dtype=np.int64)
+    counts = np.zeros(4, dtype=np.int64)  # items, keys, key bytes, escaped
+    # items are rarely under 64 bytes: start there and grow to what the
+    # scanner counted on its overflow code
+    max_items = len(body) // 64 + 1024
+    while True:
+        spans = np.empty((max_items, 2), dtype=np.int64)
+        ids = np.empty(max_items, dtype=np.int32)
+        keys = np.empty(len(body) + 2 * max_items, dtype=np.uint8)
+        esc = np.empty(max_items, dtype=np.int32)
+        rc = lib.json_list_keys(
+            body, len(body), int(read_namespace) | 2 * int(read_name),
+            arr_span.ctypes.data_as(p64), spans.ctypes.data_as(p64),
+            ids.ctypes.data_as(p32), max_items,
+            keys.ctypes.data_as(ctypes.c_char_p), esc.ctypes.data_as(p32),
+            counts.ctypes.data_as(p64))
+        if rc != -2:
+            break
+        max_items = int(counts[0])
+    if rc < 0:
+        return None
+    n, _, n_bytes, n_esc = counts.tolist()
+    return (arr_span.tolist(), spans[:n], ids[:n], keys[:n_bytes].tobytes(),
+            esc[:n_esc])
 
 
 def proto_list_spans(raw: bytes):

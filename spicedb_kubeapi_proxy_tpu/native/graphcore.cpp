@@ -519,17 +519,136 @@ struct Kept {
   }
 };
 
+// json_list_filter's sink: each item decided against the allowed records
+// as it closes, by its record '0' ns 0x1f name (missing -> empty); JSON
+// forbids raw control bytes in a string, so the separator cannot collide.
+struct Decide {
+  const char* buf;
+  const RecordSet& allowed;
+  Kept out;
+  std::string rec;
+
+  void reset() {
+    out.n_dropped = out.n_runs = out.n_esc = 0;
+    out.last_end = -1;
+  }
+  __attribute__((always_inline)) void item(
+      int64_t s, int64_t e, int64_t ns_s, int64_t ns_e, bool ns_esc,
+      int64_t nm_s, int64_t nm_e, bool nm_esc) {
+    if (nm_esc || ns_esc) {
+      out.undecided(s, e, ns_s, ns_e, nm_s, nm_e);
+      return;
+    }
+    rec.assign(1, '0');
+    if (ns_s >= 0) rec.append(buf + ns_s, static_cast<size_t>(ns_e - ns_s));
+    rec.push_back('\x1f');
+    if (nm_s >= 0) rec.append(buf + nm_s, static_cast<size_t>(nm_e - nm_s));
+    if (allowed.has(rec.data(), static_cast<int64_t>(rec.size())))
+      out.keep(s, e, true);
+    else
+      ++out.n_dropped;
+  }
+};
+
+// json_list_keys' sink: every item's span and the id of its key, the raw
+// bytes of the namespace and/or the name the caller reads (one unread or
+// missing is empty), ids dense in the order keys first occur. A key holds
+// raw bytes, so two escapes of one string are two keys: the caller decodes
+// the escaped ones and merges. Past `max_items` no span or id is written
+// and the counting goes on (-2).
+struct Keys {
+  const char* buf;
+  bool read_ns, read_nm;
+  int64_t* spans;
+  int32_t* ids;
+  int64_t max_items;
+  int64_t n_items = 0;
+  std::vector<int64_t> key;     // 4 a key: its first item's ns and name spans
+  std::vector<uint8_t> escaped; // a key whose bytes hold an escape
+  std::vector<uint64_t> hashes;
+  std::vector<RecordSet::Slot> table;
+  uint64_t mask = 0;
+
+  void reset() {
+    n_items = 0;
+    key.clear();
+    escaped.clear();
+    hashes.clear();
+    table.assign(16, RecordSet::Slot{-1, 0});
+    mask = 15;
+  }
+  int64_t n_keys() const { return static_cast<int64_t>(hashes.size()); }
+  void place(int32_t id) {
+    const uint64_t h = hashes[id];
+    uint64_t pos = h & mask;
+    while (table[pos].idx >= 0) pos = (pos + 1) & mask;
+    table[pos] = RecordSet::Slot{id, static_cast<uint32_t>(h >> 32)};
+  }
+  bool same(int32_t id, int64_t ns_s, int64_t ns_e, int64_t nm_s,
+            int64_t nm_e) const {
+    const int64_t* k = &key[4 * static_cast<size_t>(id)];
+    const size_t ns_len = static_cast<size_t>(ns_e - ns_s);
+    const size_t nm_len = static_cast<size_t>(nm_e - nm_s);
+    return k[1] - k[0] == ns_e - ns_s && k[3] - k[2] == nm_e - nm_s &&
+           memcmp(buf + k[0], buf + ns_s, ns_len) == 0 &&
+           memcmp(buf + k[2], buf + nm_s, nm_len) == 0;
+  }
+  __attribute__((always_inline)) void item(
+      int64_t s, int64_t e, int64_t ns_s, int64_t ns_e, bool ns_esc,
+      int64_t nm_s, int64_t nm_e, bool nm_esc) {
+    if (!read_ns || ns_s < 0) { ns_s = ns_e = 0; ns_esc = false; }
+    if (!read_nm || nm_s < 0) { nm_s = nm_e = 0; nm_esc = false; }
+    uint64_t h = RecordSet::hash(buf + ns_s, ns_e - ns_s) *
+                     0x9E3779B97F4A7C15ull ^
+                 RecordSet::hash(buf + nm_s, nm_e - nm_s);
+    h ^= h >> 29;
+    const uint32_t tag = static_cast<uint32_t>(h >> 32);
+    int32_t id = -1;
+    for (uint64_t pos = h & mask;; pos = (pos + 1) & mask) {
+      const RecordSet::Slot sl = table[pos];
+      if (sl.idx < 0) break;
+      if (sl.tag == tag && same(sl.idx, ns_s, ns_e, nm_s, nm_e)) {
+        id = sl.idx;
+        break;
+      }
+    }
+    if (id < 0) {  // a key not seen before
+      id = static_cast<int32_t>(n_keys());
+      key.insert(key.end(), {ns_s, ns_e, nm_s, nm_e});
+      escaped.push_back(ns_esc || nm_esc);
+      hashes.push_back(h);
+      if (static_cast<uint64_t>(n_keys()) * 2 > mask + 1) {
+        table.assign(2 * (mask + 1), RecordSet::Slot{-1, 0});
+        mask = 2 * mask + 1;
+        for (int32_t k = 0; k < n_keys(); ++k) place(k);
+      } else {
+        place(id);
+      }
+    }
+    if (n_items < max_items) {
+      spans[2 * n_items] = s;
+      spans[2 * n_items + 1] = e;
+      ids[n_items] = id;
+    }
+    ++n_items;
+  }
+};
+
 // One pass under one array key. -1 bails; else 0 with kind_span, arr_span
 // (-1,-1 when the key is absent: legal, the caller may only need the
-// kind to rescan a Table under "rows") and `out` filled.
+// kind to rescan a Table under "rows") and `out` handed every item as it
+// closes: its span and the raw spans of its metadata.namespace and
+// metadata.name (-1,-1 when missing), each with whether it holds an escape.
+// A sink's `item` is inlined by force: called from the scan's own lambda,
+// an out-of-line call costs the filter a quarter of its speed.
+template <typename Sink>
 static int64_t scan_list(
     const char* buf, int64_t n, const char* items_key,
     bool nested,          // false: metadata at item top level (List items);
                           // true: inside item["object"] (Table rows)
-    const RecordSet& allowed, std::string& rec,
     int64_t* kind_span,   // [2] raw value span, -1,-1 when absent
     int64_t* arr_span,    // [2] start = after '[', end = index of ']'
-    Kept& out) {
+    Sink& out) {
   Scan sc{buf, n};
   kind_span[0] = kind_span[1] = -1;
   arr_span[0] = arr_span[1] = -1;
@@ -621,20 +740,7 @@ static int64_t scan_list(
               });
     if (!walked) return false;
     // the item's span ends exclusive, after its closing '}'
-    if (nm_esc || ns_esc) {
-      out.undecided(start, sc.i, ns_s, ns_e, nm_s, nm_e);
-      return true;
-    }
-    // the record '0' ns 0x1f name (missing -> empty); JSON forbids raw
-    // control bytes in a string, so the separator cannot collide
-    rec.assign(1, '0');
-    if (ns_s >= 0) rec.append(buf + ns_s, static_cast<size_t>(ns_e - ns_s));
-    rec.push_back('\x1f');
-    if (nm_s >= 0) rec.append(buf + nm_s, static_cast<size_t>(nm_e - nm_s));
-    if (allowed.has(rec.data(), static_cast<int64_t>(rec.size())))
-      out.keep(start, sc.i, true);
-    else
-      ++out.n_dropped;
+    out.item(start, sc.i, ns_s, ns_e, ns_esc, nm_s, nm_e, nm_esc);
     return true;
   };
 
@@ -682,6 +788,36 @@ static int64_t scan_list(
   return 0;
 }
 
+// A *List or Table body through `out`, under the array key its kind names:
+// a cheap sniff of the kind picks the key, so the common case is ONE pass;
+// a Table with unusual kind spacing pays a second (`out.reset()` before
+// each). -1 bails, also where the body is neither a Table nor a *List.
+template <typename Sink>
+static int64_t scan_body(const char* buf, int64_t n, int64_t* arr_span,
+                         Sink& out) {
+  int64_t kind_span[2];
+  auto holds = [&](const char* lit) {
+    return memmem(buf, static_cast<size_t>(n), lit, strlen(lit)) != nullptr;
+  };
+  bool table = holds("\"kind\":\"Table\"") || holds("\"kind\": \"Table\"");
+  while (true) {
+    out.reset();
+    if (scan_list(buf, n, table ? "rows" : "items", table, kind_span,
+                  arr_span, out) < 0)
+      return -1;
+    const int64_t klen = kind_span[0] < 0 ? 0 : kind_span[1] - kind_span[0];
+    const char* kind = buf + (klen ? kind_span[0] : 0);
+    const bool is_table = klen == 5 && memcmp(kind, "Table", 5) == 0;
+    if (is_table != table) {
+      table = is_table;  // the sniff guessed wrong: once more, the other
+      continue;          // key (the kind read is the same, so only once)
+    }
+    if (!is_table && !(klen >= 4 && memcmp(kind + klen - 4, "List", 4) == 0))
+      return -1;  // a single object: the Python path
+    return 0;
+  }
+}
+
 }  // namespace jsonscan
 
 extern "C" int64_t json_list_filter(
@@ -697,39 +833,65 @@ extern "C" int64_t json_list_filter(
     int64_t* counts) {       // [3] out: items dropped, runs, undecided
   if (n_recs < 0 || n_recs > INT32_MAX) return -1;
   const jsonscan::RecordSet allowed(rec_buf, rec_off, n_recs);
-  std::string rec;
-  int64_t kind_span[2];
-  auto holds = [&](const char* lit) {
-    return memmem(buf, static_cast<size_t>(n), lit, strlen(lit)) != nullptr;
-  };
-  // a cheap sniff of the kind picks the array key, so the common case is
-  // ONE pass; a Table with unusual kind spacing pays a second
-  bool table = holds("\"kind\":\"Table\"") || holds("\"kind\": \"Table\"");
-  while (true) {
-    jsonscan::Kept out{runs, max_runs, esc, max_esc};
-    if (jsonscan::scan_list(buf, n, table ? "rows" : "items", table,
-                            allowed, rec, kind_span, arr_span, out) < 0)
-      return -1;
-    const int64_t klen = kind_span[0] < 0 ? 0 : kind_span[1] - kind_span[0];
-    const char* kind = buf + (klen ? kind_span[0] : 0);
-    const bool is_table = klen == 5 && memcmp(kind, "Table", 5) == 0;
-    if (is_table != table) {
-      table = is_table;  // the sniff guessed wrong: once more, the other
-      continue;          // key (the kind read is the same, so only once)
+  jsonscan::Decide out{buf, allowed, {runs, max_runs, esc, max_esc}, {}};
+  if (jsonscan::scan_body(buf, n, arr_span, out) < 0) return -1;
+  counts[0] = out.out.n_dropped;
+  counts[1] = out.out.n_runs;
+  counts[2] = out.out.n_esc;
+  return (out.out.n_runs > max_runs || out.out.n_esc > max_esc) ? -2 : 0;
+}
+
+// JSON list keys (authz/postfilter.py): the same one pass over a kube *List
+// or Table body as json_list_filter, for a caller that decides items only
+// after it has seen their keys. It gives back every item's byte span and
+// the id of its key (the namespace and/or name `read` names, bit 0 and bit
+// 1; what is unread or missing is empty), and each distinct key once, in
+// the order keys first occur: the raw bytes of every key's namespace, each
+// ended by 0x1e, then of every key's name likewise (a raw JSON string holds
+// no byte under 0x20, so the separator cannot collide), and the ids of the
+// keys whose bytes hold an escape, for the caller to decode exactly. Bails
+// (-1) where json_list_filter does; -2 when `max_items` is too small
+// (counts[0] then says what it takes). counts: items, keys, bytes of
+// `keys`, escaped keys.
+extern "C" int64_t json_list_keys(
+    const char* buf, int64_t n, int64_t read,
+    int64_t* arr_span,       // [2] out; -1,-1: the array key is absent
+    int64_t* spans,          // [2 * max_items] out: each item's byte span
+    int32_t* ids,            // [max_items] out: each item's key id
+    int64_t max_items,
+    char* keys,              // out, room for n + 2 * max_items bytes
+    int32_t* esc,            // [max_items] out: ids of escaped keys
+    int64_t* counts) {       // [4] out
+  if (n > INT32_MAX) return -1;
+  jsonscan::Keys out{buf, (read & 1) != 0, (read & 2) != 0, spans, ids,
+                     max_items};
+  if (jsonscan::scan_body(buf, n, arr_span, out) < 0) return -1;
+  counts[0] = out.n_items;
+  if (out.n_items > max_items) return -2;
+  const int64_t k = out.n_keys();
+  char* at = keys;
+  for (int half = 0; half < 2; ++half) {
+    for (int64_t i = 0; i < k; ++i) {
+      const int64_t s = out.key[4 * i + 2 * half];
+      const int64_t e = out.key[4 * i + 2 * half + 1];
+      memcpy(at, buf + s, static_cast<size_t>(e - s));
+      at += e - s;
+      *at++ = '\x1e';
     }
-    if (!is_table && !(klen >= 4 && memcmp(kind + klen - 4, "List", 4) == 0))
-      return -1;  // a single object: the Python path
-    counts[0] = out.n_dropped;
-    counts[1] = out.n_runs;
-    counts[2] = out.n_esc;
-    return (out.n_runs > max_runs || out.n_esc > max_esc) ? -2 : 0;
   }
+  int64_t n_esc = 0;
+  for (int64_t i = 0; i < k; ++i)
+    if (out.escaped[i]) esc[n_esc++] = static_cast<int32_t>(i);
+  counts[1] = k;
+  counts[2] = at - keys;
+  counts[3] = n_esc;
+  return 0;
 }
 
 // Bumped on ANY exported-signature change: the loader refuses a library
 // whose ABI differs (a stale cached .so with preserved mtimes would
 // otherwise bind by name and silently misread arguments).
-extern "C" int64_t graphcore_abi_version() { return 5; }
+extern "C" int64_t graphcore_abi_version() { return 6; }
 
 // ---------------------------------------------------------------------------
 // Protobuf list scanner (authz/filterer.py filter_body_proto): one pass

@@ -165,6 +165,8 @@ def readings() -> dict:
     out["engine_bulk_cache_seconds"] = metrics.histogram(
         "engine_bulk_cache_seconds").n
     out.update((c, metrics.counter(c).value) for c in COUNTERS)
+    out["native_lists"] = metrics.counter("proxy_postfilter_total",
+                                          path="native").value
     return out
 
 
@@ -213,6 +215,9 @@ def test_served_lists_name_what_the_reference_and_the_oracle_name(
         assert delta[hist] == LISTS, hist
     assert delta["proxy_postfilter_items_total"] == LISTS * n_objects
     assert delta["proxy_postfilter_kept_total"] == kept
+    # the rule reads nothing of an object but its namespace: the native
+    # scanner reads every list, List or Table
+    assert delta["native_lists"] == LISTS
     # the rule reads the object's namespace and the user: it is resolved
     # once a namespace that holds a service, and that is one check. Every
     # user is new to the cache, so every check is dispatched, and each is

@@ -965,6 +965,8 @@ def test_a_collectors_poll_is_one_observation_of_the_lock_wait(
     came back early (a clock's step) observes 0, never less."""
     from spicedb_kubeapi_proxy_tpu.obs import profile
 
+    for thread in _collector_threads():  # an earlier test's, on its way out
+        thread.join(10)
     monkeypatch.setattr(profile, "GC_POLL_S", 0.002)
     if not sleeps:
         monkeypatch.setattr(time, "sleep", lambda s: None)

@@ -22,23 +22,27 @@ items are written back as the upstream's own bytes. Otherwise (a rule
 reads ``object``, ``metadata`` or ``this``; the scanner refuses the body;
 no native library) the body goes through ``json.loads`` and the kept
 objects through ``json.dumps``; both paths ask the same checks in the
-same order. A 10,000-service list (1.19 MB) in 6,293 namespaces takes
-54.0 ms of a v5e host, 84.6 by the json path: parse 3.1 (17.3), resolve
-21.4 (27.8; 3.4 us a distinct namespace), the cache's passes 14.8,
-encode to device wait 9.9 of which the device works 0.68, write 2.2
-(4.9) (PERF.md section 5).
+same order. A rule whose every per-object field is a bare root (the
+common rule's ``{{namespace}}``; ``RelExpr.columns``) evaluates no
+template a key: its checks are zipped from the distinct keys' columns
+(``_column_checks``), in the per-key loop's order. A 10,000-service list
+(1.19 MB) in 6,303 namespaces takes 40.2 ms of a v5e host: parse 3.1,
+resolve 9.4 (21.5 with a template evaluated a key; what is left is a
+``CheckItem`` a check), the cache's passes 14.7, encode to device wait
+9.2 of which the device works 0.68, write 1.4 (PERF.md section 5).
 
 Stage ``postfilter`` (``proxy_postfilter_seconds``) brackets one
 filtered list; inside it ``postfilter_parse``, ``postfilter_resolve``
 and ``postfilter_write`` bracket the three host steps, and the bulk check
 between them is the engine's own stages. ``proxy_postfilter_total{path}``
-says which path read a list.
+says which path read a list, ``proxy_postfilter_column_resolved_total``
+how many of ``proxy_postfilter_resolved_total`` were read off columns.
 """
 
 from __future__ import annotations
 
 import json
-from itertools import compress
+from itertools import chain, compress, count
 from operator import and_
 from typing import Optional, Sequence
 
@@ -58,24 +62,42 @@ from ..utils.metrics import metrics
 # of it than both
 NAMESPACE_ROOTS = frozenset({"namespace", "namespacedName", "resourceId"})
 NAME_ROOTS = frozenset({"name", "namespacedName", "resourceId"})
+JOINED_ROOTS = NAMESPACE_ROOTS & NAME_ROOTS
 OBJECT_ROOTS = frozenset({"object", "metadata", "this"})
 
 
 def _rule_checks(rel: RelationshipExpr, data: dict,
                  checks: dict[RelFields, int], namespaces: list[str],
-                 names: list[str], objs: Sequence[dict] = ()
-                 ) -> tuple[list[int], list[int], Sequence[int]]:
+                 names: list[str], objs: Sequence[dict] = (),
+                 scanned: bool = False
+                 ) -> tuple[list[int], list[int], Sequence[int], bool]:
     """One postfilter rule over the objects of one list -> (the places in
     ``checks`` of every resolution's checks, one resolution after the
     other; where each resolution's end among them; object -> its
-    resolution).
+    resolution; whether the resolutions were read off columns).
 
     An object is keyed by the values of the per-object roots the rule's
     templates read (``RelationshipExpr.refs``, known since the rule
     compiled): the templates are resolved once a distinct key, and every
     object with that key shares the checks. A rule that reads ``object``
-    or ``metadata`` has no such key: it resolves per object of ``objs``."""
+    or ``metadata`` has no key: it resolves per object of ``objs``.
+    ``scanned``: the (namespace, name) pairs are the native scanner's
+    distinct keys. Where each of the rule's per-object fields is a bare
+    root (``RelationshipExpr.columns``), the distinct keys' checks are
+    zipped from their columns, no template evaluated for a key: the same
+    checks in the same order as the per-key loop."""
     reads = rel.refs & ITEM_ROOTS
+    if not reads & OBJECT_ROOTS:
+        key_ns, key_names, resolution = _keys(reads, namespaces, names,
+                                              scanned)
+        columns = rel.columns
+        # (a value of the json path that is not a string is made one by
+        # the per-key loop's templates)
+        if columns is not None and (scanned or all(
+                isinstance(v, str) for v in chain(key_ns, key_names))):
+            asked = _column_checks(rel, data, checks, columns, key_ns,
+                                   key_names)
+            return asked, list(range(1, len(asked) + 1)), resolution, True
     resolve = rel.per_list(data)
     asked: list[int] = []
     ends: list[int] = []
@@ -97,17 +119,43 @@ def _rule_checks(rel: RelationshipExpr, data: dict,
             else:
                 data.pop("metadata", None)
             ask(namespace, name)
-        return asked, ends, range(len(objs))
-    # the part of (namespace, name) the rule cannot read is left out of
-    # the key: one that reads neither is resolved once a list
-    unread = [""] * len(names)
-    keys = list(zip(namespaces if reads & NAMESPACE_ROOTS else unread,
-                    names if reads & NAME_ROOTS else unread))
-    place = {}
-    for key in dict.fromkeys(keys):
-        place[key] = len(ends)
+        return asked, ends, range(len(objs)), False
+    for key in zip(key_ns, key_names):
         ask(*key)
-    return asked, ends, list(map(place.__getitem__, keys))
+    return asked, ends, resolution, False
+
+
+def _keys(reads: frozenset, namespaces: list[str], names: list[str],
+          scanned: bool) -> tuple[list[str], list[str], Sequence[int]]:
+    """The distinct keys of a rule that reads ``reads`` of the objects, in
+    the order they first occur -> (their namespaces, their names, object
+    -> its key). The part of (namespace, name) the rule cannot read is
+    left out of the key: one that reads neither has one key a list."""
+    unread = [""] * len(names)
+    key_ns = namespaces if reads & NAMESPACE_ROOTS else unread
+    key_names = names if reads & NAME_ROOTS else unread
+    if scanned and key_ns == namespaces and key_names == names:
+        # the scanner's keys, each its own
+        return key_ns, key_names, range(len(names))
+    keys = list(zip(key_ns, key_names))
+    place = dict(zip(dict.fromkeys(keys), count()))
+    return ([ns for ns, _ in place], [name for _, name in place],
+            list(map(place.__getitem__, keys)))
+
+
+def _column_checks(rel: RelationshipExpr, data: dict,
+                   checks: dict[RelFields, int], columns: dict[int, str],
+                   key_ns: list[str], key_names: list[str]) -> list[int]:
+    """The checks of a rule whose per-object fields are the bare roots
+    ``columns`` names, one a key, entered in ``checks`` as the per-key
+    loop enters them -> their places there."""
+    roots = {"namespace": key_ns, "name": key_names}
+    if JOINED_ROOTS & set(columns.values()):
+        roots["namespacedName"] = roots["resourceId"] = [
+            f"{ns}/{name}" if ns else name
+            for ns, name in zip(key_ns, key_names)]
+    return [checks.setdefault(fields, len(checks))
+            for fields in rel.resolve_columns(data, roots, len(key_ns))]
 
 
 def _unescape(raw: str) -> str:
@@ -220,7 +268,8 @@ def _filter(engine: Engine, post_filters: list[PostFilter],
         if names:  # no object, no template resolved: nothing to refuse
             data = input.template_data()
             rules = [_rule_checks(pf.rel, data, checks, namespaces, names,
-                                  objs) for pf in post_filters]
+                                  objs, scan is not None)
+                     for pf in post_filters]
         items = [CheckItem(rtype, rid, rel, stype, sid, srel or None)
                  for rtype, rid, rel, stype, sid, srel in checks]
     results = (engine.check_bulk(items, context=context) if context
@@ -235,7 +284,7 @@ def _filter(engine: Engine, post_filters: list[PostFilter],
                 resp.body, rules, verdicts, len(names), *scan[:4])
         else:
             keep = [True] * len(objs)
-            for asked, ends, resolution in rules:
+            for asked, ends, resolution, _ in rules:
                 ok = _passed(verdicts, asked, ends).tolist()
                 keep = map(and_, keep, map(ok.__getitem__, resolution))
             kept = list(compress(entries, keep))
@@ -246,7 +295,9 @@ def _filter(engine: Engine, post_filters: list[PostFilter],
             body = json.dumps(doc).encode()
             n_items, n_kept = len(objs), len(kept)
     metrics.counter("proxy_postfilter_resolved_total").inc(
-        sum(len(ends) for _, ends, _ in rules))
+        sum(len(ends) for _, ends, _, _ in rules))
+    metrics.counter("proxy_postfilter_column_resolved_total").inc(
+        sum(len(ends) for _, ends, _, by_column in rules if by_column))
     metrics.counter("proxy_postfilter_items_total").inc(n_items)
     metrics.counter("proxy_postfilter_kept_total").inc(n_kept)
     headers = dict(resp.headers)
@@ -261,7 +312,7 @@ def _write_scanned(body: bytes, rules: list, verdicts: np.ndarray,
     the kept items' own bytes joined back between the array's brackets,
     items, kept): a verdict a key, spread to the items by their key ids."""
     ok = np.ones(n_keys, bool)
-    for asked, ends, resolution in rules:
+    for asked, ends, resolution, _ in rules:
         passed = _passed(verdicts, asked, ends)
         # as many resolutions as keys: each key its own, in order
         ok &= passed if len(ends) == n_keys else passed[resolution]

@@ -9,7 +9,8 @@ relationship strings, and `if` conditions compile to boolean programs.
 from __future__ import annotations
 
 from dataclasses import dataclass, field
-from typing import Callable, Optional
+from itertools import repeat
+from typing import Callable, Iterator, Optional, Sequence
 
 from ..models.tuples import TupleError, parse_rel_fields
 from .expr import CompiledExpr, ExprError, compile_expr, compile_template
@@ -53,10 +54,38 @@ ITEM_ROOTS = frozenset({"name", "namespace", "namespacedName", "resourceId",
                         "object", "metadata", "this"})
 
 
+# the item roots a list can hand a rule as columns: an object's namespace,
+# its name, and their join (``{ns}/{name}``, the name alone where there is
+# no namespace) under its two names
+COLUMN_ROOTS = frozenset({"namespace", "name", "namespacedName",
+                          "resourceId"})
+
+
 _REL_FIELDS = ("resource_type", "resource_id", "resource_relation",
                "subject_type", "subject_id", "subject_relation")
 # a relationship's six fields, in ResolvedRel's order
 RelFields = tuple[str, str, str, str, str, str]
+
+
+def _column_root(expr: CompiledExpr) -> Optional[str]:
+    """The item root that ``expr`` is, bare (``{{namespace}}``,
+    ``{{ name }}``: whitespace is insignificant); None where it is
+    anything else."""
+    src = "".join(expr.source.split())
+    return src if src in COLUMN_ROOTS and src in expr.refs else None
+
+
+def _field(data: dict, name: str, expr: Optional[CompiledExpr]) -> str:
+    """One relationship field evaluated against ``data``."""
+    if expr is None:  # no subject relation
+        return ""
+    try:
+        v = expr.evaluate_str(data)
+    except ExprError as e:
+        raise ExprError(f"resolving relationship: {e}") from None
+    if not v and name != "subject_relation":
+        raise ExprError(f"relationship field {name} resolved empty")
+    return v
 
 
 class RelationshipExpr:
@@ -79,6 +108,13 @@ class RelationshipExpr:
         evaluates the rest against ``data`` as it then stands and
         returns the fields of what ``generate`` would for that input."""
         raise NotImplementedError
+
+    @property
+    def columns(self) -> Optional[dict[int, str]]:
+        """Where the expression reads the item roots only as whole fields
+        that are bare ``COLUMN_ROOTS``: the place of each such field ->
+        the root it is (``RelExpr.resolve_columns``); None otherwise."""
+        return None
 
 
 @dataclass
@@ -103,29 +139,57 @@ class RelExpr(RelationshipExpr):
                 for f in self.per_list(input.template_data())()]
 
     def per_list(self, data: dict) -> Callable[[], list[RelFields]]:
-        def evaluate(name: str, expr: Optional[CompiledExpr]) -> str:
-            if expr is None:  # no subject relation
-                return ""
-            try:
-                v = expr.evaluate_str(data)
-            except ExprError as e:
-                raise ExprError(f"resolving relationship: {e}") from None
-            if not v and name != "subject_relation":
-                raise ExprError(f"relationship field {name} resolved empty")
-            return v
-
         fields = [(f_, getattr(self, f_)) for f_ in _REL_FIELDS]
         per_item = [i for i, (_, e) in enumerate(fields)
                     if e is not None and e.refs & ITEM_ROOTS]
-        values = [None if i in per_item else evaluate(*f)
+        values = [None if i in per_item else _field(data, *f)
                   for i, f in enumerate(fields)]
 
         def resolve() -> list[RelFields]:
             for i in per_item:
-                values[i] = evaluate(*fields[i])
+                values[i] = _field(data, *fields[i])
             return [tuple(values)]
 
         return resolve
+
+    @property
+    def columns(self) -> Optional[dict[int, str]]:
+        """Derived from the field expressions, as ``PreFilter.
+        mapping_kind`` is: a field that reads an item root is a column
+        where it is a bare one (``{{namespace}}``, ``{{ name }}``), and
+        not where it reads one inside an expression
+        (``{{split_name(resourceId)}}``, ``{{object.metadata.name}}``)."""
+        places = {}
+        for i, f_ in enumerate(_REL_FIELDS):
+            expr = getattr(self, f_)
+            if expr is not None and expr.refs & ITEM_ROOTS:
+                places[i] = _column_root(expr)
+                if places[i] is None:
+                    return None
+        return places
+
+    def resolve_columns(self, data: dict, roots: dict[str, Sequence[str]],
+                        n: int) -> Iterator[RelFields]:
+        """Where ``columns`` is not None: ``per_list(data)`` for n objects
+        whose item roots come as columns of strings (``roots``: each root
+        that ``columns`` names -> its value for every object, in order)
+        -> the fields each object's call would return, in order, zipped
+        from the columns with no template evaluated; what reads no item
+        root is evaluated once, as ``per_list`` does. Raises what the
+        first failing call would: a field but ``subject_relation`` that is
+        empty for some object."""
+        places = self.columns
+        values = [None if i in places else
+                  _field(data, f_, getattr(self, f_))
+                  for i, f_ in enumerate(_REL_FIELDS)]
+        empty = [(roots[root].index(""), i) for i, root in places.items()
+                 if _REL_FIELDS[i] != "subject_relation"
+                 and "" in roots[root]]
+        if empty:
+            raise ExprError(f"relationship field {_REL_FIELDS[min(empty)[1]]}"
+                            " resolved empty")
+        return zip(*[roots[places[i]] if i in places else repeat(v, n)
+                     for i, v in enumerate(values)])
 
 
 @dataclass

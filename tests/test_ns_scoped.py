@@ -36,6 +36,7 @@ STAGES = {"postfilter": "proxy_postfilter_seconds",
           "postfilter_write": "proxy_postfilter_write_seconds"}
 COUNTERS = ("proxy_postfilter_items_total", "proxy_postfilter_kept_total",
             "proxy_postfilter_resolved_total",
+            "proxy_postfilter_column_resolved_total",
             "engine_checks_total", "engine_checks_distinct_total")
 PROTOBUF = "application/vnd.kubernetes.protobuf,application/json"
 TABLE = "application/json;as=Table;v=v1;g=meta.k8s.io,application/json"
@@ -225,6 +226,8 @@ def test_served_lists_name_what_the_reference_and_the_oracle_name(
     held = scoped.held
     assert held < n_objects
     assert delta["proxy_postfilter_resolved_total"] == LISTS * held
+    # a bare {{namespace}}: every resolution is read off the key columns
+    assert delta["proxy_postfilter_column_resolved_total"] == LISTS * held
     assert delta["engine_checks_total"] == LISTS * held
     assert delta["engine_checks_distinct_total"] == LISTS * held
     spans = [s["name"] for t in tracer.recent() for s in t["spans"]]

@@ -587,9 +587,12 @@ class Engine:
         metrics.gauge("engine_feeder_ranges").set(cg.feeder_ranges())
         # of the residual: the edges out of a subject's own range, which
         # a dispatch finds from its seeds, the padded rest that every
-        # dispatch walks, and how many entries a seed looks up
+        # dispatch walks (and how much of that is padding), and how many
+        # entries a seed looks up
         metrics.gauge("engine_seed_edges").set(cg.seed_edges())
         metrics.gauge("engine_walked_edges").set(cg.walked_edges())
+        metrics.gauge("engine_walked_padding_edges").set(
+            cg.walked_padding_edges())
         metrics.gauge("engine_seed_fanout").set(cg.seed_fanout())
         metrics.gauge("engine_delta_occupancy").set(cg.n_delta)
         if cg.tier is not None:

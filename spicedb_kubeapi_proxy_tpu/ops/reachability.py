@@ -186,6 +186,23 @@ def _next_bucket(n: int, minimum: int = 8) -> int:
     return b
 
 
+# from this many edges a phase's walked part is padded to an eighth of an
+# octave instead of a power of two (``_walked_bucket``)
+WALKED_FINE_MIN = 4096
+
+
+def _walked_bucket(n: int) -> int:
+    """Padded size of a phase's walked part, which every dispatch gathers
+    and scatters whole: ``_next_bucket`` below ``WALKED_FINE_MIN`` edges,
+    from there the next multiple of 2^(floor(log2 n) - 3). That is at
+    most eight sizes an octave, under 12.5% padding where a power of two
+    pads up to 100%, and every size a multiple of 512."""
+    if n < WALKED_FINE_MIN:
+        return _next_bucket(n)
+    step = 1 << (n.bit_length() - 4)
+    return -(-n // step) * step
+
+
 # Every slot range is padded to a multiple of LANE so the state tensor can
 # live as [B, rows, LANE] with ranges row-aligned: slot s = (s // LANE,
 # s % LANE). Without this, a [M, B] layout at B=1 pads the lane axis 1->128
@@ -1425,6 +1442,14 @@ class CompiledGraph:
         return int(bounds[-1] - bounds[0] - (
             0 if seed is None else sum(seed.lengths(bounds))))
 
+    def walked_padding_edges(self) -> int:
+        """Of ``walked_edges``, the trash entries that pad each phase's
+        walked part to its bucket (``_walked_bucket``): gathered and
+        scattered by every dispatch for nothing."""
+        real = (self.n_edges if self.res_idx is None
+                else len(self.res_idx)) - self.seed_edges()
+        return self.walked_edges() - real
+
     def seed_fanout(self) -> int:
         """Sum of the phases' D_k: a row's seed looks up that many
         entries a dispatch, twice (subject and wildcard)."""
@@ -2338,11 +2363,13 @@ def compile_graph(schema: Schema, snapshot: Snapshot,
 
     # padded host residual views, one slice per phase in order of
     # execution (res_level_bounds: feeder levels, entry, core, levels).
-    # A slice is two parts, each padded to its own power-of-two bucket so
-    # the bounds (part of the jit signature) stay stable as edge counts
-    # drift between recompiles: the *walked* part, dst-sorted for
-    # segment_max's indices_are_sorted, then the *seeded* part
-    # (SeedLayout): the edges that start in a ``__self`` range, sorted by
+    # A slice is two parts, each padded to its own bucket so the bounds
+    # (part of the jit signature) stay stable as edge counts drift
+    # between recompiles: the *walked* part, dst-sorted for segment_max's
+    # indices_are_sorted and padded by ``_walked_bucket`` (every dispatch
+    # walks it whole), then the *seeded* part (SeedLayout), padded to a
+    # power of two (looked up, not walked, and its size drifts with the
+    # seed): the edges that start in a ``__self`` range, sorted by
     # (src, dst) under a row pointer per source, but for the sources
     # whose run is longer than the phase's fan-out, which stay walked
     n_res = len(res_idx)
@@ -2367,7 +2394,7 @@ def compile_graph(schema: Schema, snapshot: Snapshot,
         fanout.append(d_k if len(t) else 0)
     bounds, seed_starts = [0], []
     for w, t in zip(walked, seeded):
-        seed_starts.append(bounds[-1] + _next_bucket(max(len(w), 1)))
+        seed_starts.append(bounds[-1] + _walked_bucket(max(len(w), 1)))
         bounds.append(seed_starts[-1]
                       + (_next_bucket(len(t)) if len(t) else 0))
     res_level_bounds = tuple(bounds)

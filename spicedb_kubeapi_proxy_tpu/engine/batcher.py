@@ -329,7 +329,6 @@ class LookupBatcher:
                 _rt, _perm, st, sid, srl = item.args
                 seeds[i] = cg.encode_subject(st, sid, srl, objs)
         t0 = time.perf_counter()
-        e._apply_crossover(cg)
         qfut = cg.query_async(seeds, None, None,
                               q_contig_grid=(off, n, FUSED_ROWS))
         enqueued.append(qfut)
@@ -356,7 +355,6 @@ class LookupBatcher:
                     metrics.counter(
                         "engine_caveat_denied_missing_context_total"
                     ).inc(missing)
-                e._count_semiring_modes((qfut,))
             return mask_pseudo_objects(
                 np.array(out[i * n:(i + 1) * n])), interner
 

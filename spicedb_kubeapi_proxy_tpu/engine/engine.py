@@ -23,7 +23,6 @@ from ..models.schema import Schema
 from ..models.tuples import Relationship
 from ..obs.profile import install_jax_compile_hook
 from ..obs.trace import tracer
-from ..ops import semiring
 from ..ops.reachability import (
     CompiledGraph,
     DELTA_CAPACITY,
@@ -309,12 +308,6 @@ class Engine:
         # rebuilding 2x400KB of arange/zeros per request is waste (their
         # DEVICE copies are already cached per key in query_async)
         self._q_host: dict[tuple, tuple] = {}
-        # frontier-occupancy EWMA feeding the semiring push/pull
-        # crossover: every mask-lookup readback contributes its observed
-        # final-frontier fill fraction (the engine_frontier_occupancy
-        # signal), and the resulting threshold rides each dispatch as a
-        # TRACED scalar — retuning it never recompiles
-        self._occ_ewma: Optional[float] = None
         # optional jax.sharding.Mesh ("data", "graph" axes): queries route
         # through a ShardedGraph pinned across it instead of one device
         self.mesh = mesh
@@ -991,7 +984,6 @@ class Engine:
             cg = self.compiled()
         objs = self._objects_by_name()
         t0 = time.perf_counter()
-        self._apply_crossover(cg)
         backend = self._backend(cg)
         n = len(items)
         chunk = self.CHECK_PIPELINE_CHUNK
@@ -1046,7 +1038,6 @@ class Engine:
             wait.set("fixpoint_iters", it)
             wait.set("core_edges", cg.core_edges())
             metrics.histogram("engine_fixpoint_iterations").observe(it)
-            self._count_semiring_modes(futs)
             # caveat instances that resolved missing-context this call:
             # denied fail-closed, and LOUD — this counter replaces the
             # old silent load-time exclusion of conditional grants.
@@ -1244,47 +1235,6 @@ class Engine:
                                    subject_id, subject_relation, now,
                                    context)
 
-    # -- semiring mode feedback ---------------------------------------------
-
-    def _apply_crossover(self, cg: CompiledGraph) -> None:
-        """Stamp the occupancy-derived push/pull crossover onto the
-        snapshot about to dispatch. It rides the dispatch as a TRACED
-        scalar (ops/semiring.propagate branches on it with lax.cond), so
-        retuning per request costs zero recompiles. A freshly compiled
-        graph starts back at 1.0 (always-push) only until the engine's
-        EWMA re-stamps it here."""
-        cg.spmm_crossover = semiring.crossover_from_occupancy(
-            self._occ_ewma)
-        # the crossover was invisible to operators before this gauge:
-        # auto mode's push/pull choice is made ON DEVICE per iteration,
-        # and the only host-side artifacts are this threshold and the
-        # per-mode step counters below
-        metrics.gauge("engine_semiring_crossover").set(cg.spmm_crossover)
-
-    def _observe_occupancy(self, frac: float) -> None:
-        """Fold one observed final-frontier fill fraction ([0, 1], from
-        the ``engine_frontier_occupancy`` readback accounting) into the
-        EWMA that drives :meth:`_apply_crossover`."""
-        e = self._occ_ewma
-        self._occ_ewma = frac if e is None else 0.9 * e + 0.1 * frac
-
-    @staticmethod
-    def _count_semiring_modes(futs) -> None:
-        """Per-mode hop counters off completed futures: how many semiring
-        hops took the push (bit-packed) vs pull (dense matmul) branch.
-        ``push_steps`` may exceed ``iterations()`` (acyclic level
-        applications count toward pushes but not core iterations), so the
-        pull share clamps at zero."""
-        push = pull = 0
-        for f in futs:
-            p = getattr(f, "push_steps", lambda: 0)()
-            push += p
-            pull += max(f.iterations() - p, 0)
-        if push:
-            metrics.counter("engine_semiring_push_steps_total").inc(push)
-        if pull:
-            metrics.counter("engine_semiring_pull_steps_total").inc(pull)
-
     def _lookup_direct(self, resource_type: str, permission: str,
                        subject_type: str, subject_id: str,
                        subject_relation: Optional[str],
@@ -1333,7 +1283,6 @@ class Engine:
         # the query arrays are a pure function of (type, permission) slot
         # layout: cache their device copies across queries (the ~0.5MB
         # upload per 100k-object lookup is otherwise paid on every call)
-        self._apply_crossover(cg)
         fut = self._backend(cg).query_async(
             seeds, q_slots, q_batch, now=now,
             q_cache_key=("lookup", off, n), q_contiguous=True,
@@ -1372,11 +1321,6 @@ class Engine:
                 "engine_frontier_occupancy",
                 buckets=(0, 1, 8, 64, 512, 4096, 32768, 262144, 2**21),
             ).observe(occ)
-            # ... and close the loop: the observed fill fraction feeds
-            # the EWMA behind the semiring push/pull crossover, so dense
-            # workloads drift the dense phase onto the MXU pull path
-            self._observe_occupancy(float(occ) / max(m.size, 1))
-            self._count_semiring_modes((fut,))
             return m, interner
 
         return EngineFuture(None, fin, iters=fut.iterations)

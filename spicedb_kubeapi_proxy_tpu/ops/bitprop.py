@@ -77,19 +77,10 @@ def eligible(n_dst: int, n_src: int) -> bool:
 
 
 def kernel_enabled() -> bool:
-    """Bit kernel runs on TPU; tests force the interpreter with
-    SDBKP_BITPROP=interpret (CPU default stays on the matmul path). The
-    BitKernel feature gate turns it off wholesale."""
-    from ..utils.features import features
-
-    if not features.enabled("BitKernel"):
-        return False
-    mode = os.environ.get("SDBKP_BITPROP", "auto")
-    if mode == "0":
-        return False
-    if mode == "interpret":
-        return True
-    return jax.default_backend() == "tpu"
+    """Bit kernel runs on TPU; elsewhere only in the Pallas interpreter,
+    which SDBKP_BITPROP=interpret selects (the CPU tests' seam)."""
+    return os.environ.get("SDBKP_BITPROP") == "interpret" \
+        or jax.default_backend() == "tpu"
 
 
 def _interpret() -> bool:
@@ -176,7 +167,7 @@ def bit_or_matmul(a_bits: jax.Array, v_bits: jax.Array, n_b: int) -> jax.Array:
 
 
 # ---------------------------------------------------------------------------
-# MXU-shaped dense kernel: the semiring PULL path (ops/semiring.py)
+# MXU-shaped dense kernel: batches beyond BIT_B_MAX (ops/semiring.py)
 # ---------------------------------------------------------------------------
 
 # the MXU systolic array is 128x128; the dense kernel's grid tiles both
@@ -190,21 +181,13 @@ DENSE_B_MAX = 4096
 
 
 def dense_kernel_enabled() -> bool:
-    """Dense MXU Pallas kernel runs on TPU; tests force the interpreter
-    with SDBKP_SEMIRING=interpret (CPU default stays on dot_general).
-    The SemiringDenseKernel feature gate turns it off wholesale. Part of
-    the jit-cache key (reachability._jit_run_for) — flipping it never
-    reuses a stale trace."""
-    from ..utils.features import features
-
-    if not features.enabled("SemiringDenseKernel"):
-        return False
-    mode = os.environ.get("SDBKP_SEMIRING", "auto")
-    if mode == "0":
-        return False
-    if mode == "interpret":
-        return True
-    return jax.default_backend() == "tpu"
+    """Dense MXU Pallas kernel runs on TPU; elsewhere only in the Pallas
+    interpreter, which SDBKP_SEMIRING=interpret selects (the CPU tests'
+    seam; otherwise dot_general). Part of the jit-cache key
+    (reachability._jit_run_for) — flipping it never reuses a stale
+    trace."""
+    return os.environ.get("SDBKP_SEMIRING") == "interpret" \
+        or jax.default_backend() == "tpu"
 
 
 def _dense_interpret() -> bool:
@@ -238,10 +221,10 @@ def _dense_kernel(f_ref, a_ref, out_ref):
     The out tile is revisited across the src-tile grid axis (zeroed at
     the first step) — the standard Pallas accumulation pattern; the
     frontier-tile emptiness predicate skips the matmul for all-zero
-    frontier chunks, the push-flavored work skip that makes the pull
-    kernel cheap on sparse iterations too. The predicate reduces the
-    tile widened to int32: Mosaic has no relayout for the i1 mask of an
-    int8 compare ((32,128) tiling) into the reduction's (8,128) one."""
+    frontier chunks, which makes the kernel cheap on sparse iterations.
+    The predicate reduces the tile widened to int32: Mosaic has no
+    relayout for the i1 mask of an int8 compare ((32,128) tiling) into
+    the reduction's (8,128) one."""
     from jax.experimental import pallas as pl
 
     @pl.when(pl.program_id(1) == 0)

@@ -138,9 +138,9 @@ def _jit_run_for(cg: "CompiledGraph", active: Optional[tuple] = None):
     buffers for as long as the cache entry lives — a dead-revision memory
     leak proportional to graph size x cached signatures.
 
-    Kernel/mode toggles that are baked into traces (bit kernel, dense
-    Pallas kernel, forced semiring mode) discriminate the key — flipping
-    one mid-process gets a fresh trace, never a stale one.
+    The kernel toggles that are baked into traces (bit kernel, dense
+    Pallas kernel) discriminate the key — flipping one mid-process gets
+    a fresh trace, never a stale one.
 
     ``active``: tiered dispatch passes the demand-set block indices
     (sorted tuple) — the trace consumes exactly those blocks. The key is
@@ -148,8 +148,7 @@ def _jit_run_for(cg: "CompiledGraph", active: Optional[tuple] = None):
     of residency, so promote/demote churn cannot cause a retrace."""
     global _TRACE_BUILDS
     sig = (cg.signature(), bitprop.kernel_enabled(),
-           bitprop.dense_kernel_enabled(), semiring.resolved_mode(),
-           active)
+           bitprop.dense_kernel_enabled(), active)
     with _DEV_INIT_LOCK:
         run = _JIT_CACHE.get(sig)
         if run is None:
@@ -569,10 +568,6 @@ class RunMeta:
     # edge activation is the expiration mask alone)
     caveats: tuple = ()
     cav_rows: int = 1
-    # semiring propagation-mode policy baked into the trace: "auto" =
-    # per-iteration lax.cond on traced occupancy; "push"/"pull" force one
-    # branch (ops/semiring.py force_mode)
-    spmm_mode: str = "auto"
     # one-shot phases before the loop (0 = none: no cycle, or nothing
     # feeds it), and per phase -n_pre..-1 the slot ranges it finalizes;
     # the entry phase (last) merges into the core's ranges
@@ -736,12 +731,6 @@ class CompiledGraph:
     # across incremental descendants of one compiled base (carried by
     # dataclasses.replace), rebuilt fresh by each compaction fold.
     tier: Optional[object] = None
-    # push/pull crossover threshold fed to the semiring primitive as a
-    # TRACED scalar (ops/semiring.propagate): push while the traced
-    # per-iteration occupancy is <= this. Mutated in place by the engine
-    # from its frontier-occupancy EWMA
-    # (semiring.crossover_from_occupancy) — tuning costs zero recompiles.
-    spmm_crossover: float = 1.0
     # lazily-populated device state
     _device: dict = field(default_factory=dict)
 
@@ -927,7 +916,6 @@ class CompiledGraph:
             level_ranges=level_ranges,
             caveats=cav.metas if cav is not None else (),
             cav_rows=cav.n_rows if cav is not None else 1,
-            spmm_mode=semiring.resolved_mode(),
             n_pre=self.n_pre,
             pre_ranges=pre_ranges,
             seed=self._seed_layout(),
@@ -1042,11 +1030,7 @@ class CompiledGraph:
                 bits_dev.append(None)
         d["blocks"] = tuple(blocks_dev)
         d["blocks_bits"] = tuple(bits_dev)
-        # kernel/mode toggles are baked into traces, so they are part of
-        # the shared-function cache key; query_async keeps a per-mode
-        # entry so a force_mode() flip (the differential tests) cannot
-        # dispatch through a stale trace
-        d[("run", semiring.resolved_mode())] = _jit_run_for(self)
+        d["run"] = _jit_run_for(self)
         return d
 
     def _dead_cells(self, bm: _BlockMeta) -> tuple[np.ndarray, np.ndarray]:
@@ -1327,26 +1311,20 @@ class CompiledGraph:
                 cav_req, _ = cav.encode_request(context, now_abs)
         else:
             cav_req = ()
-        # per-mode jitted entry (force_mode flips between dispatches must
-        # hit their own trace); built lazily under the shared cache lock
-        mk = semiring.resolved_mode()
         if self.tier is None:
-            run = d.get(("run", mk))
-            if run is None:
-                run = _jit_run_for(self)
-                d[("run", mk)] = run
+            run = d["run"]
             blocks_arg = d["blocks"]
             bits_arg = d["blocks_bits"]
         else:
             # tiered dispatch: demand-set the blocks, stream in the cold
-            # ones, and run the per-(mode, active-set) trace. The run
-            # key depends only on query shape — residency churn between
+            # ones, and run the per-active-set trace. The run key
+            # depends only on query shape — residency churn between
             # dispatches reuses this exact entry (zero recompiles).
             active = self._demand_blocks(seed_slots, q_slots)
             if active is None:
                 active = tuple(range(len(self.blocks)))
             blocks_arg, bits_arg = self._stream_blocks(active)
-            rk = ("run", mk, active)
+            rk = ("run", active)
             run = d.get(rk)
             if run is None:
                 run = _jit_run_for(self, active)
@@ -1365,14 +1343,13 @@ class CompiledGraph:
             # seeds ride the jit call as a host array: jax folds the
             # transfer into the dispatch instead of a separate device_put
             # round trip
-            out, converged, iters, n_push, cav_missing = run(
+            out, converged, iters, cav_missing = run(
                 blocks_arg, bits_arg, d["src"], d["dst"], d["exp"],
                 d["cav"], d["ptr"],
                 d["dsrc"], d["ddst"], d["dexp"], d["dcav"],
                 d["cav_static"], cav_req,
                 seeds, qs_dev, qb_dev,
-                now_rel, np.float32(self.spmm_crossover),
-                max_iters=max_iters, **run_kwargs,
+                now_rel, max_iters=max_iters, **run_kwargs,
             )
         try:
             out.copy_to_host_async()
@@ -1381,12 +1358,11 @@ class CompiledGraph:
             # result finalizer; without the prefetch that int() is a
             # synchronous device roundtrip per query
             iters.copy_to_host_async()
-            n_push.copy_to_host_async()
             cav_missing.copy_to_host_async()
         except AttributeError:  # non-jax array backends in tests
             pass
         return QueryFuture(out, converged, iters, Q, max_iters,
-                           cav_missing, n_push)
+                           cav_missing)
 
     def query(
         self,
@@ -1468,95 +1444,6 @@ class CompiledGraph:
             seed_looked(rows, d, n) for d, n in zip(
                 seed.fanout, seed.lengths(self.res_level_bounds))
         ) else "walk"
-
-    def hop_bytes(self, batch: int = 1) -> dict:
-        """Estimated HBM traffic (bytes) for roofline reporting, split by
-        the stratified schedule: ``total`` is the per-ITERATION cost of
-        the cyclic core (what multiplies by the fixpoint iteration count);
-        ``tail_once`` is the one-shot cost of every other phase (feeder
-        levels and entry before the loop, acyclic levels after it). Streams
-        counted: residual gather/segment, dense-block operands (bit-packed
-        or int8 A), elementwise program passes. An estimate of bytes
-        *touched* — XLA fusion can only reduce it.
-
-        ``modes`` reports the core dense-block bytes PER SEMIRING MODE
-        (ops/semiring.py) so collective-bytes baselines (ROADMAP item 1)
-        can be stated per branch instead of assuming one layout:
-        ``push`` streams each block's bit-packed dual (its eligible
-        blocks) or the full int8 A where no dual exists; ``pull`` always
-        streams the full int8 A; ``pallas`` adds the MXU kernel's
-        frontier re-stream (the [b32, n_src] operand is re-read once per
-        dst-tile row of the grid). ``blocks``/``total`` keep reporting
-        the mode the CURRENT configuration would run (bits when the bit
-        kernel is live and the batch fits, else dense)."""
-        rows = self.M // LANE + 1
-        Mp = rows * LANE
-
-        def res_bytes(n):  # src+dst int32 + valid uint8 + B gathered
-            return n * (4 + 4 + 1 + batch) + batch * Mp
-
-        def bits_bytes(b):
-            k0 = (b.n_src + 31) // 32
-            k_pad = -(-k0 // bitprop.LANES) * bitprop.LANES
-            return b.n_dst * k_pad * 4
-
-        def push_bytes(b):
-            # bit-packed dual when one exists for this batch, else the
-            # push pass degrades to the dense pull stream for the block
-            if batch <= bitprop.BIT_B_MAX and bitprop.eligible(
-                    b.n_dst, b.n_src):
-                return bits_bytes(b)
-            return b.n_dst * b.n_src
-
-        def pull_bytes(b):
-            return b.n_dst * b.n_src
-
-        def pallas_bytes(b):
-            # dense MXU kernel: A streamed once + the padded frontier
-            # tile re-streamed per dst-tile grid row
-            if not bitprop.dense_eligible(b.n_dst, b.n_src, batch):
-                return pull_bytes(b)
-            b32 = -(-batch // bitprop.SUBLANE) * bitprop.SUBLANE
-            return b.n_dst * b.n_src \
-                + b32 * b.n_src * (b.n_dst // bitprop.MXU_TILE)
-
-        def block_bytes(b):
-            use_bits = (batch <= bitprop.BIT_B_MAX
-                        and bitprop.kernel_enabled())
-            if use_bits and bitprop.eligible(b.n_dst, b.n_src):
-                return bits_bytes(b)
-            return b.n_dst * b.n_src
-
-        bounds = self.res_level_bounds
-        if bounds is None:
-            n_core = (len(self.res_idx) if self.res_idx is not None
-                      else self.n_edges)
-            tail_res = 0
-        else:
-            n_core = bounds[self.n_pre + 1] - bounds[self.n_pre]
-            # the seeded parts are read from the seeds, 2 * batch runs
-            # of at most the phase's fan-out, not walked
-            tail_res = self.walked_edges() - n_core \
-                + 2 * batch * self.seed_fanout()
-        delta = self._delta_pad() * (4 + 4 + 1 + batch)
-        core_res = res_bytes(n_core) + delta
-        core_blk = [b for b in self.blocks if b.level == 0]
-        core_blocks = sum(block_bytes(b) for b in core_blk)
-        core_prog = sum(2 * p.size * batch for p in self.programs
-                        if p.level == 0)
-        tail = (res_bytes(tail_res) if tail_res else 0) \
-            + sum(block_bytes(b) for b in self.blocks if b.level) \
-            + sum(2 * p.size * batch for p in self.programs if p.level) \
-            + (self.n_pre + self.n_levels) \
-            * (delta + 2 * batch * Mp)  # merges + delta
-        return {"residual": core_res, "blocks": core_blocks,
-                "programs": core_prog, "tail_once": tail,
-                "total": core_res + core_blocks + core_prog,
-                "modes": {
-                    "push": sum(push_bytes(b) for b in core_blk),
-                    "pull": sum(pull_bytes(b) for b in core_blk),
-                    "pallas": sum(pallas_bytes(b) for b in core_blk),
-                }}
 
 
 def _materialize_block(cg: "CompiledGraph", i: int) -> tuple:
@@ -1686,10 +1573,7 @@ class QueryFuture:
     dispatch depth, exported to the metrics registry by the engine.
     ``caveats_missing()`` is the number of caveat instances that resolved
     to the missing-context tri-state this dispatch (denied fail-closed;
-    feeds ``engine_caveat_denied_missing_context_total``).
-    ``push_steps()`` is how many of those hops took the semiring PUSH
-    branch (ops/semiring.py; the rest took pull) — the per-iteration
-    mode telemetry behind ``engine_semiring_push_steps_total``."""
+    feeds ``engine_caveat_denied_missing_context_total``)."""
 
     _out: object
     _converged: object
@@ -1697,7 +1581,6 @@ class QueryFuture:
     _q: int
     _max_iters: int
     _cav_missing: object = None
-    _push: object = None
 
     def result(self) -> np.ndarray:
         if not bool(self._converged):
@@ -1709,9 +1592,6 @@ class QueryFuture:
 
     def iterations(self) -> int:
         return int(self._iters)
-
-    def push_steps(self) -> int:
-        return 0 if self._push is None else int(self._push)
 
     def caveats_missing(self) -> int:
         return 0 if self._cav_missing is None else int(self._cav_missing)
@@ -1774,15 +1654,13 @@ def apply_level_once(meta: "RunMeta", prop_level, V, baseflat, k: int):
     whose sources are final by now; write the result into the phase's
     slot ranges and no other (a replacing merge, so finalized levels are
     untouched and no dense masks exist anywhere); run the phase's
-    programs. ``prop_level(V, k) -> (prop [B, Mp], is_push)`` is the
-    caller's hop (the mesh joins its shards inside it), so the
-    single-chip and the sharded fixpoint share this step letter for
-    letter. Returns ``(V, is_push)``."""
+    programs. ``prop_level(V, k) -> prop [B, Mp]`` is the caller's hop
+    (the mesh joins its shards inside it), so the single-chip and the
+    sharded fixpoint share this step letter for letter."""
     B, rows = V.shape[0], V.shape[1]
     Mp = rows * LANE
     with jax.named_scope(meta.scope(k)):
-        prop, is_push = prop_level(V, k)
-        propb = prop | baseflat
+        propb = prop_level(V, k) | baseflat
         Vflat = V.reshape(B, Mp)
         for off, size in meta.windows(k):
             Vflat = jax.lax.dynamic_update_slice(
@@ -1790,7 +1668,7 @@ def apply_level_once(meta: "RunMeta", prop_level, V, baseflat, k: int):
                 jax.lax.dynamic_slice(propb, (0, off), (B, size)),
                 (0, off))
         return _apply_program(meta, Vflat.reshape(B, rows, LANE),
-                              meta.programs_at(k)), is_push
+                              meta.programs_at(k))
 
 
 def _seed_runs(cg: "RunMeta", k: int, seeds, ptr):
@@ -1809,7 +1687,7 @@ def _seed_runs(cg: "RunMeta", k: int, seeds, ptr):
 
 def _run(cg: "RunMeta", blocks, blocks_bits, src, dst, exp_rel, cav, ptr,
          dsrc, ddst, dexp, dcav, cav_static, cav_req,
-         seeds, q_slots, q_batch, now_rel, crossover, *,
+         seeds, q_slots, q_batch, now_rel, *,
          max_iters: int, q_contig_len: int = 0, q_contig_rows: int = 1):
     """The jitted stratified fixpoint. V layout: [B, rows, LANE] uint8 —
     the slot space rides the lane axis so a B=1 query streams exactly M
@@ -1848,11 +1726,7 @@ def _run(cg: "RunMeta", blocks, blocks_bits, src, dst, exp_rel, cav, ptr,
     fused into the multiply. The caveat VM evaluates every instance's
     tri-state once up front when the graph carries caveat instances
     (cg.cav_rows > 1); caveated edges never enter dense blocks
-    (compile_graph routes them residual, like expiring edges).
-    ``crossover`` is the traced push/pull threshold (CompiledGraph
-    .spmm_crossover): the per-iteration mode branch is a lax.cond on
-    traced occupancy, so neither tuning nor the runtime flip
-    re-specializes."""
+    (compile_graph routes them residual, like expiring edges)."""
     B = seeds.shape[0]
     rows = cg.M // LANE + 1  # + trash row (slots M .. M+LANE-1)
     Mp = rows * LANE
@@ -1883,51 +1757,46 @@ def _run(cg: "RunMeta", blocks, blocks_bits, src, dst, exp_rel, cav, ptr,
             unsorted = tuple(
                 jnp.concatenate([a, b[mid:hi]])
                 for a, b in zip(unsorted, (src, dst, act)))
-        occ = semiring.frontier_occupancy(Vflat)
-        prop, is_push = semiring.propagate(
+        prop = semiring.propagate(
             cg.blocks, blocks, blocks_bits, src[lo:mid], dst[lo:mid],
-            act[lo:mid], *unsorted, Vflat, occ, crossover,
-            level=k, mode=cg.spmm_mode)
+            act[lo:mid], *unsorted, Vflat, level=k)
         if looked:
             start, length = _seed_runs(cg, k, seeds, ptr)
             prop = semiring.propagate_seeded(
                 prop, start, length, dst[mid:hi], act[mid:hi],
                 cg.seed.fanout[k + cg.n_pre])
-        return prop, is_push
+        return prop
 
     # jax.named_scope names the phases for HLO dumps and xprof (op_name
     # metadata only: the computation is the same). No one-shot phase may
     # be skipped — incremental delta edges can target any level and only
     # that phase's re-application establishes their values.
-    V, n_push = base, jnp.int32(0)
+    V = base
     for k in range(-cg.n_pre, 0):
-        V, is_push = apply_level_once(cg, prop_level, V, baseflat, k)
-        n_push = n_push + is_push
+        V = apply_level_once(cg, prop_level, V, baseflat, k)
     # what the loop ORs into every trip: the seeds, the feeders' final
     # values and the entry edges' contribution (``base`` itself where
     # nothing feeds the core)
     const = V
 
     def step(V):
-        prop, is_push = prop_level(V, 0)
         return _apply_program(
-            cg, prop.reshape(B, rows, LANE) | const, core_progs), is_push
+            cg, prop_level(V, 0).reshape(B, rows, LANE) | const, core_progs)
 
     def cond(state):
-        V, prev_changed, it, _ = state
+        V, prev_changed, it = state
         return prev_changed & (it < max_iters)
 
     def body(state):
-        V, _, it, n_push = state
-        V2, is_push = step(V)
-        return V2, jnp.any(V2 != V), it + 1, n_push + is_push
+        V, _, it = state
+        V2 = step(V)
+        return V2, jnp.any(V2 != V), it + 1
 
     with jax.named_scope(cg.scope(0)):
-        V, still_changing, iters, n_push = jax.lax.while_loop(
-            cond, body, (V, jnp.bool_(True), 0, n_push))
+        V, still_changing, iters = jax.lax.while_loop(
+            cond, body, (V, jnp.bool_(True), 0))
     for k in range(1, cg.n_levels + 1):
-        V, is_push = apply_level_once(cg, prop_level, V, baseflat, k)
-        n_push = n_push + is_push
+        V = apply_level_once(cg, prop_level, V, baseflat, k)
     # still_changing at loop exit means we hit max_iters before convergence;
     # surface it so the host can raise instead of silently denying
     with jax.named_scope("readout"):
@@ -1948,7 +1817,7 @@ def _run(cg: "RunMeta", blocks, blocks_bits, src, dst, exp_rel, cav, ptr,
             ).reshape(q_contig_rows * q_contig_len).astype(jnp.bool_)
         else:
             out = V.reshape(B, Mp)[q_batch, q_slots].astype(jnp.bool_)
-    return out, jnp.logical_not(still_changing), iters, n_push, cav_missing
+    return out, jnp.logical_not(still_changing), iters, cav_missing
 
 
 # ---------------------------------------------------------------------------

@@ -41,17 +41,16 @@ loop carry buffers are donated/double-buffered by the ``while_loop``
 lowering (no fresh HBM per block). Because the flags are per step,
 ``iterations()`` reports the ACTUAL converged-at step (the number of
 steps that changed anything — no longer quantized to K, so the engine's
-occupancy/crossover telemetry sees true depths); ``conv_checks()``
-reports the convergence collectives actually paid.
+iteration metrics see true depths); ``conv_checks()`` reports the
+convergence collectives actually paid.
 
 Propagation itself is one call per level into the masked-semiring
 primitive (ops/semiring.propagate) — the SAME primitive the
 single-device fixpoint uses, with the ``(exp > now) ∧ cav_ok[row]``
 edge-activation mask hoisted to once per dispatch (= once per K-step
 fused window) — followed by the pmax partial-product join over ICI. The
-join stays OUTSIDE the primitive's push/pull ``lax.cond`` branches:
-devices whose data shards diverge on the traced occupancy branch must
-never meet a collective inside one branch.
+mesh keeps no bit duals, so its blocks take the MXU dense kernel or
+``lax.dot_general``.
 
 The query surface is both a *grid* (``B`` subjects x ``Q`` result slots
 per subject — bulk checks and concurrent list prefilters, BASELINE config
@@ -101,8 +100,7 @@ from ..ops.reachability import (
 
 def _run_sharded(meta, block_meta, ng: int, level_edges, blocks,
                  dsrc, ddst, dexp, dcav, cav_static, cav_req,
-                 seeds, q_slots, now_rel, crossover, *,
-                 max_iters: int, k_steps: int):
+                 seeds, q_slots, now_rel, *, max_iters: int, k_steps: int):
     """Per-device body (inside shard_map). Shapes are the LOCAL shards:
     level_edges[k + n_pre] = (src, dst, exp, cav) [E_k/ng] (per phase of
     the schedule, in order of execution, each chunk dst-sorted);
@@ -122,13 +120,12 @@ def _run_sharded(meta, block_meta, ng: int, level_edges, blocks,
     ``shard=(g_idx, ng)`` so block frontiers cover only this chip's
     src-axis chunk): only the cyclic core (level 0) iterates; every
     other phase is applied once; partial propagations are joined with
-    pmax over ICI AFTER the primitive returns (outside its push/pull
-    lax.cond — a collective inside a branch devices may disagree on
-    would deadlock). Edge activation (expiration ∧ caveat verdict) is
-    hoisted to once per dispatch — once per K-step fused window, not
-    once per hop. The while body fuses ``k_steps`` propagation steps
-    per convergence collective, pmaxing the K per-step change flags as
-    one vector so the true converged-at step survives fusing."""
+    pmax over ICI after the primitive returns. Edge activation
+    (expiration ∧ caveat verdict) is hoisted to once per dispatch —
+    once per K-step fused window, not once per hop. The while body fuses
+    ``k_steps`` propagation steps per convergence collective, pmaxing the
+    K per-step change flags as one vector so the true converged-at step
+    survives fusing."""
     B = seeds.shape[0]
     rows = meta.M // LANE + 1  # + trash row
     Mp = rows * LANE
@@ -158,43 +155,40 @@ def _run_sharded(meta, block_meta, ng: int, level_edges, blocks,
     def prop_level(V, k):
         Vflat = V.reshape(B, Mp)
         src, dst = level_edges[k + meta.n_pre][:2]
-        occ = semiring.frontier_occupancy(Vflat)
-        prop, is_push = semiring.propagate(
+        prop = semiring.propagate(
             block_meta, blocks, no_bits, src, dst, acts[k + meta.n_pre],
-            dsrc, ddst, dact, Vflat, occ, crossover,
-            level=k, mode=meta.spmm_mode, shard=(g_idx, ng))
-        # join partials over ICI — outside the primitive's mode cond.
-        # Widened to int32 for the wire: on v5e chips a uint8 pmax
-        # compares the four bytes packed in a 32-bit word as ONE number
-        # and keeps the larger word whole, dropping the set bytes of the
-        # other (rows 0-2 of every 4; grants denied for B > 1). int32
-        # is exact. The CPU backend reduces bytes one by one, so no
-        # virtual-device run can see this: chip_smoke.py --chips 4 does,
-        # and tests/test_chip_compile.py pins the compiled join to s32.
+            dsrc, ddst, dact, Vflat, level=k, shard=(g_idx, ng))
+        # join partials over ICI, widened to int32 for the wire: on v5e
+        # chips a uint8 pmax compares the four bytes packed in a 32-bit
+        # word as ONE number and keeps the larger word whole, dropping
+        # the set bytes of the other (rows 0-2 of every 4; grants denied
+        # for B > 1). int32 is exact. The CPU backend reduces bytes one
+        # by one, so no virtual-device run can see this: chip_smoke.py
+        # --chips 4 does, and tests/test_chip_compile.py pins the
+        # compiled join to s32.
         joined = jax.lax.pmax(prop.astype(jnp.int32), "graph")
-        return joined.astype(jnp.uint8), is_push
+        return joined.astype(jnp.uint8)
 
     core_progs = meta.programs_at(0)
     # before the loop: feeder levels and the entry phase, once each (see
     # ops/reachability._run); what they leave is the loop's start and
     # its constant term
-    V, n_push = base, jnp.int32(0)
+    V = base
     for k in range(-meta.n_pre, 0):
-        V, is_push = apply_level_once(meta, prop_level, V, baseflat, k)
-        n_push = n_push + is_push
+        V = apply_level_once(meta, prop_level, V, baseflat, k)
     const = V
 
     def step(V):
-        prop, is_push = prop_level(V, 0)
         return _apply_program(
-            meta, prop.reshape(B, rows, LANE) | const, core_progs), is_push
+            meta, prop_level(V, 0).reshape(B, rows, LANE) | const,
+            core_progs)
 
     def cond(state):
-        _, prev_changed, it, _, _ = state
+        _, prev_changed, it, _ = state
         return (prev_changed > 0) & (it < max_iters)
 
     def body(state):
-        V, _, it, checks, n_push = state
+        V, _, it, checks = state
         # K-step fusing: k_steps hops per convergence collective. The
         # fixpoint is monotone, so hops past convergence are no-ops.
         # Each step's LOCAL change flag is recorded and the K flags are
@@ -206,38 +200,30 @@ def _run_sharded(meta, block_meta, ng: int, level_edges, blocks,
         # identical fl, so all agree on the loop exit.
         Vk = V
         flags = []
-        pushes = jnp.int32(0)
         for _ in range(k_steps):
-            V2, is_push = step(Vk)
+            V2 = step(Vk)
             flags.append(jnp.any(V2 != Vk).astype(jnp.int32))
-            pushes = pushes + is_push
             Vk = V2
         fl = jax.lax.pmax(jnp.stack(flags), ("data", "graph"))
-        return (Vk, fl[k_steps - 1], it + fl.sum(), checks + 1,
-                n_push + pushes)
+        return Vk, fl[k_steps - 1], it + fl.sum(), checks + 1
 
     with jax.named_scope(meta.scope(0)):
-        V, still_changing, iters, checks, n_push = jax.lax.while_loop(
-            cond, body, (V, jnp.int32(1), jnp.int32(0), jnp.int32(0),
-                         n_push))
+        V, still_changing, iters, checks = jax.lax.while_loop(
+            cond, body, (V, jnp.int32(1), jnp.int32(0), jnp.int32(0)))
     # acyclic levels: one application each (see ops/reachability._run)
     for k in range(1, meta.n_levels + 1):
-        V, is_push = apply_level_once(meta, prop_level, V, baseflat, k)
-        n_push = n_push + is_push
+        V = apply_level_once(meta, prop_level, V, baseflat, k)
     out = V.reshape(B, Mp)[brange[:, None], q_slots].astype(jnp.bool_)
     # replicate the (tiny, bool) result over the data axis so it is fully
     # addressable on EVERY process — under a multi-host mesh a
     # data-sharded output cannot be fetched by the serving process
     out = jax.lax.all_gather(out, "data", axis=0, tiled=True)
-    # push counts may diverge per data shard (occupancy is local): join
-    # so the P() out_spec's replication promise holds
-    n_push = jax.lax.pmax(n_push, ("data", "graph"))
     converged = still_changing == 0
     # fl.sum() counts CHANGING hops; the single-chip loop also pays (and
     # reports) exactly one confirming no-op hop when it converges — count
     # it here too so both futures report the same converged-at step
     iters = iters + converged.astype(jnp.int32)
-    return out, converged, iters, checks, n_push, cav_missing
+    return out, converged, iters, checks, cav_missing
 
 
 class ShardedQueryFuture:
@@ -245,17 +231,16 @@ class ShardedQueryFuture:
     and validates convergence; ``iterations()`` mirrors the single-chip
     QueryFuture so the engine's metrics finalizers work unchanged — it
     reports the ACTUAL converged-at step (per-step change flags survive
-    the K-step fuse), so occupancy/crossover telemetry is no longer
-    quantized to K; ``conv_checks()`` is the number of convergence
-    collectives actually paid; ``push_steps()`` how many core hops took
-    the semiring push branch; ``caveats_missing()`` the missing-context
+    the K-step fuse), so iteration metrics are no longer quantized to
+    K; ``conv_checks()`` is the number of convergence collectives
+    actually paid; ``caveats_missing()`` the missing-context
     instance count (fail-closed denials, counted by the engine)."""
 
     __slots__ = ("_out", "_converged", "_iters", "_sel", "_max_iters",
-                 "_cav_missing", "_k_steps", "_checks", "_push")
+                 "_cav_missing", "_k_steps", "_checks")
 
     def __init__(self, out, converged, iters, sel, max_iters,
-                 cav_missing=None, k_steps=1, checks=None, push=None):
+                 cav_missing=None, k_steps=1, checks=None):
         self._out = out
         self._converged = converged
         self._iters = iters
@@ -265,7 +250,6 @@ class ShardedQueryFuture:
         self._cav_missing = cav_missing
         self._k_steps = max(int(k_steps), 1)
         self._checks = checks
-        self._push = push
 
     def result(self) -> np.ndarray:
         if not bool(self._converged):
@@ -295,9 +279,6 @@ class ShardedQueryFuture:
         if self._checks is not None:
             return int(self._checks)
         return -(-int(self._iters) // self._k_steps)
-
-    def push_steps(self) -> int:
-        return 0 if self._push is None else int(self._push)
 
     def caveats_missing(self) -> int:
         return 0 if self._cav_missing is None else int(self._cav_missing)
@@ -432,9 +413,9 @@ class ShardedGraph:
                 tuple(P(None, "graph") for _ in self._block_meta),
                 P("graph"), P("graph"), P("graph"), P("graph"),
                 P(), P(),
-                P("data", None), P("data", None), P(), P(),
+                P("data", None), P("data", None), P(),
             ),
-            out_specs=(P(None, None), P(), P(), P(), P(), P()),
+            out_specs=(P(None, None), P(), P(), P(), P()),
         ))
 
     @staticmethod
@@ -795,7 +776,6 @@ class ShardedGraph:
         # in_specs, which works identically whether the mesh spans one
         # process or many (a committed local array would need a reshard
         # from a non-global placement under multi-controller)
-        crossover = np.float32(getattr(self.cg, "spmm_crossover", 1.0))
         if self.cg.seed_edges():
             # the mesh walks the seeded parts (_host_level_edges)
             metrics.counter("engine_seed_walks_total").inc()
@@ -803,22 +783,19 @@ class ShardedGraph:
                           metrics.histogram("engine_enqueue_seconds"),
                           metrics.counter("engine_enqueue_cpu_seconds_total"),
                           rows=len(seeds_pad)):
-            out, converged, iters, checks, n_push, cav_missing = self._run(
+            out, converged, iters, checks, cav_missing = self._run(
                 self._level_edges, self._blocks,
                 self._dsrc, self._ddst, self._dexp, self._dcav,
-                self._cav_static, cav_req,
-                seeds_pad, grid, now_rel, crossover,
-            )
+                self._cav_static, cav_req, seeds_pad, grid, now_rel)
         try:
             out.copy_to_host_async()
             converged.copy_to_host_async()
             iters.copy_to_host_async()
             checks.copy_to_host_async()
-            n_push.copy_to_host_async()
             cav_missing.copy_to_host_async()
         except AttributeError:  # non-jax backends in tests
             pass
-        return out, converged, iters, checks, n_push, cav_missing
+        return out, converged, iters, checks, cav_missing
 
     def _request_arrays(self, context: Optional[dict],
                         cav_req: Optional[tuple], now_abs: float) -> tuple:
@@ -856,11 +833,11 @@ class ShardedGraph:
         qs = np.full((B_pad, Q_pad), cg.M, dtype=np.int32)
         qs[:B, :Q] = q_slots
         now_abs = time.time() if now is None else now
-        out, converged, iters, checks, n_push, cav_missing = self._dispatch(
+        out, converged, iters, checks, cav_missing = self._dispatch(
             seeds, qs, now_abs, self._request_arrays(context, None, now_abs))
         fut = ShardedQueryFuture(out, converged, iters, None,
                                  self.max_iters, cav_missing, self.k_steps,
-                                 checks=checks, push=n_push)
+                                 checks=checks)
         return fut.result()[:B, :Q]
 
     def query_async(
@@ -946,7 +923,7 @@ class ShardedGraph:
                     self._qgrid.pop(next(iter(self._qgrid)), None)
                 self._qgrid[(q_cache_key, B_pad)] = grid
         now_abs = time.time() if now is None else now
-        out, converged, iters, checks, n_push, cav_missing = self._dispatch(
+        out, converged, iters, checks, cav_missing = self._dispatch(
             seeds, grid, now_abs,
             self._request_arrays(context, cav_req, now_abs))
         sel = (("contig_grid", L, R) if contig is not None
@@ -955,4 +932,4 @@ class ShardedGraph:
                                   max_iters=self.max_iters,
                                   cav_missing=cav_missing,
                                   k_steps=self.k_steps,
-                                  checks=checks, push=n_push)
+                                  checks=checks)

@@ -11,11 +11,6 @@ Registered gates (all real behavior switches):
 
 - ``IncrementalGraphUpdates`` (default on): O(delta) compiled-graph
   updates on write; off forces a full recompile per revision change.
-- ``BitKernel`` (default on): the bit-packed Pallas propagation kernel on
-  TPU for small query batches; off keeps every block on the MXU matmul.
-- ``SemiringDenseKernel`` (default on): the MXU-tile-shaped Pallas dense
-  kernel for the semiring pull path (ops/semiring.py); off keeps the
-  dense phase on the plain XLA dot_general.
 - ``ProtobufNegotiation`` (default on): forward kube-protobuf Accept
   ranges upstream and wire-filter protobuf responses; off rewrites every
   Accept to JSON.
@@ -98,7 +93,5 @@ class FeatureGates:
 
 features = FeatureGates()
 features.register("IncrementalGraphUpdates", True)
-features.register("BitKernel", True)
-features.register("SemiringDenseKernel", True)
 features.register("ProtobufNegotiation", True)
 features.register("ProtobufWatch", True)

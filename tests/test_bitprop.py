@@ -100,7 +100,10 @@ definition ns {
             for _ in range(300)]
 
     def run(mode):
-        monkeypatch.setenv("SDBKP_BITPROP", mode)
+        if mode is None:
+            monkeypatch.delenv("SDBKP_BITPROP", raising=False)
+        else:
+            monkeypatch.setenv("SDBKP_BITPROP", mode)
         e = Engine(schema=schema)
         e.write_relationships(
             [WriteOp("touch", parse_relationship(r)) for r in set(rels)])
@@ -110,4 +113,4 @@ definition ns {
         # use identical inputs either way
         return e.check_bulk(items)
 
-    assert run("interpret") == run("0")
+    assert run("interpret") == run(None)

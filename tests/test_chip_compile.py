@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
-from spicedb_kubeapi_proxy_tpu.ops import bitprop, reachability, semiring
+from spicedb_kubeapi_proxy_tpu.ops import bitprop, reachability
 
 # the headline deployment's big block (100k pods x 10k users, bucket-padded)
 HEADLINE = (131072, 16384)
@@ -141,7 +141,7 @@ def graph():
 
 def _compile_fixpoint(cg, one_chip, q_contig_len: int, rows: int = 1) -> str:
     """One whole jitted fixpoint (``_jit_run_for``'s program) over ``cg``,
-    in ``auto``, compiled for the described chip: its HLO text. ``rows``
+    compiled for the described chip: its HLO text. ``rows``
     subject rows, read back as the grid of that many rows over one
     window (a lookup alone, or the batcher's fused dispatch)."""
 
@@ -161,36 +161,37 @@ def _compile_fixpoint(cg, one_chip, q_contig_len: int, rows: int = 1) -> str:
         cav_static = cg.caveats.device_static()
         cav_req, _ = cg.caveats.encode_request({"ip": "10.1.2.3"},
                                                time.time())
-    with semiring.force_mode("auto"):
-        run = jax.jit(reachability.fixpoint_program(cg.run_meta()),
-                      static_argnames=("max_iters", "q_contig_len",
-                                       "q_contig_rows"))
-        return run.lower(
-            blocks, bits,
-            *like((cg.res_src, cg.res_dst, cg.res_exp, cg.res_cav,
-                   cg._res_ptr())),
-            *like(cg._delta_host()),
-            like(cav_static), like(cav_req),
-            S((rows, 2), jnp.int32), S((), jnp.int32), S((), jnp.int32),
-            S((), jnp.float32), S((), jnp.float32),
-            max_iters=reachability.DEFAULT_MAX_ITERS,
-            q_contig_len=q_contig_len, q_contig_rows=rows,
-        ).compile().as_text()
+    run = jax.jit(reachability.fixpoint_program(cg.run_meta()),
+                  static_argnames=("max_iters", "q_contig_len",
+                                   "q_contig_rows"))
+    return run.lower(
+        blocks, bits,
+        *like((cg.res_src, cg.res_dst, cg.res_exp, cg.res_cav,
+               cg._res_ptr())),
+        *like(cg._delta_host()),
+        like(cav_static), like(cav_req),
+        S((rows, 2), jnp.int32), S((), jnp.int32), S((), jnp.int32),
+        S((), jnp.float32),
+        max_iters=reachability.DEFAULT_MAX_ITERS,
+        q_contig_len=q_contig_len, q_contig_rows=rows,
+    ).compile().as_text()
 
 
 def test_whole_fixpoint_compiles_in_auto(one_chip, compiled_kernels, graph):
-    """Both kernels sit in the two branches of the per-iteration
-    ``lax.cond``, so both must compile in one program."""
+    """Each kernel compiles inside the whole fixpoint at the batch that
+    picks it: the bit kernel at one row, the MXU kernel past
+    ``BIT_B_MAX`` rows."""
     cg = graph
-    assert any(bitprop.eligible(b.n_dst, b.n_src) for b in cg.blocks)
+    assert all(bitprop.eligible(b.n_dst, b.n_src) for b in cg.blocks)
     text = _compile_fixpoint(cg, one_chip, cg.type_sizes["pod"])
-    # every block: its dense kernel in the pull branch, its bit kernel in
-    # the push branch
-    assert text.count("tpu_custom_call") >= 2 * len(cg.blocks)
+    # every block: its one kernel
+    assert text.count("tpu_custom_call") >= len(cg.blocks)
     # the names a device trace is reduced by: the module line reads
     # jit_sdbkp_fixpoint, the kernels' operations sdbkp_*_hop
     assert text.startswith("HloModule jit_sdbkp_fixpoint")
-    assert "sdbkp_bit_hop" in text and "sdbkp_dense_hop" in text
+    assert "sdbkp_bit_hop" in text and "sdbkp_dense_hop" not in text
+    wide = _compile_fixpoint(cg, one_chip, cg.type_sizes["pod"], 16)
+    assert "sdbkp_dense_hop" in wide and "sdbkp_bit_hop" not in wide
 
 
 def test_fixpoint_with_feeder_levels_and_entry_edges_compiles(
@@ -281,9 +282,11 @@ def test_fused_lookup_program_of_the_tenant_schema_compiles(
     assert cg.blocks and cg.seed_edges()
     text = _compile_fixpoint(cg, one_chip, cg.type_sizes["namespace"], rows)
     assert text.startswith("HloModule jit_sdbkp_fixpoint")
-    # past the bit kernel's rows the push branch is the pull branch
-    assert "sdbkp_dense_hop" in text
-    assert ("sdbkp_bit_hop" in text) == (rows <= bitprop.BIT_B_MAX)
+    bit = rows <= bitprop.BIT_B_MAX
+    assert ("sdbkp_bit_hop" in text) == bit
+    # a block without a bit dual rides the MXU kernel at any batch
+    assert ("sdbkp_dense_hop" in text) == (not bit or not all(
+        bitprop.eligible(b.n_dst, b.n_src) for b in cg.blocks))
     assert f"pred[{rows * cg.type_sizes['namespace']}]" in text
 
 
@@ -318,16 +321,15 @@ def test_mesh_fixpoint_compiles_and_joins_as_int32(topo, one_chip,
 
     cav_req, _ = cg.caveats.encode_request({"ip": "10.1.2.3"}, time.time())
     batch = 16
-    with semiring.force_mode("auto"):
-        text = sg._program(described).lower(
-            placed(sg._level_edges), placed(sg._blocks),
-            placed(sg._dsrc), placed(sg._ddst), placed(sg._dexp),
-            placed(sg._dcav), placed(sg._cav_static),
-            jax.tree.map(lambda a: S(a, P()), cav_req),
-            S(np.zeros((batch, 2), np.int32), P("data", None)),
-            S(np.zeros((batch, 1024), np.int32), P("data", None)),
-            S(np.float32(0), P()), S(np.float32(0), P()),
-        ).compile().as_text()
+    text = sg._program(described).lower(
+        placed(sg._level_edges), placed(sg._blocks),
+        placed(sg._dsrc), placed(sg._ddst), placed(sg._dexp),
+        placed(sg._dcav), placed(sg._cav_static),
+        jax.tree.map(lambda a: S(a, P()), cav_req),
+        S(np.zeros((batch, 2), np.int32), P("data", None)),
+        S(np.zeros((batch, 1024), np.int32), P("data", None)),
+        S(np.float32(0), P()),
+    ).compile().as_text()
     assert "tpu_custom_call" in text
     assert "all-gather" in text
     # shard_map keeps the partial's given name: what XLA Modules reads

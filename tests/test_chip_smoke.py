@@ -35,7 +35,7 @@ def test_tiny_rehearsal_walks_every_phase_but_reports_nothing():
     # every phase ran to its end, with its checks
     for marker in ("== device:", "== load:", "== engine:", "== served:",
                    "native graph core built from source",
-                   "parity: lookups and checks equal across pull/push/auto",
+                   "lookup first=",
                    "agree", "GET pod", ": 403", "watch namespaces as"):
         assert marker in out, (marker, out[-3000:], p.stderr[-3000:])
     assert "bit enabled=True interpreted=True" in out

@@ -1146,9 +1146,7 @@ def test_jit_cache_shared_across_revisions():
     cg2 = e.compiled()
     assert cg1 is not cg2
     assert cg1.signature() == cg2.signature()
-    from spicedb_kubeapi_proxy_tpu.ops import semiring
-    mk = ("run", semiring.resolved_mode())
-    assert cg1._device[mk] is cg2._device[mk]
+    assert cg1._device["run"] is cg2._device["run"]
 
 
 def test_reflexive_userset_identity_both_paths():

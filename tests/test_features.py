@@ -24,9 +24,9 @@ def reset_gates():
 
 def test_spec_parsing():
     assert features.validate_spec(
-        "IncrementalGraphUpdates=false, BitKernel=true") == [
-        ("IncrementalGraphUpdates", False), ("BitKernel", True)]
-    for bad in ("Nope=true", "BitKernel=maybe", "BitKernel"):
+        "IncrementalGraphUpdates=false, ProtobufWatch=true") == [
+        ("IncrementalGraphUpdates", False), ("ProtobufWatch", True)]
+    for bad in ("Nope=true", "ProtobufWatch=maybe", "ProtobufWatch"):
         with pytest.raises(FeatureGateError):
             features.validate_spec(bad)
     with pytest.raises(OptionsError):
@@ -51,15 +51,6 @@ def test_incremental_gate_forces_full_recompiles():
         "namespace:c#creator@user:alice"))])
     assert e.check(CheckItem("namespace", "c", "view", "user", "alice"))
     assert metrics.counter("engine_graph_compiles_total").value == c0 + 1
-
-
-def test_bitkernel_gate(monkeypatch):
-    from spicedb_kubeapi_proxy_tpu.ops import bitprop
-
-    monkeypatch.setenv("SDBKP_BITPROP", "interpret")
-    assert bitprop.kernel_enabled()
-    features.set("BitKernel", False)
-    assert not bitprop.kernel_enabled()
 
 
 def test_protobuf_gate():

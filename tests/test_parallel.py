@@ -850,38 +850,45 @@ def test_watch_over_engine_mesh(tmp_path):
 
 
 def test_semiring_push_pull_differential_churn(monkeypatch):
-    """The ISSUE 17 parity bar for the masked-semiring core: forced
-    push, forced pull, and auto mode agree byte-identically with each
-    other and with the recursive oracle at EVERY churn step, on BOTH
-    backends, with real dense blocks and bit-packed duals in play
-    (interpret-mode kernels on the CPU host platform) while expiring +
-    caveated + plain tuples churn through the incremental overlay."""
+    """The parity bar for the masked-semiring core: with the kernels on
+    (interpret mode on the CPU host: real dense blocks with bit-packed
+    duals, the MXU dense kernel) and off (dot_general), both backends
+    agree byte-identically with the recursive oracle at EVERY churn step
+    while expiring + caveated + plain tuples churn through the
+    incremental overlay."""
     import time as _time
 
     from spicedb_kubeapi_proxy_tpu.models.tuples import Relationship
-    from spicedb_kubeapi_proxy_tpu.ops import reachability, semiring
+    from spicedb_kubeapi_proxy_tpu.ops import reachability
 
-    # interpret-mode bit kernel + a low dense threshold: the small test
-    # graph forms real dense blocks WITH bit duals, so push and pull are
-    # genuinely different code paths here, not the same fallback
-    monkeypatch.setenv("SDBKP_BITPROP", "interpret")
+    def kernels(on: bool):
+        # each engine builds, traces and serves under its own setting
+        for var in ("SDBKP_BITPROP", "SDBKP_SEMIRING"):
+            if on:
+                monkeypatch.setenv(var, "interpret")
+            else:
+                monkeypatch.delenv(var, raising=False)
+
+    # a low dense threshold: the small test graph forms real dense blocks
     monkeypatch.setattr(reachability, "DENSE_MIN_EDGES", 8)
 
     rng = np.random.default_rng(0x5E31)
     users = [f"u{i}" for i in range(7)]
     docs = [f"d{i}" for i in range(10)]
-    engines = {"single": Engine(bootstrap=CAVEAT_BOOTSTRAP),
-               "mesh": Engine(bootstrap=CAVEAT_BOOTSTRAP,
-                              mesh=make_mesh(8, data=2, graph=4))}
     seed_rels = [f"doc:{d}#viewer@user:{u}"
                  for d in docs for u in users if hash((d, u)) % 2]
-    for e in engines.values():
-        e.write_relationships(touch(*seed_rels))
-    cg = engines["single"].compiled()
-    assert cg.blocks, "differential needs at least one dense block"
-    assert any(b is not None
-               for b in cg._dev()["blocks_bits"]), \
-        "differential needs a bit-packed dual (real push path)"
+    engines = {}
+    for on in (True, False):
+        kernels(on)
+        for name, mesh in (("single", None),
+                           ("mesh", make_mesh(8, data=2, graph=4))):
+            e = Engine(bootstrap=CAVEAT_BOOTSTRAP, mesh=mesh)
+            e.write_relationships(touch(*seed_rels))
+            engines[(on, name)] = e
+        cg = engines[(on, "single")].compiled()
+        assert cg.blocks, "differential needs at least one dense block"
+        assert any(b is not None for b in cg._dev()["blocks_bits"]) == on, \
+            "a bit-packed dual exactly where the kernels are on"
 
     now_fixed = _time.time()
     req = {"ip": "10.5.5.5"}
@@ -911,15 +918,14 @@ def test_semiring_push_pull_differential_churn(monkeypatch):
             rel = Relationship("doc", d, "viewer", "user", u)
             live.append(rel)
             op = WriteOp("touch", rel)
-        for e in engines.values():
+        for (on, _), e in engines.items():
+            kernels(on)
             e.write_relationships([op])
         for ctx in (req, None):
-            o = engines["single"].oracle(now=now_fixed, context=ctx)
+            o = engines[(True, "single")].oracle(now=now_fixed, context=ctx)
             want = [o.check(i.resource_type, i.resource_id, i.permission,
                             i.subject_type, i.subject_id) for i in items]
-            for mode in ("pull", "push", "auto"):
-                with semiring.force_mode(mode):
-                    for name, e in engines.items():
-                        got = e.check_bulk(items, context=ctx,
-                                           now=now_fixed)
-                        assert got == want, (step, ctx, mode, name)
+            for (on, name), e in engines.items():
+                kernels(on)
+                got = e.check_bulk(items, context=ctx, now=now_fixed)
+                assert got == want, (step, ctx, on, name)

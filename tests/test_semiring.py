@@ -1,12 +1,16 @@
-"""Masked boolean-semiring SpMM primitive (ops/semiring.py): push, pull,
-the auto lax.cond, the Pallas dense kernel (interpreter mode on CPU), and
-the numpy oracle must agree byte-identically ON EVERY HOP — not just at
-the fixpoint — plus the mode-policy plumbing (force_mode, crossover
-mapping, per-mode hop_bytes accounting)."""
+"""Masked boolean-semiring SpMM primitive (ops/semiring.py): the kernel
+each batch size picks (bit kernel, Pallas dense kernel, both in
+interpreter mode on CPU) and the numpy oracle must agree byte-identically
+ON EVERY HOP — not just at the fixpoint — and the jitted fixpoint holds
+no runtime branch between them."""
+
+from functools import partial
+from types import SimpleNamespace
 
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from spicedb_kubeapi_proxy_tpu.engine import Engine, WriteOp
@@ -28,9 +32,10 @@ definition doc {
 
 def _block_engine(monkeypatch, n_docs=12, n_users=7):
     """A small engine whose graph really forms dense blocks WITH
-    bit-packed duals on the CPU host (interpret-mode kernel + lowered
-    dense threshold) — push and pull are distinct code paths here."""
+    bit-packed duals on the CPU host (interpret-mode kernels + lowered
+    dense threshold) — the bit and the dense kernel are both live here."""
     monkeypatch.setenv("SDBKP_BITPROP", "interpret")
+    monkeypatch.setenv("SDBKP_SEMIRING", "interpret")
     monkeypatch.setattr(reachability, "DENSE_MIN_EDGES", 8)
     e = Engine(schema=parse_schema(SCHEMA))
     rels = [f"doc:d{i}#viewer@user:u{(i * 3 + j) % n_users}"
@@ -60,9 +65,10 @@ def _np_hop(Vf, src, dst, act, metas, blocks):
 
 
 def test_propagate_modes_agree_every_hop(monkeypatch):
-    """Push, pull, both auto branches, and the numpy oracle produce the
-    SAME propagation byte-for-byte at every hop of the closure, and the
-    auto lax.cond reports the branch it took."""
+    """The kernel each batch picks (3 rows: the bit kernel; the same rows
+    padded to BIT_B_MAX + 1: the dense kernel) and the numpy oracle
+    produce the SAME propagation byte-for-byte at every hop of the
+    closure."""
     e, cg, d = _block_engine(monkeypatch)
     meta = cg.run_meta()
     Mp = (cg.M // reachability.LANE + 1) * reachability.LANE
@@ -84,26 +90,21 @@ def test_propagate_modes_agree_every_hop(monkeypatch):
             if 0 <= s < cg.M:
                 V[b, s] = 1
 
-    def one_hop(Vf, mode, crossover):
-        prop, is_push = semiring.propagate(
+    def one_hop(Vf):
+        return np.asarray(semiring.propagate(
             meta.blocks, d["blocks"], d["blocks_bits"],
             jnp.asarray(src), jnp.asarray(dst), jnp.asarray(act),
-            dsrc, ddst, dact, jnp.asarray(Vf),
-            semiring.frontier_occupancy(jnp.asarray(Vf)),
-            jnp.float32(crossover), level=None, mode=mode)
-        return np.asarray(prop), int(is_push)
+            dsrc, ddst, dact, jnp.asarray(Vf), level=None))
 
+    wide = bitprop.BIT_B_MAX + 1
     for hop in range(6):
         want = _np_hop(V, src, dst, act, meta.blocks, d["blocks"])
-        got_push, p1 = one_hop(V, "push", 1.0)
-        got_pull, p2 = one_hop(V, "pull", 1.0)
-        got_auto_hi, p3 = one_hop(V, "auto", 1.0)   # occ <= 1 -> push
-        got_auto_lo, p4 = one_hop(V, "auto", -1.0)  # occ > -1 -> pull
-        assert (p1, p2, p3, p4) == (1, 0, 1, 0), hop
-        for name, got in (("push", got_push), ("pull", got_pull),
-                          ("auto/push", got_auto_hi),
-                          ("auto/pull", got_auto_lo)):
-            np.testing.assert_array_equal(got, want, err_msg=f"{name}@{hop}")
+        padded = np.zeros((wide, Mp), dtype=np.uint8)
+        padded[:B] = V
+        np.testing.assert_array_equal(one_hop(V), want,
+                                      err_msg=f"bit@{hop}")
+        np.testing.assert_array_equal(one_hop(padded)[:B], want,
+                                      err_msg=f"dense@{hop}")
         V2 = V | want
         if np.array_equal(V2, V):
             break
@@ -111,6 +112,72 @@ def test_propagate_modes_agree_every_hop(monkeypatch):
     else:
         pytest.fail("closure did not settle within the hop budget")
     assert hop >= 1, "graph must need multiple hops to exercise per-hop parity"
+
+
+@pytest.mark.parametrize("B", [1, bitprop.BIT_B_MAX, bitprop.BIT_B_MAX + 1,
+                               33])
+def test_propagate_picks_kernel_by_batch(monkeypatch, B):
+    """One dual-bearing block: up to BIT_B_MAX rows the hop is the bit
+    kernel's, beyond it the dense kernel's, and either matches its numpy
+    oracle. The block sits at [128, 384) fed by [0, 128)."""
+    monkeypatch.setenv("SDBKP_BITPROP", "interpret")
+    monkeypatch.setenv("SDBKP_SEMIRING", "interpret")
+    n_dst, n_src, dst_off, Mp = 256, 128, 128, 512
+    rng = np.random.default_rng(B)
+    A = (rng.random((n_dst, n_src)) < 0.05).astype(np.int8)
+    dl, sl = np.nonzero(A)
+    bits = bitprop.pack_block_host(dl, sl, n_dst, n_src)
+    bm = SimpleNamespace(level=0, src_off=0, n_src=n_src,
+                         dst_off=dst_off, n_dst=n_dst)
+    V = np.zeros((B, Mp), dtype=np.uint8)
+    V[:, :n_src] = rng.random((B, n_src)) < 0.1
+    none = jnp.zeros(0, dtype=jnp.int32)
+    hop = partial(semiring.propagate, (bm,), (jnp.asarray(A),),
+                  (jnp.asarray(bits),), none, none,
+                  jnp.zeros(0, dtype=jnp.uint8), jnp.asarray([0]),
+                  jnp.asarray([Mp - 1]), jnp.asarray([0], dtype=jnp.uint8))
+    kernel = ("sdbkp_bit_hop" if B <= bitprop.BIT_B_MAX
+              else "sdbkp_dense_hop")
+    assert kernel in str(jax.make_jaxpr(hop)(jnp.asarray(V)))
+    got = np.array(hop(jnp.asarray(V)))
+    frontier = V[:, :n_src]
+    want = bitprop.dense_hop_reference(A, frontier)
+    if B <= bitprop.BIT_B_MAX:
+        np.testing.assert_array_equal(
+            want, bitprop.bit_hop_reference(bits, frontier.T.copy()).T)
+    np.testing.assert_array_equal(got[:, dst_off:dst_off + n_dst], want)
+    got[:, dst_off:dst_off + n_dst] = 0
+    assert not got.any()
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs nested in it."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _eqns(sub)
+
+
+def test_fixpoint_holds_no_runtime_kernel_branch(monkeypatch):
+    """A graph with a bit-dual block traces to a fixpoint with no cond
+    anywhere in it, and four outputs: the answer, convergence, the
+    iteration count and the missing-context count."""
+    _, cg, d = _block_engine(monkeypatch)
+    run = partial(reachability.fixpoint_program(cg.run_meta()),
+                  max_iters=8)
+    seeds = np.full((1, 2), cg.M, dtype=np.int32)
+    qs = np.full(8, cg.M, dtype=np.int32)
+    closed = jax.make_jaxpr(run)(
+        d["blocks"], d["blocks_bits"], d["src"], d["dst"], d["exp"],
+        d["cav"], d["ptr"], d["dsrc"], d["ddst"], d["dexp"], d["dcav"],
+        d["cav_static"], (), seeds, qs, np.zeros(8, dtype=np.int32),
+        np.float32(0.0))
+    prims = {eqn.primitive.name for eqn in _eqns(closed.jaxpr)}
+    assert "while" in prims and "cond" not in prims
+    assert len(closed.out_avals) == 4
 
 
 def test_edge_activation_fuses_expiry_and_caveat():
@@ -127,27 +194,6 @@ def test_edge_activation_fuses_expiry_and_caveat():
         np.asarray(semiring.edge_activation(exp, np.float32(0.0), cav,
                                             None)),
         [1, 0, 1, 1])
-
-
-def test_crossover_from_occupancy_mapping():
-    assert semiring.crossover_from_occupancy(None) == 1.0
-    assert semiring.crossover_from_occupancy(0.0) == 1.0
-    assert semiring.crossover_from_occupancy(0.3) == pytest.approx(0.7)
-    # floor keeps seed-only first hops on push under a dense steady state
-    assert semiring.crossover_from_occupancy(1.0) == 0.05
-
-
-def test_force_mode():
-    assert semiring.resolved_mode() == "auto"
-    with semiring.force_mode("push"):
-        assert semiring.resolved_mode() == "push"
-        with semiring.force_mode("pull"):
-            assert semiring.resolved_mode() == "pull"
-        assert semiring.resolved_mode() == "push"
-    assert semiring.resolved_mode() == "auto"
-    with pytest.raises(ValueError):
-        with semiring.force_mode("sideways"):
-            pass
 
 
 @pytest.mark.parametrize("n_dst,n_src,n_b", [
@@ -184,29 +230,3 @@ def test_dense_eligibility_matrix():
     assert not bitprop.dense_eligible(128, 100, 1)  # src not tile-aligned
     assert not bitprop.dense_eligible(
         128, 128, bitprop.DENSE_B_MAX + 1)          # batch cap
-    # the gate composes with the feature switch
-    from spicedb_kubeapi_proxy_tpu.utils.features import features
-    features.set("SemiringDenseKernel", False)
-    try:
-        assert not bitprop.dense_kernel_enabled()
-    finally:
-        features.reset()
-
-
-def test_hop_bytes_reports_per_mode_traffic(monkeypatch):
-    """hop_bytes() breaks the core dense-block bytes out PER SEMIRING
-    MODE: push streams the bit-packed duals (8x smaller where they
-    exist), pull the full int8 A, pallas adds the MXU kernel's frontier
-    re-stream on eligible blocks."""
-    _, cg, d = _block_engine(monkeypatch)
-    hb = cg.hop_bytes(batch=1)
-    modes = hb["modes"]
-    assert set(modes) == {"push", "pull", "pallas"}
-    core = [bm for bm in cg.run_meta().blocks if bm.level == 0]
-    if core:
-        assert modes["pull"] == sum(bm.n_dst * bm.n_src for bm in core)
-        assert 0 < modes["push"] < modes["pull"]
-        assert modes["pallas"] >= modes["pull"]
-    # the pre-semiring keys survive for the roofline reports
-    for k in ("residual", "blocks", "programs", "tail_once", "total"):
-        assert k in hb
